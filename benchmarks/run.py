@@ -13,9 +13,11 @@ import pathlib
 import sys
 
 from benchmarks import tables
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     names = sys.argv[1:] or ["params", "ema", "macs", "utilization",
                              "latency_energy", "kernels", "decode",
                              "decode_attn", "accuracy", "roofline"]

@@ -461,15 +461,18 @@ def bench_decode(n_requests: int = 24, num_slots: int = 8) -> List[Row]:
     # sharding property). Gated: tokens identical to the single-device
     # run at equal counts, and per-rank KV traffic == kv_bytes_per_token
     # / tp_ranks — each rank streams only its head-slice of every page.
+    # The child is forced onto the CPU (never a chip this process may
+    # hold), and the row says so: its numbers are host-device counts.
     sub = textwrap.dedent("""
         import os
         flag = "--xla_force_host_platform_device_count=4"
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import json, time
         import jax
         assert len(jax.devices()) == 4, jax.devices()
+        assert jax.default_backend() == "cpu", jax.default_backend()
         import numpy as np
         from repro.configs import get_config
         from repro.launch.mesh import make_local_mesh
@@ -507,6 +510,7 @@ def bench_decode(n_requests: int = 24, num_slots: int = 8) -> List[Row]:
         s1, t1, d1 = run(None)
         sN, tN, dN = run(make_local_mesh(1, 4))
         print(json.dumps({
+            "backend": jax.default_backend(),
             "tokens_match": t1 == tN,
             "decoded_tokens": dN["decoded_tokens"],
             "decoded_tokens_single": d1["decoded_tokens"],
@@ -520,6 +524,7 @@ def bench_decode(n_requests: int = 24, num_slots: int = 8) -> List[Row]:
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)  # the child sets its own, before jax init
     env.setdefault("PYTHONPATH", "src")
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", sub], capture_output=True,
                          text=True, timeout=900, env=env)
     if out.returncode != 0:
@@ -808,6 +813,7 @@ def bench_decode(n_requests: int = 24, num_slots: int = 8) -> List[Row]:
          f"faults={sum(dg['faults_injected'].values())} "
          f"recovered_preempts={dg['preemptions_recovered']}"),
         ("decode/sharded", 0.0,
+         f"backend={shr['backend']} "
          f"tp={shr['tp_ranks']} tok/s={shr['tokens_per_s']:.0f} vs "
          f"1-device {shr['tokens_per_s_single']:.0f} "
          f"tokens_match={shr['tokens_match']} "

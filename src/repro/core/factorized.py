@@ -45,6 +45,7 @@ __all__ = [
     "compress_linear",
     "apply_compressed_linear",
     "compress_model_params",
+    "decompress_model_params",
     "decompress_ws_entry",
     "decompress_wd_leaf",
     "params_stream_bits",
@@ -115,7 +116,7 @@ class DictionaryBank:
             scale = 1.0 / np.sqrt(d_in)
             self.dicts[family] = (
                 jax.random.normal(key, (d_in, r), self.dtype) * scale
-            )
+            ).astype(self.dtype)
         else:
             got = self.dicts[family].shape
             if got != (d_in, r):
@@ -141,9 +142,11 @@ def init_linear(
     if fcfg.applies_to(d_in, d_out) and bank is not None:
         r = bank.ensure(kd, family, d_in, d_out)
         # var(W) target 1/d_in; W_S contributes r * (1/d_in) * var(W_D).
-        p["wd"] = jax.random.normal(kd, (r, d_out), dtype) / np.sqrt(r)
+        p["wd"] = (jax.random.normal(kd, (r, d_out), dtype)
+                   / np.sqrt(r)).astype(dtype)
     else:
-        p["w"] = jax.random.normal(kd, (d_in, d_out), dtype) / np.sqrt(d_in)
+        p["w"] = (jax.random.normal(kd, (d_in, d_out), dtype)
+                  / np.sqrt(d_in)).astype(dtype)
     if use_bias:
         p["b"] = jnp.zeros((d_out,), dtype)
     return p
@@ -487,3 +490,41 @@ def compress_model_params(params, fcfg: FactorizationConfig,
         "value_bits": value_bits,
     }
     return cparams, stats
+
+
+_STREAM_KEYS = ("wd_first", "wd_deltas", "wd_vq", "wd_scale", "wd_offset",
+                "wd_bits")
+
+
+def decompress_model_params(cparams, like):
+    """Inverse walk of :func:`compress_model_params`: the streaming tree
+    back to a dense factorized tree that ``apply_linear`` runs as plain
+    ``(x @ W_S) @ W_D`` — the jnp reference the compressed kernels are
+    checked against.
+
+    ``like`` is the factorized tree (arrays or ``jax.eval_shape`` shapes)
+    the streams were made from: it supplies each W_D's rank and the dtype
+    the dense factors come back in. Decompression is exact up to float
+    rounding, so both trees compute the same function."""
+    def wd_dense(ref, c):
+        lead = ref.shape[:-2]
+        r = ref.shape[-2]
+        flat = {k: jnp.reshape(c[k], (-1,) + c[k].shape[len(lead):])
+                for k in _STREAM_KEYS}
+        dense = jax.vmap(lambda q: decompress_wd_leaf(q, r))(flat)
+        return dense.reshape(ref.shape).astype(ref.dtype)
+
+    def walk(ref, c):
+        if isinstance(ref, dict):
+            if "wd" in ref and "wd_vq" in c:
+                out = {k: v for k, v in c.items() if k not in _STREAM_KEYS}
+                out["wd"] = wd_dense(ref["wd"], c)
+                return out
+            return {k: walk(ref[k], c[k]) for k in ref}
+        return c
+
+    out = {k: walk(like[k], cparams[k]) for k in like if k != "dicts"}
+    out["dicts"] = {f: decompress_ws_entry(cparams["dicts"][f], ws.shape[0],
+                                           ws.dtype)
+                    for f, ws in like["dicts"].items()}
+    return out

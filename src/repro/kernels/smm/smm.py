@@ -12,13 +12,18 @@ matmul dense:
 i.e. the paper's EMA reduction is preserved exactly while the compute side is
 traded from MAC-skipping to full MXU occupancy — the codesign argument in
 DESIGN §2. Densification is a compare-select accumulation over the nnz axis
-(VPU-friendly; no scatter, which TPUs lack in-kernel).
+(VPU-friendly; no scatter, which TPUs lack in-kernel) that walks the stream
+row by row and carries the running row index as it goes — the delta decode
+is that running sum, so no prefix-sum primitive is needed. The scale,
+offset and level-count scalars ride SMEM.
 
-Grid: (M/bm, N/bn); each step holds the full r (the factorization rank is
-small by construction — that is the paper's point).
+Grid: (N/bn, M/bm), M innermost: a column block's dense (r, bn) tile is
+decoded once, at its first M block, into VMEM scratch and reused by every
+other M block of that column (each step holds the full r — the
+factorization rank is small by construction, that is the paper's point).
 VMEM per step (bm=bn=256, r=1024, nnz=128):
-  y tile 256x1024x2 = 512 KiB, dense tile 1024x256x4 = 1 MiB,
-  streams (128x256 x2) = 64 KiB, out 256x256x4 = 256 KiB   (~1.9 MiB).
+  y tile 256x1024x4 = 1 MiB, dense scratch 1024x256x4 = 1 MiB,
+  streams (128x256 x2) = 64 KiB, out 256x256x4 = 256 KiB   (~2.3 MiB).
 """
 from __future__ import annotations
 
@@ -28,33 +33,40 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 VALUE_BITS = 6
 
 
-def _smm_kernel(y_ref, first_ref, deltas_ref, vq_ref, scale_ref, offset_ref,
-                levels_ref, o_ref, *, r: int, nnz: int):
-    # ---- decode the stream for this column block
-    first = first_ref[...].astype(jnp.int32)  # (bn,)
-    deltas = deltas_ref[...].astype(jnp.int32)  # (nnz-1, bn)
-    idx = jnp.concatenate([first[None], first[None] + jnp.cumsum(deltas, 0)], 0)
-    # Dequant level count (2^value_bits - 1) rides as a scalar operand, like
-    # scale/offset: the value width is part of the stream, not the program.
-    vals = vq_ref[...].astype(jnp.float32) / levels_ref[0] * scale_ref[0] \
-        + offset_ref[0]  # (nnz, bn)
+def _smm_kernel(y_ref, first_ref, deltas_ref, vq_ref, consts_ref, o_ref,
+                dense_ref, dscr_ref, vscr_ref, *, r: int, nnz: int):
+    # consts_ref (SMEM): scale, offset, dequant level count 2^bits - 1 —
+    # the value width is part of the stream, not the program.
+    scale, offset, levels = consts_ref[0], consts_ref[1], consts_ref[2]
 
-    # ---- densify: (r, bn) via compare-select accumulation over nnz rows.
-    rows = jax.lax.broadcasted_iota(jnp.int32, (r, idx.shape[1]), 0)
+    @pl.when(pl.program_id(1) == 0)
+    def _densify():
+        bn = dense_ref.shape[1]
+        # Widen the 8-bit streams to 32-bit scratch once per column block:
+        # Mosaic loads single rows only at 32-bit, and casts 8-bit ints
+        # only to int32.
+        dscr_ref[...] = deltas_ref[...].astype(jnp.int32)
+        vq = vq_ref[...].astype(jnp.int32).astype(jnp.float32)
+        vscr_ref[...] = vq / levels * scale + offset
+        rows = jax.lax.broadcasted_iota(jnp.int32, (r, bn), 0)
 
-    def body(k, dense):
-        hit = rows == idx[k][None, :]
-        return dense + jnp.where(hit, vals[k][None, :], 0.0)
+        def body(k, carry):
+            dense, idx = carry
+            idx = idx + dscr_ref[pl.ds(k - 1, 1), :]
+            hit = rows == idx
+            return dense + jnp.where(hit, vscr_ref[pl.ds(k, 1), :], 0.0), idx
 
-    dense = jax.lax.fori_loop(
-        0, nnz, body, jnp.zeros((r, idx.shape[1]), jnp.float32))
+        idx0 = first_ref[...]  # (1, bn) int32: row of the first nonzero
+        dense0 = jnp.where(rows == idx0, vscr_ref[0:1, :], 0.0)
+        dense_ref[...], _ = jax.lax.fori_loop(1, nnz, body, (dense0, idx0))
 
     # ---- dense MXU matmul
-    o_ref[...] = jnp.dot(y_ref[...].astype(jnp.float32), dense,
+    o_ref[...] = jnp.dot(y_ref[...].astype(jnp.float32), dense_ref[...],
                          preferred_element_type=jnp.float32)
 
 
@@ -76,21 +88,26 @@ def smm_matmul(y: jnp.ndarray, first: jnp.ndarray, deltas: jnp.ndarray,
     assert M % bm == 0 and N % bn == 0, (M, N, bm, bn)
     if levels is None:
         levels = jnp.float32((1 << VALUE_BITS) - 1)
-    grid = (M // bm, N // bn)
+    if nnz == 1:  # no deltas: keep a (1, N) operand so the spec is valid
+        deltas = jnp.zeros((1, N), deltas.dtype)
+    consts = jnp.stack([jnp.asarray(c, jnp.float32).reshape(())
+                        for c in (scale, offset, levels)])
     return pl.pallas_call(
         functools.partial(_smm_kernel, r=r, nnz=nnz),
-        grid=grid,
+        grid=(N // bn, M // bm),
         in_specs=[
-            pl.BlockSpec((bm, r), lambda m, n: (m, 0)),
-            pl.BlockSpec((bn,), lambda m, n: (n,)),
-            pl.BlockSpec((max(nnz - 1, 1), bn), lambda m, n: (0, n)),
-            pl.BlockSpec((nnz, bn), lambda m, n: (0, n)),
-            pl.BlockSpec((1,), lambda m, n: (0,)),
-            pl.BlockSpec((1,), lambda m, n: (0,)),
-            pl.BlockSpec((1,), lambda m, n: (0,)),
+            pl.BlockSpec((bm, r), lambda n, m: (m, 0)),
+            pl.BlockSpec((1, bn), lambda n, m: (0, n)),
+            pl.BlockSpec((deltas.shape[0], bn), lambda n, m: (0, n)),
+            pl.BlockSpec((nnz, bn), lambda n, m: (0, n)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda m, n: (m, n)),
+        out_specs=pl.BlockSpec((bm, bn), lambda n, m: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((r, bn), jnp.float32),       # dense W_D
+                        pltpu.VMEM((deltas.shape[0], bn), jnp.int32),
+                        pltpu.VMEM((nnz, bn), jnp.float32)],     # values
         interpret=interpret,
-    )(y, first, deltas, vq, scale.reshape(1), offset.reshape(1),
-      jnp.asarray(levels, jnp.float32).reshape(1))
+    )(y, first.astype(jnp.int32).reshape(1, N), deltas, vq, consts)

@@ -53,19 +53,6 @@ NEG_INF = -1e30  # matches models/layers.py: finite masked-score sentinel
 _EPS = 1e-30     # matches the TDA kernel's finish division guard
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (same shim as models/moe.py):
-    the top-level binding (and its ``check_vma`` kwarg) only exist in newer
-    jax; older versions expose ``jax.experimental.shard_map.shard_map``
-    with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def decode_partials(
     q: jnp.ndarray,        # (B, Hq_loc, D) queries for this rank's heads
     k: jnp.ndarray,        # (B, S_loc, Hkv_loc, D) fp — or int8 codes
@@ -194,8 +181,8 @@ def sharded_decode_attention(
     # the shard_map arity fixed (specs must match positionally).
     ksl = k_scale if quant else jnp.zeros((B, S, Hkv), jnp.float32)
     vsl = v_scale if quant else jnp.zeros((B, S, Hkv), jnp.float32)
-    out = _shard_map(
-        body, mesh,
+    out = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P(), kv_spec, kv_spec, P(), sc_spec, sc_spec),
         out_specs=P())(q, k_cache, v_cache, hi, ksl, vsl)
     return out.reshape(B, 1, Hq, D).astype(q.dtype)

@@ -134,26 +134,17 @@ def _tda_body(lo, hi, q_ref, k_ref, v_ref, ks_ref, vs_ref, table,
                     jnp.maximum(l_acc[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _tda_kernel(bounds_ref, q_ref, k_ref, v_ref, *rest,
-                bk: int, groups: int, quant: bool, lut: bool):
-    rest = list(rest)
-    ks_ref = rest.pop(0) if quant else None
-    vs_ref = rest.pop(0) if quant else None
-    table = rest.pop(0)[...] if lut else None
-    o_ref, o_acc, m_acc, l_acc = rest
-    _tda_body(bounds_ref[0, 0], bounds_ref[0, 1], q_ref, k_ref, v_ref,
-              ks_ref, vs_ref, table, o_ref, o_acc, m_acc, l_acc,
-              bk=bk, groups=groups, quant=quant)
-
-
-def _tda_paged_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
-                      bk: int, groups: int, quant: bool, lut: bool):
-    """Paged grid step: bounds arrive as a scalar-prefetch ref (indexed by
-    the slot program id — the block table prefetch ref is consumed by the
-    K/V index maps, not the body); predication still runs over *logical*
-    block positions, so the body is shared with the contiguous kernel."""
-    del bt_ref  # consumed by the in_specs index maps
-    rest = list(rest)
+def _tda_kernel(bounds_ref, *refs, bk: int, groups: int, quant: bool,
+                lut: bool, paged: bool):
+    """One grid step of either layout. Bounds arrive as a scalar-prefetch
+    ref indexed by the slot program id; the paged kernel's block-table
+    prefetch ref is consumed by the K/V index maps, not the body, and
+    predication runs over *logical* block positions in both layouts."""
+    refs = list(refs)
+    if paged:
+        refs.pop(0)  # block table
+    q_ref, k_ref, v_ref = refs[:3]
+    rest = refs[3:]
     ks_ref = rest.pop(0) if quant else None
     vs_ref = rest.pop(0) if quant else None
     table = rest.pop(0)[...] if lut else None
@@ -162,6 +153,17 @@ def _tda_paged_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
     _tda_body(bounds_ref[b, 0], bounds_ref[b, 1], q_ref, k_ref, v_ref,
               ks_ref, vs_ref, table, o_ref, o_acc, m_acc, l_acc,
               bk=bk, groups=groups, quant=quant)
+
+
+def _live_block(kb, lo, hi, bk: int, nk: int):
+    """K/V block a grid step fetches: ``kb`` held inside the blocks that
+    intersect ``[lo, hi)``. Steps the body skips repeat the nearest live
+    block's index, and Pallas issues no DMA when a block index repeats from
+    one step to the next, so K/V traffic follows occupancy as the FLOPs
+    do. An empty span pins one block."""
+    first = jnp.minimum(lo // bk, nk - 1)
+    last = jnp.clip((hi + bk - 1) // bk - 1, first, nk - 1)
+    return jnp.clip(kb, first, last)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -183,35 +185,45 @@ def tda_decode_attention(q, k, v, bounds, k_scale=None, v_scale=None,
     lut = lut_table is not None
     nk = S // block_k
 
+    def block(b, kb, bounds):
+        return _live_block(kb, bounds[b, 0], bounds[b, 1], block_k, nk)
+
     in_specs = [
-        pl.BlockSpec((1, 2), lambda b, kb: (b, 0), memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, Hq, D), lambda b, kb: (b, 0, 0)),
-        pl.BlockSpec((1, block_k, Hkv, D), lambda b, kb: (b, kb, 0, 0)),
-        pl.BlockSpec((1, block_k, Hkv, D), lambda b, kb: (b, kb, 0, 0)),
+        pl.BlockSpec((1, Hq, D), lambda b, kb, bounds: (b, 0, 0)),
+        pl.BlockSpec((1, block_k, Hkv, D),
+                     lambda b, kb, bounds: (b, block(b, kb, bounds), 0, 0)),
+        pl.BlockSpec((1, block_k, Hkv, D),
+                     lambda b, kb, bounds: (b, block(b, kb, bounds), 0, 0)),
     ]
     args = [bounds, q, k, v]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, block_k, Hkv), lambda b, kb: (b, kb, 0)),
-            pl.BlockSpec((1, block_k, Hkv), lambda b, kb: (b, kb, 0)),
+            pl.BlockSpec((1, block_k, Hkv),
+                         lambda b, kb, bounds: (b, block(b, kb, bounds), 0)),
+            pl.BlockSpec((1, block_k, Hkv),
+                         lambda b, kb, bounds: (b, block(b, kb, bounds), 0)),
         ]
         args += [k_scale, v_scale]
     if lut:
-        in_specs.append(pl.BlockSpec((LUT_SIZE,), lambda b, kb: (0,)))
+        in_specs.append(pl.BlockSpec((LUT_SIZE,), lambda b, kb, bounds: (0,)))
         args.append(lut_table)
 
-    return pl.pallas_call(
-        functools.partial(_tda_kernel, bk=block_k, groups=Hq // Hkv,
-                          quant=quant, lut=lut),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # bounds ride SMEM prefetch
         grid=(B, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, kb: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
+        out_specs=pl.BlockSpec((1, Hq, D), lambda b, kb, bounds: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hq, D), jnp.float32),  # o accumulator
             pltpu.VMEM((Hq, 1), jnp.float32),  # running max
             pltpu.VMEM((Hq, 1), jnp.float32),  # running denominator
         ],
+    )
+    return pl.pallas_call(
+        functools.partial(_tda_kernel, bk=block_k, groups=Hq // Hkv,
+                          quant=quant, lut=lut, paged=False),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
         interpret=interpret,
     )(*args)
 
@@ -241,7 +253,8 @@ def tda_paged_decode_attention(q, k, v, bounds, block_table, k_scale=None,
     lut = lut_table is not None
 
     def page(b, kb, bounds_ref, bt_ref):
-        return jnp.clip(bt_ref[b, kb], 0, P - 1)
+        live = _live_block(kb, bounds_ref[b, 0], bounds_ref[b, 1], ps, nk)
+        return jnp.clip(bt_ref[b, live], 0, P - 1)
 
     in_specs = [
         pl.BlockSpec((1, Hq, D), lambda b, kb, bounds, bt: (b, 0, 0)),
@@ -280,8 +293,8 @@ def tda_paged_decode_attention(q, k, v, bounds, block_table, k_scale=None,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_tda_paged_kernel, bk=ps, groups=Hq // Hkv,
-                          quant=quant, lut=lut),
+        functools.partial(_tda_kernel, bk=ps, groups=Hq // Hkv,
+                          quant=quant, lut=lut, paged=True),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
         interpret=interpret,
@@ -290,11 +303,14 @@ def tda_paged_decode_attention(q, k, v, bounds, block_table, k_scale=None,
 
 def _tda_mixed_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, kr_ref,
                       vr_ref, *rest, bk: int, groups: int, quant: bool,
-                      lut: bool, ring: int, window, S: int):
-    """Mixed (multi-query) grid step: cache blocks 0..nk-1 are predicated on
-    the slot's pre-write occupancy exactly like decode; the final grid step
-    folds the in-row chunk keys in and normalizes. Online-softmax state is
-    per (query column, head) — scratch rows are laid out (Hkv, S, G)."""
+                      lut: bool, ring: int, window, tq: int, nk: int):
+    """Mixed (multi-query) grid step over one ``tq``-column query tile:
+    cache blocks ``0..nk-1`` are predicated on the slot's pre-write
+    occupancy exactly like decode, then the in-row chunk's key tiles at or
+    before the query tile fold in, and the last step normalizes.
+    Online-softmax state is per (query column, head) of this tile only —
+    scratch rows are laid out (Hkv, tq, G) — so VMEM is bounded by ``tq``,
+    not by the chunk width."""
     del bt_ref  # consumed by the in_specs index maps
     rest = list(rest)
     ks_ref = rest.pop(0) if quant else None
@@ -302,10 +318,15 @@ def _tda_mixed_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, kr_ref,
     table = rest.pop(0)[...] if lut else None
     o_ref, o_acc, m_acc, l_acc = rest
     b = pl.program_id(0)
-    kb = pl.program_id(1)
-    nk = pl.num_programs(1) - 1  # last grid step is the in-row chunk
+    qi = pl.program_id(1)
+    kb = pl.program_id(2)
     ci = bounds_ref[b, 0]  # tokens resident in the lane (pre-write)
     nn = bounds_ref[b, 1]  # fresh chunk columns this step
+    q0 = qi * tq           # first chunk column of this query tile
+    # Query tiles wholly past the fresh columns are garbage the caller
+    # ignores: they attend nothing (a decode row in a mixed step works in
+    # its first tile only).
+    live = q0 < nn
 
     @pl.when(kb == 0)
     def _init():
@@ -315,37 +336,37 @@ def _tda_mixed_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, kr_ref,
 
     def attend(kblk, vblk, valid):
         """One online-softmax block over ``kblk`` (bkk, Hkv, D) with a
-        per-(query, key) ``valid`` mask (S, bkk)."""
-        q = q_ref[0].astype(jnp.float32)  # (S, Hq, D)
+        per-(query, key) ``valid`` mask (tq, bkk)."""
+        q = q_ref[0].astype(jnp.float32)  # (tq, Hq, D)
         Hq, D = q.shape[1], q.shape[2]
         Hkv = kblk.shape[1]
-        qg = q.reshape(S, Hkv, groups, D).transpose(1, 0, 2, 3)
-        qg = qg.reshape(Hkv, S * groups, D)
+        qg = q.reshape(tq, Hkv, groups, D).transpose(1, 0, 2, 3)
+        qg = qg.reshape(Hkv, tq * groups, D)
         s = jax.lax.dot_general(
             qg, kblk, (((2,), (2,)), ((0,), (1,))),
             preferred_element_type=jnp.float32) * (1.0 / np.sqrt(D))
-        vmask = valid[None, :, None, :]  # (1, S, 1, bkk)
-        s4 = s.reshape(Hkv, S, groups, -1)
-        s = jnp.where(vmask, s4, NEG_INF).reshape(Hkv, S * groups, -1)
-        m_prev = m_acc[...].reshape(Hkv, S * groups)
+        vmask = valid[None, :, None, :]  # (1, tq, 1, bkk)
+        s4 = s.reshape(Hkv, tq, groups, -1)
+        s = jnp.where(vmask, s4, NEG_INF).reshape(Hkv, tq * groups, -1)
+        m_prev = m_acc[...].reshape(Hkv, tq * groups)
         m_new = jnp.maximum(m_prev, s.max(-1))
         p = _exp(s - m_new[..., None], table)
-        p = jnp.where(vmask, p.reshape(Hkv, S, groups, -1),
-                      0.0).reshape(Hkv, S * groups, -1)
+        p = jnp.where(vmask, p.reshape(Hkv, tq, groups, -1),
+                      0.0).reshape(Hkv, tq * groups, -1)
         alpha = _exp(m_prev - m_new, table)
-        l_acc[...] = (l_acc[...].reshape(Hkv, S * groups) * alpha
-                      + p.sum(-1)).reshape(S * Hq, 1)
+        l_acc[...] = (l_acc[...].reshape(Hkv, tq * groups) * alpha
+                      + p.sum(-1)).reshape(tq * Hq, 1)
         pv = jax.lax.dot_general(
             p, vblk, (((2,), (0,)), ((0,), (1,))),
             preferred_element_type=jnp.float32)
-        o_acc[...] = (o_acc[...].reshape(Hkv, S * groups, D)
-                      * alpha[..., None] + pv).reshape(S * Hq, D)
-        m_acc[...] = m_new.reshape(S * Hq, 1)
+        o_acc[...] = (o_acc[...].reshape(Hkv, tq * groups, D)
+                      * alpha[..., None] + pv).reshape(tq * Hq, D)
+        m_acc[...] = m_new.reshape(tq * Hq, 1)
 
     blk0 = kb * bk
     hi = jnp.minimum(ci, ring)  # pre-write occupancy: [0, min(ci, ring))
 
-    @pl.when((kb < nk) & (blk0 < hi))
+    @pl.when(live & (kb < nk) & (blk0 < hi))
     def _cache_block():
         k = k_ref[0].astype(jnp.float32)  # (bk, Hkv, D)
         v = v_ref[0].astype(jnp.float32)
@@ -356,41 +377,106 @@ def _tda_mixed_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, kr_ref,
         # (canonical ring phase walked back from the newest resident token);
         # one formula covers full lanes (p_r == r for r < ci) and wrapped
         # rings. Valid iff p_r >= 0 (and inside the window of query p_q).
-        r = blk0 + jax.lax.broadcasted_iota(jnp.int32, (S, bk), 1)
+        r = blk0 + jax.lax.broadcasted_iota(jnp.int32, (tq, bk), 1)
         p_r = (ci - 1) - jnp.mod(ci - 1 - r, ring)
         valid = (p_r >= 0) & (r < ring)
         if window is not None:
-            j = jax.lax.broadcasted_iota(jnp.int32, (S, bk), 0)
+            j = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, bk), 0)
             valid &= p_r > (ci + j - window)
         attend(k, v, valid)
 
-    @pl.when(kb == nk)
-    def _row_and_finish():
-        @pl.when(nn > 0)
-        def _row_block():
-            kr = kr_ref[0].astype(jnp.float32)  # (S, Hkv, D)
-            vr = vr_ref[0].astype(jnp.float32)
-            j = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
-            i = jax.lax.broadcasted_iota(jnp.int32, (S, S), 1)
-            valid = (i <= j) & (i < nn)
-            if window is not None:
-                valid &= (j - i) < window
-            attend(kr, vr, valid)
+    rt = kb - nk  # in-row key tile of this step (cache steps: negative)
 
+    @pl.when(live & (rt >= 0) & (rt <= qi) & (rt * tq < nn))
+    def _row_block():
+        kr = kr_ref[0].astype(jnp.float32)  # (tq, Hkv, D)
+        vr = vr_ref[0].astype(jnp.float32)
+        j = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 0)
+        i = rt * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 1)
+        valid = (i <= j) & (i < nn)
+        if window is not None:
+            valid &= (j - i) < window
+        attend(kr, vr, valid)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _finish():
         # Rows with no resident and no fresh keys keep l == 0 -> zeros.
         Hq = q_ref.shape[2]
         D = q_ref.shape[3]
         Hkv = Hq // groups
         o = o_acc[...] / jnp.maximum(l_acc[...], 1e-30)
-        o = o.reshape(Hkv, S, groups, D).transpose(1, 0, 2, 3)
-        o_ref[0] = o.reshape(S, Hq, D).astype(o_ref.dtype)
+        o = o.reshape(Hkv, tq, groups, D).transpose(1, 0, 2, 3)
+        o_ref[0] = o.reshape(tq, Hq, D).astype(o_ref.dtype)
+
+
+# Query rows (tile columns x query heads) one mixed grid step holds. The
+# accumulators, the f32 query copy and the score block all scale with it;
+# on a v5e, 48 heads x 32 columns already overflow the default scoped
+# VMEM, and Mosaic compile time grows with the tile as well.
+MIXED_TILE_ROWS = 1024
+
+
+def mixed_query_tile(n_heads: int) -> int:
+    """Columns per query tile of the mixed kernel: the largest power of
+    two whose (columns x heads) rows fit :data:`MIXED_TILE_ROWS`."""
+    t = 1
+    while 2 * t * n_heads <= MIXED_TILE_ROWS:
+        t *= 2
+    return t
+
+
+def _pad_cols(x, target: int):
+    if x.shape[1] == target:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[1] = (0, target - x.shape[1])
+    return jnp.pad(x, widths)
+
+
+def _mixed_maps(*, nk: int, nq: int, tq: int, ps: int, ring: int, P: int):
+    """Index maps of the mixed kernel's grid ``(B, nq, nk + nq)``: query
+    tile, pool page (K/V and their scales), in-row key tile, output tile.
+
+    DMA follows occupancy: a step the kernel skips repeats the previous
+    step's block index, so Pallas fetches nothing for it. Query tiles
+    past the fresh columns are dead and repeat the last live tile's
+    blocks; cache steps past the resident span, and the in-row steps,
+    repeat the last resident page; in-row steps past the query tile
+    repeat its diagonal key tile.
+    """
+    def last_tile(b, bounds):
+        return jnp.maximum((bounds[b, 1] + tq - 1) // tq - 1, 0)
+
+    def page(b, qi, kb, bounds, bt):
+        kb = jnp.where(qi * tq < bounds[b, 1], kb, nk - 1)
+        kb = _live_block(kb, 0, jnp.minimum(bounds[b, 0], ring), ps, nk)
+        return jnp.clip(bt[b, kb], 0, P - 1)
+
+    def pool_map(b, qi, kb, bounds, bt):
+        return (page(b, qi, kb, bounds, bt), 0, 0, 0)
+
+    def pool_scale_map(b, qi, kb, bounds, bt):
+        return (page(b, qi, kb, bounds, bt), 0, 0)
+
+    def row_map(b, qi, kb, bounds, bt):
+        qt = jnp.minimum(qi, last_tile(b, bounds))
+        rt = jnp.where(qi * tq < bounds[b, 1], kb - nk, nq)
+        return (b, jnp.clip(rt, 0, qt), 0, 0)
+
+    def q_map(b, qi, kb, bounds, bt):
+        return (b, jnp.minimum(qi, last_tile(b, bounds)), 0, 0)
+
+    def out_map(b, qi, kb, bounds, bt):
+        return (b, qi, 0, 0)
+
+    return q_map, pool_map, pool_scale_map, row_map, out_map
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("ring", "window", "interpret"))
+                   static_argnames=("ring", "window", "block_q", "interpret"))
 def tda_mixed_attention(q, k, v, k_row, v_row, bounds, block_table,
                         k_scale=None, v_scale=None, lut_table=None, *,
-                        ring: int, window=None,
+                        ring: int, window=None, block_q=None,
                         interpret: bool = True) -> jnp.ndarray:
     """Fused multi-query mixed-step attention over a paged KV lane pool.
 
@@ -402,8 +488,11 @@ def tda_mixed_attention(q, k, v, k_row, v_row, bounds, block_table,
     :func:`tda_paged_decode_attention`. ``ring`` is the logical lane
     width. Chunked-prefill attention is predicated the same way decode is:
     cache blocks outside ``[0, min(cache_index, ring))`` are skipped, and
-    the in-row chunk rides one extra always-resident grid step. Returns
-    (B, S, Hq, D) f32.
+    the in-row chunk rides extra grid steps, one per key tile at or before
+    the query tile. The chunk axis is cut into ``block_q``-column query
+    tiles (padded up to a multiple; default :func:`mixed_query_tile`), so
+    the kernel's VMEM does not grow with S. Columns ``>= n_new`` are
+    garbage the caller ignores. Returns (B, S, Hq, D) f32.
     """
     B, S, Hq, D = q.shape
     P, ps, Hkv = k.shape[0], k.shape[1], k.shape[2]
@@ -411,56 +500,47 @@ def tda_mixed_attention(q, k, v, k_row, v_row, bounds, block_table,
     assert Hq % Hkv == 0, (Hq, Hkv)
     quant = k_scale is not None
     lut = lut_table is not None
+    tq = min(block_q or mixed_query_tile(Hq), S)
+    nq = -(-S // tq)
+    Sp = nq * tq
+    q, k_row, v_row = (_pad_cols(x, Sp) for x in (q, k_row, v_row))
 
-    def page(b, kb, bounds_ref, bt_ref):
-        # kb == nk is the in-row step: clamp keeps the prefetch in range
-        # (that step never reads the pool refs).
-        return jnp.clip(bt_ref[b, jnp.minimum(kb, nk - 1)], 0, P - 1)
-
+    q_map, pool_map, pool_scale_map, row_map, out_map = _mixed_maps(
+        nk=nk, nq=nq, tq=tq, ps=ps, ring=ring, P=P)
     in_specs = [
-        pl.BlockSpec((1, S, Hq, D), lambda b, kb, bounds, bt: (b, 0, 0, 0)),
-        pl.BlockSpec((1, ps, Hkv, D),
-                     lambda b, kb, bounds, bt: (page(b, kb, bounds, bt),
-                                                0, 0, 0)),
-        pl.BlockSpec((1, ps, Hkv, D),
-                     lambda b, kb, bounds, bt: (page(b, kb, bounds, bt),
-                                                0, 0, 0)),
-        pl.BlockSpec((1, S, Hkv, D), lambda b, kb, bounds, bt: (b, 0, 0, 0)),
-        pl.BlockSpec((1, S, Hkv, D), lambda b, kb, bounds, bt: (b, 0, 0, 0)),
+        pl.BlockSpec((1, tq, Hq, D), q_map),
+        pl.BlockSpec((1, ps, Hkv, D), pool_map),
+        pl.BlockSpec((1, ps, Hkv, D), pool_map),
+        pl.BlockSpec((1, tq, Hkv, D), row_map),
+        pl.BlockSpec((1, tq, Hkv, D), row_map),
     ]
     args = [bounds, block_table, q, k, v, k_row, v_row]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, ps, Hkv),
-                         lambda b, kb, bounds, bt: (page(b, kb, bounds, bt),
-                                                    0, 0)),
-            pl.BlockSpec((1, ps, Hkv),
-                         lambda b, kb, bounds, bt: (page(b, kb, bounds, bt),
-                                                    0, 0)),
-        ]
+        in_specs += [pl.BlockSpec((1, ps, Hkv), pool_scale_map),
+                     pl.BlockSpec((1, ps, Hkv), pool_scale_map)]
         args += [k_scale, v_scale]
     if lut:
         in_specs.append(pl.BlockSpec((LUT_SIZE,),
-                                     lambda b, kb, bounds, bt: (0,)))
+                                     lambda b, qi, kb, bounds, bt: (0,)))
         args.append(lut_table)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, nk + 1),  # + the in-row chunk step
+        grid=(B, nq, nk + nq),  # cache blocks, then in-row key tiles
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, S, Hq, D),
-                               lambda b, kb, bounds, bt: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, tq, Hq, D), out_map),
         scratch_shapes=[
-            pltpu.VMEM((S * Hq, D), jnp.float32),  # o accumulator
-            pltpu.VMEM((S * Hq, 1), jnp.float32),  # running max
-            pltpu.VMEM((S * Hq, 1), jnp.float32),  # running denominator
+            pltpu.VMEM((tq * Hq, D), jnp.float32),  # o accumulator
+            pltpu.VMEM((tq * Hq, 1), jnp.float32),  # running max
+            pltpu.VMEM((tq * Hq, 1), jnp.float32),  # running denominator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_tda_mixed_kernel, bk=ps, groups=Hq // Hkv,
                           quant=quant, lut=lut, ring=ring, window=window,
-                          S=S),
+                          tq=tq, nk=nk),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, Hq, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, Sp, Hq, D), jnp.float32),
         interpret=interpret,
     )(*args)
+    return out[:, :S]

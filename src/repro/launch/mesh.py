@@ -9,33 +9,26 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["compat_make_mesh", "make_production_mesh", "make_local_mesh",
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh",
            "devices_per_pod", "tensor_parallel_size"]
 
 
-def compat_make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types across jax versions.
-
-    ``jax.sharding.AxisType`` (and ``make_mesh``'s ``axis_types`` kwarg)
-    only exist in newer jax; older versions treat every axis as Auto
-    implicitly, so omitting the kwarg is equivalent there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis Auto: GSPMD partitions whatever
+    the sharding rules leave unconstrained."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over however many (host) devices exist — tests/examples."""
-    return compat_make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def tensor_parallel_size(mesh) -> int:

@@ -588,15 +588,21 @@ def attention_block(
             # bounds — the scatter drops them, the lane stays untouched.
             phys = jnp.where(jnp.reshape(slot_mask, (-1,)), phys, P * ps)
 
-        def paged_write(buf, new):
+        # This layer's written pools. Attention reads these, never a
+        # slice of the stacked cache after its update: reading the updated
+        # carry back makes XLA copy the whole stacked pool every step.
+        views = {}
+
+        def paged_write(name, new):
+            buf = cache[name]
             lv = layer_view(buf)  # (P, ps, ...)
             lvf = lv.reshape((P * ps,) + lv.shape[2:])
             lvf = lvf.at[phys].set(new.astype(buf.dtype), mode="drop")
-            lv2 = lvf.reshape(lv.shape)
+            views[name] = lvf.reshape(lv.shape)
             if layer_idx is None:
-                return lv2
+                return views[name]
             return jax.lax.dynamic_update_slice(
-                buf, lv2[None], (layer_idx,) + (0,) * lv2.ndim)
+                buf, views[name][None], (layer_idx,) + (0,) * lv.ndim)
 
         impl = resolve_decode_attn(cfg.decode_attn)
         if slot_mask is not None:
@@ -605,13 +611,13 @@ def attention_block(
         if quant:
             kq, ksc = kv_quantize(k)
             vq, vsc = kv_quantize(v)
-            new_cache = {"k": paged_write(cache["k"], kq[:, 0]),
-                         "v": paged_write(cache["v"], vq[:, 0]),
-                         "k_scale": paged_write(cache["k_scale"], ksc[:, 0]),
-                         "v_scale": paged_write(cache["v_scale"], vsc[:, 0])}
+            new_cache = {"k": paged_write("k", kq[:, 0]),
+                         "v": paged_write("v", vq[:, 0]),
+                         "k_scale": paged_write("k_scale", ksc[:, 0]),
+                         "v_scale": paged_write("v_scale", vsc[:, 0])}
         else:
-            new_cache = {"k": paged_write(cache["k"], k[:, 0]),
-                         "v": paged_write(cache["v"], v[:, 0])}
+            new_cache = {"k": paged_write("k", k[:, 0]),
+                         "v": paged_write("v", v[:, 0])}
         # Ring lanes: every position < min(cache_index+1, ring) is valid
         # (canonical ring phase) — same bounds as the contiguous layout.
         hi = cache_index + 1 if window is None \
@@ -619,27 +625,21 @@ def attention_block(
         if impl == "tda":
             # The kernel consumes the page pools directly: the block table
             # rides scalar prefetch and one page is one kv block.
-            kcs = vcs = None
-            if quant:
-                kcs = layer_view(new_cache["k_scale"])
-                vcs = layer_view(new_cache["v_scale"])
             o = decode_attention(
-                q, layer_view(new_cache["k"]), layer_view(new_cache["v"]),
-                hi, k_scale=kcs, v_scale=vcs, impl="tda",
+                q, views["k"], views["v"], hi, k_scale=views.get("k_scale"),
+                v_scale=views.get("v_scale"), impl="tda",
                 block_k=cfg.decode_block_k, block_table=bt, mesh=mesh)
         else:
             # Dense path: gather each slot's lane view out of the pool
             # (same data volume as reading a dense lane), then attend.
-            def lanes(buf):
-                return gather_paged_lanes(layer_view(buf), bt)
+            def lanes(name):
+                return gather_paged_lanes(views[name], bt)
 
             if quant:
-                kc = kv_dequantize(lanes(new_cache["k"]),
-                                   lanes(new_cache["k_scale"]), dt)
-                vc = kv_dequantize(lanes(new_cache["v"]),
-                                   lanes(new_cache["v_scale"]), dt)
+                kc = kv_dequantize(lanes("k"), lanes("k_scale"), dt)
+                vc = kv_dequantize(lanes("v"), lanes("v_scale"), dt)
             else:
-                kc, vc = lanes(new_cache["k"]), lanes(new_cache["v"])
+                kc, vc = lanes("k"), lanes("v")
             o = decode_attention(q, kc, vc, hi, impl="dense", mesh=mesh)
         o = o.reshape(B, S, cfg.n_heads * hd)
     elif cache is not None and S == 1:
@@ -855,8 +855,8 @@ def init_lm_head(key: jax.Array, cfg: ModelConfig) -> Dict:
     shape = (cfg.d_model, cfg.vocab_size)
     if n_heads > 1:
         shape = (n_heads,) + shape
-    return {"w": jax.random.normal(key, shape, cfg.params_dtype)
-            / np.sqrt(cfg.d_model)}
+    return {"w": (jax.random.normal(key, shape, cfg.params_dtype)
+                  / np.sqrt(cfg.d_model)).astype(cfg.params_dtype)}
 
 
 def lm_logits(p_head: Dict, p_embed: Dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:

@@ -38,18 +38,6 @@ from repro.models.common import ModelConfig
 __all__ = ["init_moe", "moe_ffn"]
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the top-level binding (and its
-    ``check_vma`` kwarg) only exist in newer jax; older versions expose
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def init_moe(key: jax.Array, cfg: ModelConfig, bank: Optional[DictionaryBank]) -> Dict:
     m = cfg.moe
     d, f, E = cfg.d_model, m.d_ff_expert, m.n_experts
@@ -61,10 +49,11 @@ def init_moe(key: jax.Array, cfg: ModelConfig, bank: Optional[DictionaryBank]) -
         """(E, ...) stacked per-expert factors sharing one dictionary."""
         if fcfg.applies_to(d_in, d_out) and bank is not None:
             r = bank.ensure(k, family, d_in, d_out)
-            return {"wd": jax.random.normal(k, (E, r, d_out), cfg.params_dtype)
-                    / np.sqrt(r)}
-        return {"w": jax.random.normal(k, (E, d_in, d_out), cfg.params_dtype)
-                / np.sqrt(d_in)}
+            return {"wd": (jax.random.normal(k, (E, r, d_out),
+                                             cfg.params_dtype)
+                           / np.sqrt(r)).astype(cfg.params_dtype)}
+        return {"w": (jax.random.normal(k, (E, d_in, d_out), cfg.params_dtype)
+                      / np.sqrt(d_in)).astype(cfg.params_dtype)}
 
     p["w_gate"] = expert_mats(ks[1], d, f, "moe_gate")
     p["w_up"] = expert_mats(ks[2], d, f, "moe_up")
@@ -275,8 +264,8 @@ def moe_ffn(
     body = functools.partial(
         _moe_sharded_body, cfg=cfg, sparse_train=sparse_train,
         ep_axis=ep_axis, tp_axis=tp_axis, n_ep=n_ep, dp_axes=dp)
-    fn = _shard_map(
-        body, mesh=mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P(dp, None, None), P(None, None),
                   wspec, wspec, wspec_down, dict_spec),
         out_specs=(P(dp, None, None), P()),

@@ -46,12 +46,15 @@ def init_rglru(key: jax.Array, cfg: ModelConfig, bank: Optional[DictionaryBank])
                            dtype=cfg.params_dtype),
         "w_out": init_linear(ks[2], w, d, fcfg, bank, "rglru_out",
                              dtype=cfg.params_dtype),
-        "conv_w": jax.random.normal(ks[3], (w, g.conv_width),
-                                    cfg.params_dtype) / np.sqrt(g.conv_width),
+        "conv_w": (jax.random.normal(ks[3], (w, g.conv_width),
+                                     cfg.params_dtype)
+                   / np.sqrt(g.conv_width)).astype(cfg.params_dtype),
         "conv_b": jnp.zeros((w,), cfg.params_dtype),
-        "w_a": jax.random.normal(ks[5], (w, w), cfg.params_dtype) / np.sqrt(w),
+        "w_a": (jax.random.normal(ks[5], (w, w), cfg.params_dtype)
+                / np.sqrt(w)).astype(cfg.params_dtype),
         "b_a": jnp.zeros((w,), cfg.params_dtype),
-        "w_i": jax.random.normal(ks[5], (w, w), cfg.params_dtype) / np.sqrt(w),
+        "w_i": (jax.random.normal(ks[5], (w, w), cfg.params_dtype)
+                / np.sqrt(w)).astype(cfg.params_dtype),
         "b_i": jnp.zeros((w,), cfg.params_dtype),
         "lambda": lam.astype(cfg.params_dtype),
     }

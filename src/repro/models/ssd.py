@@ -40,8 +40,9 @@ def init_ssd(key: jax.Array, cfg: ModelConfig, bank: Optional[DictionaryBank]) -
                                dtype=cfg.params_dtype),
         "out_proj": init_linear(ks[1], d_in, d, fcfg, bank, "ssd_out",
                                 dtype=cfg.params_dtype),
-        "conv_w": jax.random.normal(ks[2], (conv_ch, s.d_conv),
-                                    cfg.params_dtype) / np.sqrt(s.d_conv),
+        "conv_w": (jax.random.normal(ks[2], (conv_ch, s.d_conv),
+                                     cfg.params_dtype)
+                   / np.sqrt(s.d_conv)).astype(cfg.params_dtype),
         "conv_b": jnp.zeros((conv_ch,), cfg.params_dtype),
         # A_log: decay rates; dt_bias: per-head step bias; D: skip.
         "A_log": jnp.log(jax.random.uniform(ks[3], (H,), cfg.params_dtype,

@@ -129,9 +129,15 @@ class Model:
         lkeys = jax.random.split(k_layers, cfg.n_layers)
         if cfg.uniform_layers:
             kind = cfg.block_kind(0)
-            blocks = [self._init_block(lkeys[i], kind, bank)
-                      for i in range(cfg.n_layers)]
-            params["layers"] = jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
+            if bank is not None:
+                # The shared dictionaries are drawn from layer 0's keys as
+                # the bank first sees each family; the vmapped init below
+                # then finds every family present and draws no dictionary.
+                self._init_block(lkeys[0], kind, bank)
+            # One batched init of the stacked layers: the same values as
+            # stacking per-layer inits, without holding both at once.
+            params["layers"] = jax.vmap(
+                lambda k: self._init_block(k, kind, bank))(lkeys)
         else:
             params["layers"] = {
                 f"layer_{i:02d}": self._init_block(lkeys[i], cfg.block_kind(i),
@@ -503,8 +509,11 @@ class Model:
         Requires the paged cache layout (``pages``) and an attention-only
         stack: recurrent blocks have no variable-token mixed path (the
         serving engine gates them back to phase-serialized admission).
-        Returns all-position logits ``(B, S, V)``; the caller samples row
-        b's next token from column ``n_new[b] - 1`` and ignores the rest.
+        Returns ``(B, V)`` logits of each row's last fresh column ``n_new[b]
+        - 1`` (clamped into range; an inert row reads column 0), the
+        column the caller samples row b's next token from. The head runs on
+        those B columns only: all-position logits of a 512-wide chunk over a
+        152k vocabulary would take gigabytes.
         ``cache_index``/``slot_mask`` semantics match :meth:`decode_step`;
         the per-row chunk K/V is scattered into the paged lanes through the
         block tables after attention (pre-write lane view + causal in-row
@@ -525,9 +534,11 @@ class Model:
             caches=caches, cache_index=ci, mesh=mesh, sparse_train=False,
             unroll=cfg.unroll_decode, slot_mask=slot_mask, pages=pages,
             n_new=nn)
+        last = jnp.clip(nn - 1, 0, S - 1)
+        x = jnp.take_along_axis(x, last[:, None, None], axis=1)  # (B, 1, d)
         x = L.apply_norm(params["final_norm"], x)
         logits = L.lm_logits(params["lm_head"], params["embed"], x, cfg)
-        return logits, new_caches
+        return logits[:, 0], new_caches
 
     def prefill(self, params: Dict, batch: Dict, *, mesh=None,
                 max_len: int = 0) -> Tuple[jnp.ndarray, Any]:

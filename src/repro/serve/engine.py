@@ -269,10 +269,15 @@ class Engine:
         # spec rules to replication — the bit-exact fallback that lets
         # non-MoE compressed models serve on a mesh (the old blanket
         # refusal is retired; only compressed *MoE experts* remain
-        # unsupported, above).
+        # unsupported, above). Leaves already in their shards — what
+        # launch/serve.init_params builds — are kept as they are; only
+        # the rest move, each straight into its own shards.
         if self._tp > 1 and params is not None:
             pspecs = shd.param_specs(jax.eval_shape(lambda: params), mesh)
-            self.params = jax.device_put(params, shd.named(pspecs, mesh))
+            self.params = jax.tree.map(
+                lambda x, s: x if getattr(x, "sharding", None) == s
+                else jax.device_put(x, s),
+                params, shd.named(pspecs, mesh))
         self.max_len = max_len
         self.max_new = cfg_e.max_new_tokens
         self.mesh = mesh
@@ -547,15 +552,11 @@ class Engine:
                          for name, w in self._page_struct.items()}
             else:
                 pages = entry(self._page_struct)
-            logits, new_caches = dmodel.mixed_step(
-                params, {"inputs": tokens}, caches, lengths, n_new,
-                slot_mask=active, pages=pages, mesh=mesh)
-            S = tokens.shape[1]
             # The step's emitted token comes from chunk column n_new - 1
             # (clamped; inert rows read column 0 and the host ignores it).
-            last = jnp.clip(n_new - 1, 0, S - 1)
-            row = jnp.take_along_axis(logits, last[:, None, None],
-                                      axis=1)[:, 0]
+            row, new_caches = dmodel.mixed_step(
+                params, {"inputs": tokens}, caches, lengths, n_new,
+                slot_mask=active, pages=pages, mesh=mesh)
             row = jnp.where(nan_mask[:, None], jnp.nan, row)
             if sampled:
                 # Absolute position of the sampled token: lengths + n_new
@@ -771,6 +772,41 @@ class Engine:
                                   "cancelled by caller")
         return True
 
+    def compiled_steps(self) -> Dict[str, str]:
+        """Compiled HLO text of the jitted serving steps at this engine's
+        shapes: ``"decode"``, plus ``"mixed"`` when the mixed step is on —
+        greedy variants, the ones an all-greedy batch dispatches. For
+        auditing which kernels the hot path runs (a Pallas kernel shows as
+        a ``tpu_custom_call`` on a TPU)."""
+        n = self.num_slots
+        out = {"decode": self._decode.lower(*self._step_args(
+            np.zeros((n, 1), np.int32))).compile().as_text()}
+        if self._mixed is not None:
+            out["mixed"] = self._mixed.lower(*self._step_args(
+                np.zeros((n, self._chunk_width), np.int32),
+                np.zeros(n, np.int32))).compile().as_text()
+        return out
+
+    def _step_args(self, toks, n_new=None, inj=None):
+        """Positional arguments of one ``_decode`` dispatch (or ``_mixed``,
+        given ``n_new``) at the engine's current slot state — the one
+        signature both the serving loop and :meth:`compiled_steps` use."""
+        sl = self.slots
+        nan_mask = self._no_nan
+        if inj is not None:
+            m = inj.nan_mask()
+            if m is not None:
+                nan_mask = jnp.asarray(m)
+        lengths = (jnp.asarray(sl.lengths),)
+        if n_new is not None:
+            lengths += (jnp.asarray(n_new),)
+        tables = sl.pool.device_tables() if self.paged else {}
+        sampled = bool(np.any(self._temps[sl.active] > 0))
+        return (self.params, jnp.asarray(toks), sl.caches, *lengths,
+                jnp.asarray(sl.active), jnp.asarray(self._seeds),
+                jnp.asarray(self._temps), jnp.asarray(self._topks),
+                tables, nan_mask, sampled)
+
     def step(self, submits: Sequence[Request] = ()) -> StepResult:
         """ONE engine iteration — admit, one jitted mixed/decode dispatch,
         retire — exactly the old ``run`` loop body, externally driveable.
@@ -926,19 +962,8 @@ class Engine:
                     st.kv_bytes += (bs["visited"] * min(self._block_k, ring)
                                     * self._ring_layers[ring]
                                     * self._kv_token_bytes)
-                nan_mask = self._no_nan
-                if inj is not None:
-                    m = inj.nan_mask()
-                    if m is not None:
-                        nan_mask = jnp.asarray(m)
-                tables = sl.pool.device_tables()
-                sampled = bool(np.any(self._temps[sl.active] > 0))
                 nxt, sl.caches = self._mixed(
-                    self.params, jnp.asarray(toks), sl.caches,
-                    jnp.asarray(sl.lengths), jnp.asarray(n_new),
-                    jnp.asarray(sl.active), jnp.asarray(self._seeds),
-                    jnp.asarray(self._temps), jnp.asarray(self._topks),
-                    tables, nan_mask, sampled)
+                    *self._step_args(toks, n_new, inj))
                 nxt = np.asarray(nxt)  # the step's single host sync
                 self._device_time += self._chunk_width
                 st.steps += 1
@@ -1013,18 +1038,8 @@ class Engine:
                                 * self._ring_layers[ring]
                                 * self._kv_token_bytes)
 
-            nan_mask = self._no_nan
-            if inj is not None:
-                m = inj.nan_mask()
-                if m is not None:
-                    nan_mask = jnp.asarray(m)
-            tables = sl.pool.device_tables() if self.paged else {}
-            sampled = bool(np.any(self._temps[sl.active] > 0))
             nxt, sl.caches = self._decode(
-                self.params, jnp.asarray(cur[:, None]), sl.caches,
-                jnp.asarray(sl.lengths), jnp.asarray(sl.active),
-                jnp.asarray(self._seeds), jnp.asarray(self._temps),
-                jnp.asarray(self._topks), tables, nan_mask, sampled)
+                *self._step_args(cur[:, None], inj=inj))
             nxt = np.asarray(nxt)  # the step's single host sync
             self._device_time += 1
             st.steps += 1

@@ -132,25 +132,28 @@ class SlotKVCache:
                          + leaf.shape[ba + 2:])
                 return jnp.zeros(shape, leaf.dtype)
 
-            self.caches = jax.tree.map(paged_leaf, template, self.specs,
-                                       self.widths)
+            def make():
+                return jax.tree.map(paged_leaf, template, self.specs,
+                                    self.widths)
         else:
-            self.caches = model.init_cache(num_slots, cache_len)
+            def make():
+                return model.init_cache(num_slots, cache_len)
         # Tensor-parallel placement: kv leaves (pool pages or contiguous
         # lanes) are KV-head-sharded over the mesh's ``model`` axis — each
         # rank owns its heads' slice of every page — while block tables
-        # and all host-side slot metadata stay replicated. Placing the
-        # leaves here (not in the engine) means every downstream jit (the
-        # fused assign copy, CoW page copiers, the decode step) sees
-        # committed shardings and keeps them, so the cache never
-        # materializes unsharded on one device.
+        # and all host-side slot metadata stay replicated. The zeros are
+        # created straight into those shards (never whole on one device),
+        # and placing the leaves here (not in the engine) means every
+        # downstream jit (the fused assign copy, CoW page copiers, the
+        # decode step) sees committed shardings and keeps them.
         from repro.launch.mesh import tensor_parallel_size
         if tensor_parallel_size(mesh) > 1:
             from repro.launch import sharding as shd
-            specs = shd.slot_cache_specs(
-                jax.eval_shape(lambda: self.caches), mesh)
-            self.caches = jax.device_put(self.caches,
-                                         shd.named(specs, mesh))
+            specs = shd.slot_cache_specs(jax.eval_shape(make), mesh)
+            self.caches = jax.jit(make,
+                                  out_shardings=shd.named(specs, mesh))()
+        else:
+            self.caches = make()
         # host-side slot metadata
         self.active = np.zeros(num_slots, bool)
         self.lengths = np.zeros(num_slots, np.int32)

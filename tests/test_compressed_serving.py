@@ -14,8 +14,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.factorized import (FactorizationConfig, decompress_wd_leaf,
-                                   decompress_ws_entry, project_wd_leaves)
+from repro.core.factorized import (FactorizationConfig,
+                                   decompress_model_params, project_wd_leaves)
 from repro.models.transformer import Model
 from repro.serve import Engine, Request
 
@@ -33,46 +33,15 @@ def compressed_model(request):
     return m, params, mc, cparams, stats
 
 
-def _rebuild_wd(orig_wd, cnode):
-    """Dense W_D from the streams, preserving (L,)/(E,)/(L,E) leading dims."""
-    lead = orig_wd.shape[:-2]
-    r, d_out = orig_wd.shape[-2:]
-    keys = ("wd_first", "wd_deltas", "wd_vq", "wd_scale", "wd_offset",
-            "wd_bits")
-    flat = {k: jnp.reshape(cnode[k], (-1,) + cnode[k].shape[len(lead):])
-            for k in keys}
-    dense = jax.vmap(lambda q: decompress_wd_leaf(q, r))(flat)
-    return dense.reshape(lead + (r, d_out)).astype(orig_wd.dtype)
-
-
-def _reconstruct(orig, cpar):
-    """Zip-walk: replace every compressed stream group with its dense W_D."""
-    out = {}
-    for k, v in orig.items():
-        cv = cpar[k]
-        if isinstance(v, dict):
-            if "wd" in v and isinstance(cv, dict) and "wd_vq" in cv:
-                out[k] = {kk: vv for kk, vv in cv.items()
-                          if not kk.startswith("wd_")}
-                out[k]["wd"] = _rebuild_wd(v["wd"], cv)
-            else:
-                out[k] = _reconstruct(v, cv)
-        else:
-            out[k] = cv
-    return out
-
-
 def test_compressed_forward_equals_decompressed_dense(compressed_model):
     """Tight: the streamed forward is the SAME function as a dense forward
     through explicitly-decompressed factors — only reduction order may
     differ, so tolerance is float-noise, not quantization-noise."""
     m, params, mc, cparams, _ = compressed_model
-    recon = _reconstruct(params, cparams)
-    recon["dicts"] = {
-        f: decompress_ws_entry(cparams["dicts"][f],
-                               np.asarray(params["dicts"][f]).shape[0])
-        for f in params["dicts"]
-    }
+    recon = decompress_model_params(cparams, params)
+    shapes = jax.eval_shape(lambda: params)
+    assert jax.tree.structure(decompress_model_params(cparams, shapes)) \
+        == jax.tree.structure(params)
     toks = np.random.default_rng(7).integers(
         0, m.cfg.vocab_size, size=12).astype(np.int32)
     batch = {"inputs": jnp.asarray(toks)[None]}

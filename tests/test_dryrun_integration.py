@@ -17,8 +17,8 @@ def _run(body: str) -> dict:
         from repro.configs import get_config
         from repro.launch.steps import build_bundle
         from repro.launch.hlo_analysis import analyze_hlo
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         {body}
     """)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -73,8 +73,8 @@ def test_analyzer_multiplies_scan_bodies():
 def test_analyzer_collectives_and_pod_split():
     r = _run("""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("pod", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("pod", "model"))
         def f(x, w):
             return (x @ w).sum()
         xs = jax.ShapeDtypeStruct((16, 32), jnp.float32)
@@ -108,8 +108,8 @@ def test_moe_shard_map_matches_local_oracle():
         x = jax.random.normal(jax.random.key(1), (4, 8, cfg.d_model))
         y_local, aux_l = M.moe_ffn(lp["moe"], x, cfg=cfg, dicts=None,
                                    mesh=None)
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         y_ep, aux_e = jax.jit(lambda p, xx: M.moe_ffn(
             p, xx, cfg=cfg, dicts=None, mesh=mesh))(lp["moe"], x)
         rel = float(jnp.abs(y_ep.astype(jnp.float32)

@@ -139,3 +139,18 @@ def test_packed_forward_matches_separate():
         b = np.asarray(logits_packed[row, start:start + L], np.float32)
         rel = np.abs(a - b).max() / (np.abs(a).max() + 1e-9)
         assert rel < 0.02, f"request {i} packed != solo ({rel})"
+
+
+@pytest.mark.parametrize("factorized", [False, True])
+def test_bf16_param_dtype_holds_for_weight_leaves(factorized):
+    """A bfloat16 ``param_dtype`` gives bfloat16 weights: the init scale
+    (a numpy float) must not promote them to float32, which would double
+    a full-width model's footprint on the chip."""
+    for arch in ("qwen1.5-4b", "dbrx-132b", "recurrentgemma-2b"):
+        cfg = get_config(arch, "smoke", param_dtype="bfloat16",
+                         factorized=factorized)
+        shapes = jax.eval_shape(Model(cfg).init, jax.random.key(0))
+        wide = [jax.tree_util.keystr(p) for p, leaf
+                in jax.tree_util.tree_flatten_with_path(shapes)[0]
+                if leaf.dtype != jnp.bfloat16 and leaf.ndim >= 2]
+        assert not wide, (arch, wide)

@@ -121,9 +121,9 @@ def test_grad_compression_error_feedback():
     residual so the *running sum* converges to the true mean."""
     import os
     # use the local 1-device mesh: n_pods=1 path must be identity
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.optim import compress_pod_allreduce, init_ef_state
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     g = {"w": jnp.ones((4, 4))}
     ef = init_ef_state(g)
     out, ef2 = compress_pod_allreduce(g, ef, mesh, n_pods=1)

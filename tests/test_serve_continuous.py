@@ -328,3 +328,37 @@ def test_engine_honors_per_request_budgets_and_eos(smoke_model):
     # stopped at the FIRST eos occurrence (greedy often repeats tokens)
     assert by_rid[1].output == ref[:ref.index(eos) + 1]
     assert all(len(r.output) <= 8 for r in done)
+
+
+def test_launcher_requests_are_seeded_and_share_a_prefix():
+    """``make_requests``: one request per length, same seed -> same
+    prompts, and the last prompt opens with the first one's prefix."""
+    from repro.launch.serve import make_requests
+    a = make_requests([40, 9, 33], 256, max_new=[3, 4, 5], seed=3,
+                      shared_prefix=16)
+    b = make_requests([40, 9, 33], 256, max_new=[3, 4, 5], seed=3,
+                      shared_prefix=16)
+    assert [len(r.prompt) for r in a] == [40, 9, 33]
+    assert [r.max_new_tokens for r in a] == [3, 4, 5]
+    assert all(np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
+    assert np.array_equal(a[-1].prompt[:16], a[0].prompt[:16])
+    assert not np.array_equal(a[-1].prompt[16:24], a[0].prompt[16:24])
+
+
+def test_compiled_steps_returns_both_programs(smoke_model):
+    """``compiled_steps`` compiles the decode and the mixed step from the
+    same argument builder the serving loop dispatches with, after a run
+    that used both."""
+    from repro.serve import EngineConfig
+    cfg, m, params = smoke_model
+    eng = Engine(m, params, config=EngineConfig(max_len=16, num_slots=2,
+                                                max_new_tokens=3))
+    assert eng.mixed
+    rng = np.random.default_rng(0)
+    for rid, n in enumerate((20, 5)):
+        eng.submit(Request(rid=rid, prompt=rng.integers(
+            0, cfg.vocab_size, size=n).astype(np.int32), max_new_tokens=3))
+    assert all(r.status == "ok" for r in eng.run())
+    text = eng.compiled_steps()
+    assert set(text) == {"decode", "mixed"}
+    assert all("HloModule" in t for t in text.values())

@@ -230,3 +230,40 @@ print(json.dumps(rows))
         assert row["per_rank"] == pytest.approx(row["kvpt"] / int(n))
     toks = {row["tokens"] for row in r.values()}
     assert len(toks) == 1 and toks.pop() > 0
+
+
+def test_params_and_pages_built_straight_into_shards(mesh_cpu):
+    """``init_params(model, seed, mesh)`` creates every leaf in its shards
+    (each rank holds 1/4 of a column-parallel weight) with the same values
+    as a single-device init; the engine keeps those leaves as they are, and
+    its page pool is created KV-head-sharded."""
+    out = mesh_cpu(4, """
+        import json
+        from repro.launch.mesh import make_local_mesh
+        from repro.launch.serve import build_model, init_params
+        from repro.serve import Engine, EngineConfig
+        m = build_model("qwen1.5-4b", "smoke")
+        mesh = make_local_mesh(1, 4)
+        p = init_params(m, 0, mesh)
+        whole = init_params(m, 0)
+        same = all(bool(jnp.array_equal(a, b)) for a, b in
+                   zip(jax.tree.leaves(p), jax.tree.leaves(whole)))
+        wq = p["layers"]["attn"]["wq"]["w"]
+        eng = Engine(m, p, config=EngineConfig(max_len=16, num_slots=2),
+                     mesh=mesh)
+        kept = all(a is b for a, b in zip(jax.tree.leaves(eng.params),
+                                          jax.tree.leaves(p)))
+        kv = jax.tree.leaves(eng.slots.caches)[0]
+        print(json.dumps({
+            "same": same, "kept": kept, "wq_bytes": wq.nbytes,
+            "wq_shard_bytes": sorted({s.data.nbytes
+                                      for s in wq.addressable_shards}),
+            "kv_bytes": kv.nbytes,
+            "kv_shard_bytes": sorted({s.data.nbytes
+                                      for s in kv.addressable_shards}),
+            "kv_devices": len({s.device for s in kv.addressable_shards})}))
+    """)
+    assert out["same"] and out["kept"]
+    assert out["wq_shard_bytes"] == [out["wq_bytes"] // 4]
+    assert out["kv_shard_bytes"] == [out["kv_bytes"] // 4]
+    assert out["kv_devices"] == 4

@@ -152,6 +152,99 @@ def test_tda_paged_equals_contiguous_layout():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("quant,ring,window,block_q", [
+    (False, 32, None, None),  # one query tile covers the chunk
+    (False, 32, None, 4),     # tiled queries + in-row key tiles, padded tail
+    (True, 32, None, 4),      # int8 pool codes dequantized in VMEM
+    (False, 12, 12, 8),       # wrapped ring lanes under a window
+    (False, 32, None, 2),     # five query tiles: dead tiles repeat blocks
+])
+def test_tda_mixed_matches_reference(quant, ring, window, block_q):
+    """The mixed kernel equals the gathered jnp reference on every fresh
+    column (columns >= n_new are garbage by contract), whatever the query
+    tile — chunk rows, a decode row, an inert row and an empty lane."""
+    from repro.kernels.tda.ops import fused_mixed_attention
+    from repro.kernels.tda.tda import tda_mixed_attention
+    rng = np.random.default_rng(5)
+    B, P, ps, Hq, Hkv, D, S = 4, 20, 8, 4, 2, 16, 10
+    n = -(-ring // ps)
+    ci = np.asarray([0, 5, 30, 13], np.int32)
+    nn = np.asarray([10, 1, 7, 0], np.int32)
+    k, v, ks, vs, _ = _mk_paged(1, P, ps, Hkv, D, [0], rng, quant)
+    bt = jnp.asarray(rng.permutation(P)[:B * n].reshape(B, n), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, S, Hq, D)), jnp.float32)
+    kr = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
+    vr = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
+    ref = fused_mixed_attention(q, k, v, kr, vr, jnp.asarray(ci),
+                                jnp.asarray(nn), block_table=bt, ring=ring,
+                                window=window, k_scale=ks, v_scale=vs,
+                                use_kernel=False)
+    bounds = jnp.asarray(np.stack([ci, nn], axis=1))
+    out = tda_mixed_attention(q, k, v, kr, vr, bounds, bt, ks, vs,
+                              ring=ring, window=window, block_q=block_q,
+                              interpret=True)
+    assert out.shape == ref.shape
+    for b in range(B):
+        np.testing.assert_allclose(np.asarray(out)[b, :nn[b]],
+                                   np.asarray(ref)[b, :nn[b]],
+                                   rtol=1e-5, atol=1e-5)
+
+
+def _fetches(index_map, steps, *prefetch):
+    """Block copies a Pallas pipeline issues over ``steps``: one whenever
+    the block index differs from the previous step's."""
+    n, prev = 0, None
+    for idx in steps:
+        blk = tuple(int(x) for x in index_map(*idx, *prefetch))
+        n += blk != prev
+        prev = blk
+    return n
+
+
+@pytest.mark.parametrize("lo,hi", [(0, 0), (0, 1), (0, 13), (5, 31),
+                                   (17, 24), (0, 32)])
+def test_decode_fetches_follow_occupancy(lo, hi):
+    """A decode grid row fetches each block that meets ``[lo, hi)`` once
+    and nothing for the blocks it skips (an empty span pins one block)."""
+    from repro.kernels.tda.tda import _live_block
+    bk, nk = 8, 4
+    blocks = [int(_live_block(kb, lo, hi, bk, nk)) for kb in range(nk)]
+    live = [kb for kb in range(nk) if kb * bk < hi and kb * bk + bk > lo]
+    assert all(blocks[kb] == kb for kb in live)
+    assert len(set(blocks)) == max(len(live), 1)
+    assert blocks == sorted(blocks)
+
+
+def test_mixed_fetches_follow_occupancy():
+    """Mixed-step DMA: dead query tiles, cache steps past the resident span
+    and in-row key tiles past the diagonal fetch nothing. A decode row
+    reads its resident pages once; each live tile of a chunk row reads the
+    resident pages once more and its causal key tiles."""
+    from repro.kernels.tda.tda import _mixed_maps
+    nk, ps, ring, tq, nq, P = 4, 8, 32, 4, 3, 20
+    maps = _mixed_maps(nk=nk, nq=nq, tq=tq, ps=ps, ring=ring, P=P)
+    q_map, pool_map, _, row_map, out_map = maps
+    ci = np.asarray([0, 13, 30, 20, 9], np.int32)
+    nn = np.asarray([12, 1, 6, 0, 4], np.int32)
+    bounds = np.stack([ci, nn], axis=1)
+    bt = np.arange(5 * nk, dtype=np.int32).reshape(5, nk)
+    for b in range(len(ci)):
+        steps = [(b, qi, kb) for qi in range(nq) for kb in range(nk + nq)]
+        n_live = -(-nn[b] // tq)
+        n_res = max(-(-min(ci[b], ring) // ps), 1)
+        pool = _fetches(pool_map, steps, bounds, bt)
+        rows = _fetches(row_map, steps, bounds, bt)
+        # an inert row (no live tile) pins its last resident page
+        assert pool == (1 if n_live == 0 or n_res == 1
+                        else n_live * n_res), b
+        # tile 0 reads key tile 0; tile qi >= 1 re-reads 0 (unless the
+        # previous tile ended on it) and then 1..qi
+        assert rows == max(1, sum(qi + 1 for qi in range(n_live))
+                           - (n_live >= 2)), b
+        assert _fetches(q_map, steps, bounds, bt) == max(n_live, 1), b
+        assert _fetches(out_map, steps, bounds, bt) == nq, b
+
+
 # ---- property: predication changes work, never results --------------------
 
 

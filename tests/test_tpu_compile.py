@@ -1,0 +1,93 @@
+"""Compile the serving path's Pallas kernels for a described TPU v5e.
+
+Nothing runs: each test lowers and compiles one kernel at the widths the
+chip smoke serves (qwen1.5-4b: 20 heads x 128, pages of 128 tokens, mixed
+chunks of 512 columns, factorized rank 1664 with 208 nonzeros per column)
+for a chip that is described, not attached, and checks that the Mosaic
+kernel is in the program (``tpu_custom_call``). This catches what interpret
+mode cannot: block shapes the TPU tiling refuses, more VMEM than a kernel
+may use, primitives Mosaic cannot lower.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library at a time.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.dmm.ops import lut_matmul
+from repro.kernels.smm.ops import compressed_matmul
+from repro.kernels.tda.tda import (tda_decode_attention, tda_mixed_attention,
+                                   tda_paged_decode_attention)
+
+BF16, I32, U8, F32 = jnp.bfloat16, jnp.int32, jnp.uint8, jnp.float32
+B, PAGE, D = 8, 128, 128
+PAGES, PER_SLOT = 64, 8          # page pool, pages per slot lane
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("hq,hkv", [(20, 20), (48, 4)])
+def test_paged_decode_compiles(one_chip, hq, hkv):
+    text = _compiled_text(
+        lambda q, k, v, bd, bt: tda_paged_decode_attention(
+            q, k, v, bd, bt, interpret=False), one_chip,
+        ((B, hq, D), BF16), ((PAGES, PAGE, hkv, D), BF16),
+        ((PAGES, PAGE, hkv, D), BF16), ((B, 2), I32), ((B, PER_SLOT), I32))
+    assert "tpu_custom_call" in text
+
+
+def test_mixed_compiles_at_chunk_512(one_chip):
+    S, H = 512, 20
+    text = _compiled_text(
+        lambda q, k, v, kr, vr, bd, bt: tda_mixed_attention(
+            q, k, v, kr, vr, bd, bt, ring=PER_SLOT * PAGE, interpret=False),
+        one_chip, ((B, S, H, D), BF16), ((PAGES, PAGE, H, D), BF16),
+        ((PAGES, PAGE, H, D), BF16), ((B, S, H, D), BF16),
+        ((B, S, H, D), BF16), ((B, 2), I32), ((B, PER_SLOT), I32))
+    assert "tpu_custom_call" in text
+
+
+def test_contiguous_decode_compiles(one_chip):
+    S, H = PER_SLOT * PAGE, 20
+    text = _compiled_text(
+        lambda q, k, v, bd: tda_decode_attention(q, k, v, bd, block_k=PAGE,
+                                                 interpret=False),
+        one_chip, ((B, H, D), BF16), ((B, S, H, D), BF16),
+        ((B, S, H, D), BF16), ((B, 2), I32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m", [8, 4096])  # a decode step, a mixed step
+def test_compressed_linear_compiles(one_chip, m):
+    d_in, r, nnz, d_out = 2560, 1664, 208, 6912
+
+    def linear(x, codes, lut, first, deltas, vq, scale, offset, bits):
+        y = lut_matmul(x, codes, lut, interpret=False)
+        return compressed_matmul(y, first, deltas, vq, scale, offset,
+                                 value_bits=bits, interpret=False)
+
+    text = _compiled_text(
+        linear, one_chip, ((m, d_in), BF16), ((d_in // 2, r), U8),
+        ((16,), F32), ((d_out,), I32), ((nnz - 1, d_out), U8),
+        ((nnz, d_out), U8), ((), F32), ((), F32), ((), I32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
