@@ -1,0 +1,6 @@
+"""Operations the window's processed tokens need (``work.py``) over the
+window's seconds x chips x the chip's peak (model step, whole)."""
+
+
+def read(ctx):
+    return ctx.mfu()
