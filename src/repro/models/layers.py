@@ -39,6 +39,12 @@ from repro.models.common import ModelConfig
 
 NEG_INF = -1e30
 
+# Name scopes of a paged serving step's device work, carried into the
+# compiled program's op metadata (``op_name``): the write of the step's
+# K/V into the layer's page pool, and the attention call over it.
+KV_WRITE_SCOPE = "kv_write"
+ATTN_SCOPE = "attn_kernel"
+
 
 def constrain_batch(x: jnp.ndarray, mesh,
                     model_dim: Optional[int] = None) -> jnp.ndarray:
@@ -532,23 +538,13 @@ def attention_block(
         if quant:
             kcs = layer_view(cache["k_scale"])
             vcs = layer_view(cache["v_scale"])
-        o = fused_mixed_attention(
-            q, layer_view(cache["k"]), layer_view(cache["v"]),
-            k_row, v_row, ci, nn, block_table=bt, ring=ringw,
-            window=window, k_scale=kcs, v_scale=vcs,
-            use_kernel=use_kernel)
+        with jax.named_scope(ATTN_SCOPE):
+            o = fused_mixed_attention(
+                q, layer_view(cache["k"]), layer_view(cache["v"]),
+                k_row, v_row, ci, nn, block_table=bt, ring=ringw,
+                window=window, k_scale=kcs, v_scale=vcs,
+                use_kernel=use_kernel)
         o = o.reshape(B, S, cfg.n_heads * hd)
-
-        # Chunk scatter: token j lands at lane position (ci + j) % ringw.
-        # Only the last min(n_new, ringw) columns write — earlier columns
-        # of a wrapping chunk alias the same lane position and a duplicate
-        # scatter index would make the result order-dependent.
-        cols = jax.lax.iota(jnp.int32, S)[None, :]          # (1, S)
-        lanepos = (ci[:, None] + cols) % ringw              # (B, S)
-        wvalid = (cols < nn[:, None]) & (cols >= nn[:, None] - ringw)
-        page = jnp.take_along_axis(bt, lanepos // ps, axis=1)
-        phys = jnp.where(wvalid, page * ps + lanepos % ps, P * ps)
-        physf = phys.reshape(-1)
 
         def paged_write_chunk(buf, new):  # new: (B, S, ...)
             lv = layer_view(buf)  # (P, ps, ...)
@@ -561,14 +557,27 @@ def attention_block(
             return jax.lax.dynamic_update_slice(
                 buf, lv2[None], (layer_idx,) + (0,) * lv2.ndim)
 
-        if quant:
-            new_cache = {"k": paged_write_chunk(cache["k"], kq),
-                         "v": paged_write_chunk(cache["v"], vq),
-                         "k_scale": paged_write_chunk(cache["k_scale"], ksc),
-                         "v_scale": paged_write_chunk(cache["v_scale"], vsc)}
-        else:
-            new_cache = {"k": paged_write_chunk(cache["k"], k),
-                         "v": paged_write_chunk(cache["v"], v)}
+        with jax.named_scope(KV_WRITE_SCOPE):
+            # Chunk scatter: token j lands at lane position (ci + j) %
+            # ringw. Only the last min(n_new, ringw) columns write —
+            # earlier columns of a wrapping chunk alias the same lane
+            # position and a duplicate scatter index would make the result
+            # order-dependent.
+            cols = jax.lax.iota(jnp.int32, S)[None, :]          # (1, S)
+            lanepos = (ci[:, None] + cols) % ringw              # (B, S)
+            wvalid = (cols < nn[:, None]) & (cols >= nn[:, None] - ringw)
+            page = jnp.take_along_axis(bt, lanepos // ps, axis=1)
+            phys = jnp.where(wvalid, page * ps + lanepos % ps, P * ps)
+            physf = phys.reshape(-1)
+            if quant:
+                new_cache = {
+                    "k": paged_write_chunk(cache["k"], kq),
+                    "v": paged_write_chunk(cache["v"], vq),
+                    "k_scale": paged_write_chunk(cache["k_scale"], ksc),
+                    "v_scale": paged_write_chunk(cache["v_scale"], vsc)}
+            else:
+                new_cache = {"k": paged_write_chunk(cache["k"], k),
+                             "v": paged_write_chunk(cache["v"], v)}
     elif cache is not None and S == 1 and pages is not None:
         # ---- paged decode: lanes live in a page pool (serve/pages.py) ----
         # Logical lane coordinates are the contiguous layout's (canonical
@@ -578,15 +587,6 @@ def attention_block(
         ringw = pages["width"]  # logical lane width (static int)
         bt = pages["bt"]        # (B, n) int32; FREE sentinel == num_pages
         P = cache["k"].shape[-4]  # physical pages in this leaf's pool
-
-        pos = cache_index if window is None else cache_index % ringw
-        pos = jnp.reshape(pos, (-1,))
-        page = jnp.take_along_axis(bt, (pos // ps)[:, None], axis=1)[:, 0]
-        phys = page * ps + pos % ps
-        if slot_mask is not None:
-            # Inactive slots (and unallocated sentinel pages) land out of
-            # bounds — the scatter drops them, the lane stays untouched.
-            phys = jnp.where(jnp.reshape(slot_mask, (-1,)), phys, P * ps)
 
         # This layer's written pools. Attention reads these, never a
         # slice of the stacked cache after its update: reading the updated
@@ -605,42 +605,57 @@ def attention_block(
                 buf, views[name][None], (layer_idx,) + (0,) * lv.ndim)
 
         impl = resolve_decode_attn(cfg.decode_attn)
-        if slot_mask is not None:
-            cache_index = jnp.where(jnp.reshape(slot_mask, (-1,)),
-                                    cache_index, -1)
         if quant:
             kq, ksc = kv_quantize(k)
             vq, vsc = kv_quantize(v)
-            new_cache = {"k": paged_write("k", kq[:, 0]),
-                         "v": paged_write("v", vq[:, 0]),
-                         "k_scale": paged_write("k_scale", ksc[:, 0]),
-                         "v_scale": paged_write("v_scale", vsc[:, 0])}
-        else:
-            new_cache = {"k": paged_write("k", k[:, 0]),
-                         "v": paged_write("v", v[:, 0])}
+        with jax.named_scope(KV_WRITE_SCOPE):
+            pos = cache_index if window is None else cache_index % ringw
+            pos = jnp.reshape(pos, (-1,))
+            page = jnp.take_along_axis(bt, (pos // ps)[:, None],
+                                       axis=1)[:, 0]
+            phys = page * ps + pos % ps
+            if slot_mask is not None:
+                # Inactive slots (and unallocated sentinel pages) land out
+                # of bounds — the scatter drops them, the lane stays
+                # untouched.
+                phys = jnp.where(jnp.reshape(slot_mask, (-1,)), phys,
+                                 P * ps)
+            if quant:
+                new_cache = {"k": paged_write("k", kq[:, 0]),
+                             "v": paged_write("v", vq[:, 0]),
+                             "k_scale": paged_write("k_scale", ksc[:, 0]),
+                             "v_scale": paged_write("v_scale", vsc[:, 0])}
+            else:
+                new_cache = {"k": paged_write("k", k[:, 0]),
+                             "v": paged_write("v", v[:, 0])}
+        if slot_mask is not None:
+            cache_index = jnp.where(jnp.reshape(slot_mask, (-1,)),
+                                    cache_index, -1)
         # Ring lanes: every position < min(cache_index+1, ring) is valid
         # (canonical ring phase) — same bounds as the contiguous layout.
         hi = cache_index + 1 if window is None \
             else jnp.minimum(cache_index + 1, ringw)
-        if impl == "tda":
-            # The kernel consumes the page pools directly: the block table
-            # rides scalar prefetch and one page is one kv block.
-            o = decode_attention(
-                q, views["k"], views["v"], hi, k_scale=views.get("k_scale"),
-                v_scale=views.get("v_scale"), impl="tda",
-                block_k=cfg.decode_block_k, block_table=bt, mesh=mesh)
-        else:
-            # Dense path: gather each slot's lane view out of the pool
-            # (same data volume as reading a dense lane), then attend.
-            def lanes(name):
-                return gather_paged_lanes(views[name], bt)
-
-            if quant:
-                kc = kv_dequantize(lanes("k"), lanes("k_scale"), dt)
-                vc = kv_dequantize(lanes("v"), lanes("v_scale"), dt)
+        with jax.named_scope(ATTN_SCOPE):
+            if impl == "tda":
+                # The kernel consumes the page pools directly: the block
+                # table rides scalar prefetch and one page is one kv block.
+                o = decode_attention(
+                    q, views["k"], views["v"], hi,
+                    k_scale=views.get("k_scale"),
+                    v_scale=views.get("v_scale"), impl="tda",
+                    block_k=cfg.decode_block_k, block_table=bt, mesh=mesh)
             else:
-                kc, vc = lanes("k"), lanes("v")
-            o = decode_attention(q, kc, vc, hi, impl="dense", mesh=mesh)
+                # Dense path: gather each slot's lane view out of the pool
+                # (same data volume as reading a dense lane), then attend.
+                def lanes(name):
+                    return gather_paged_lanes(views[name], bt)
+
+                if quant:
+                    kc = kv_dequantize(lanes("k"), lanes("k_scale"), dt)
+                    vc = kv_dequantize(lanes("v"), lanes("v_scale"), dt)
+                else:
+                    kc, vc = lanes("k"), lanes("v")
+                o = decode_attention(q, kc, vc, hi, impl="dense", mesh=mesh)
         o = o.reshape(B, S, cfg.n_heads * hd)
     elif cache is not None and S == 1:
         # Decode: write this step's K/V at cache_index (ring for windowed).

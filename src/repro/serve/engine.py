@@ -142,6 +142,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.errors import AuditError, UnsupportedConfigError
 from repro.core.factorized import params_stream_bits
@@ -155,6 +156,7 @@ from repro.serve.config import RECURRENT_KINDS, EngineConfig
 from repro.serve.faults import FaultInjector, FaultPlan
 from repro.serve.kv_slots import SlotKVCache
 from repro.serve.pages import PrefixHit, prefix_digests
+from repro.serve import spans
 from repro.serve.sampling import sample_tokens_batch
 from repro.serve.scheduler import (
     TERMINAL_STATUSES,
@@ -204,12 +206,22 @@ class StepResult:
     ``(request, token)`` events in emission order (continuations resolved
     to their origin request), the requests that reached a terminal status
     this step, and the modeled device time after the step's dispatch —
-    the emission timestamp behind the inter-token-latency metrics."""
+    the emission timestamp behind the inter-token-latency metrics.
+
+    The step's dispatch, when there was one: ``kind`` (``"decode"`` or
+    ``"mixed"``; ``None`` when nothing was dispatched), per slot the lane
+    length before the dispatch (``starts``) and the fresh tokens it
+    processed (``n_new``: 1 for a decode row, the chunk for a chunk row, 0
+    for an inert or empty row), and the pool's pages in use (paged)."""
 
     emitted: List[Tuple[Request, int]] = dataclasses.field(
         default_factory=list)
     finished: List[Request] = dataclasses.field(default_factory=list)
     device_time: int = 0
+    kind: Optional[str] = None
+    starts: Optional[np.ndarray] = None
+    n_new: Optional[np.ndarray] = None
+    pages_in_use: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -473,6 +485,7 @@ class Engine:
         # run() is a thin loop over it). None = no session in flight.
         self._st: Optional[_RunState] = None
         self._events: Optional[List[Tuple[Request, int]]] = None
+        self._admitted: List[int] = []  # rids admitted by this step
 
         def prefill_fn(params, batch):
             rows, width = batch["inputs"].shape
@@ -616,6 +629,7 @@ class Engine:
           ``max_prompt_len``) still raises ``ValueError`` — that is a
           caller bug, not traffic — with ``status`` set for uniformity.
         """
+        req.submitted_s = time.perf_counter()
         if (self.max_pending is not None
                 and self.scheduler.pending() >= self.max_pending):
             self._finish_terminal(
@@ -643,7 +657,6 @@ class Engine:
             raise
         req._submit_clock = self._clock  # type: ignore[attr-defined]
         req._submit_dev = self._device_time  # type: ignore[attr-defined]
-        req._submit_wall = time.perf_counter()  # type: ignore[attr-defined]
 
     def run(self, arrivals: Optional[Sequence[Tuple[int, Request]]] = None
             ) -> List[Request]:
@@ -813,9 +826,15 @@ class Engine:
 
         ``submits`` are submitted after this step's clock tick, matching
         the arrival semantics of :meth:`run`. Returns the step's streamed
-        ``(request, token)`` events and newly terminal requests; call
-        :meth:`finish_run` once :meth:`has_work` goes False to collect the
-        session's ``done`` list and ``decode_stats``."""
+        ``(request, token)`` events, newly terminal requests and the
+        record of its dispatch; call :meth:`finish_run` once
+        :meth:`has_work` goes False to collect the session's ``done`` list
+        and ``decode_stats``. The call and each of its phases are host
+        spans of a profiler trace (``serve/spans.py``)."""
+        with TraceAnnotation(spans.STEP):
+            return self._step(submits)
+
+    def _step(self, submits: Sequence[Request]) -> StepResult:
         st = self._session()
         sl = self.slots
         inj = self._inj
@@ -841,43 +860,53 @@ class Engine:
             if self._terminal:  # shed/rejected by a submission
                 done.extend(self._terminal)
                 self._terminal.clear()
-            progressed = self._expire(done) > 0
-            if inj is not None and inj.forced_preempt() and sl.active.any():
-                victims = np.flatnonzero(sl.active)
-                victim = int(max(victims,
-                                 key=lambda v: self._admit_seq[v]))
-                if self._preempt_or_fail(victim, done):
-                    st.preempt_recovered += 1
-                st.preemptions += 1
-            if self.paged:
-                # Lanes grow one page at a time; make every active slot's
-                # next write position resident, preempting the youngest
-                # request(s) when the pool runs dry. Growth runs BEFORE
-                # admission so a fresh admission can only reserve pages the
-                # in-flight lanes don't need this step — together with
-                # assign_many's one-ahead allocation, an admitted request
-                # always survives to its first decode step.
-                rec, esc = self._ensure_pages(done)
-                st.preemptions += rec + esc
-                st.preempt_recovered += rec
-            if self.mixed:
-                # Expiry / forced preemption / page growth above may have
-                # released mid-prefill slots: drop their chunk state.
-                for s in range(self.num_slots):
-                    if not sl.active[s]:
-                        pending[s] = None
-                        pending_full[s] = None
+            with TraceAnnotation(spans.EXPIRE):
+                progressed = self._expire(done) > 0
+                if (inj is not None and inj.forced_preempt()
+                        and sl.active.any()):
+                    victims = np.flatnonzero(sl.active)
+                    victim = int(max(victims,
+                                     key=lambda v: self._admit_seq[v]))
+                    if self._preempt_or_fail(victim, done):
+                        st.preempt_recovered += 1
+                    st.preemptions += 1
+            with TraceAnnotation(spans.GROW):
+                if self.paged:
+                    # Lanes grow one page at a time; make every active
+                    # slot's next write position resident, preempting the
+                    # youngest request(s) when the pool runs dry. Growth
+                    # runs BEFORE admission so a fresh admission can only
+                    # reserve pages the in-flight lanes don't need this
+                    # step — together with assign_many's one-ahead
+                    # allocation, an admitted request always survives to
+                    # its first decode step.
+                    rec, esc = self._ensure_pages(done)
+                    st.preemptions += rec + esc
+                    st.preempt_recovered += rec
+                if self.mixed:
+                    # Expiry / forced preemption / page growth above may
+                    # have released mid-prefill slots: drop their chunk
+                    # state.
+                    for s in range(self.num_slots):
+                        if not sl.active[s]:
+                            pending[s] = None
+                            pending_full[s] = None
             if self.scheduler.pending():
                 free = sl.free_slots()
                 if free.size:
                     n_done = len(done)
-                    if self.mixed:
-                        admitted = self._admit_mixed(
-                            free, cur, emitted, budget, pending,
-                            pending_full, done)
-                    else:
-                        admitted = self._admit(free, cur, emitted, budget,
-                                               done)
+                    self._admitted = []
+                    with TraceAnnotation(spans.ADMIT) as span:
+                        if self.mixed:
+                            admitted = self._admit_mixed(
+                                free, cur, emitted, budget, pending,
+                                pending_full, done)
+                        else:
+                            admitted = self._admit(free, cur, emitted,
+                                                   budget, done)
+                        if self._admitted and TraceAnnotation.is_enabled():
+                            span.set_metadata(rids=" ".join(
+                                map(str, self._admitted)))
                     progressed |= admitted > 0 or len(done) > n_done
             active_ix = np.flatnonzero(sl.active)
             if self.audit:
@@ -907,111 +936,178 @@ class Engine:
                 # decode slot in ONE jitted fixed-shape call. Pure-decode
                 # iterations below keep the (B, 1) decode step — no chunk
                 # columns to pay for when nobody is prefilling.
-                S = self._chunk_width
-                left = self.prefill_budget
-                n_new = np.zeros(self.num_slots, np.int32)
-                order = sorted(active_ix,
-                               key=lambda s: self._admit_seq[s])
-                for s in order:
-                    if pending[s] is None:
-                        n_new[s] = 1  # decode row
-                    else:
-                        c = min(len(pending[s]), S)
-                        if left is not None:
-                            c = min(c, left)
-                            left -= c
-                        n_new[s] = c
-                # Chunk writes span [len, len + c): allocate + CoW each
-                # span (oldest first; dry pool preempts the youngest, like
-                # _ensure_pages — make_range_writable is all-or-nothing so
-                # the retry after eviction is safe).
-                for s in order:
-                    if (not sl.active[s] or pending[s] is None
-                            or n_new[s] <= 0):
-                        continue
-                    ok, rec, esc = self._grow_span(
-                        int(s), int(sl.lengths[s]) + int(n_new[s]), done)
-                    st.preemptions += rec + esc
-                    st.preempt_recovered += rec
-                    if not ok:
-                        # deferred (pool dry, this slot youngest): ride
-                        # this step as an inert row, chunk intact.
-                        n_new[s] = 0
-                for s in range(self.num_slots):
-                    if not sl.active[s]:
-                        pending[s] = None
-                        pending_full[s] = None
-                n_new = np.where(sl.active, n_new, 0).astype(np.int32)
-                active_ix = np.flatnonzero(sl.active)
-                if active_ix.size == 0:
-                    return self._step_result(res, n_done0)
-                toks = np.zeros((self.num_slots, S), np.int32)
-                for s in active_ix:
-                    if pending[s] is not None:
-                        c = int(n_new[s])
-                        toks[s, :c] = pending[s][:c]
-                    else:
-                        toks[s, 0] = cur[s]
+                with TraceAnnotation(spans.PACK):
+                    S = self._chunk_width
+                    left = self.prefill_budget
+                    n_new = np.zeros(self.num_slots, np.int32)
+                    order = sorted(active_ix,
+                                   key=lambda s: self._admit_seq[s])
+                    for s in order:
+                        if pending[s] is None:
+                            n_new[s] = 1  # decode row
+                        else:
+                            c = min(len(pending[s]), S)
+                            if left is not None:
+                                c = min(c, left)
+                                left -= c
+                            n_new[s] = c
+                    # Chunk writes span [len, len + c): allocate + CoW each
+                    # span (oldest first; dry pool preempts the youngest,
+                    # like _ensure_pages — make_range_writable is
+                    # all-or-nothing so the retry after eviction is safe).
+                    for s in order:
+                        if (not sl.active[s] or pending[s] is None
+                                or n_new[s] <= 0):
+                            continue
+                        ok, rec, esc = self._grow_span(
+                            int(s), int(sl.lengths[s]) + int(n_new[s]),
+                            done)
+                        st.preemptions += rec + esc
+                        st.preempt_recovered += rec
+                        if not ok:
+                            # deferred (pool dry, this slot youngest):
+                            # ride this step as an inert row, chunk intact.
+                            n_new[s] = 0
+                    for s in range(self.num_slots):
+                        if not sl.active[s]:
+                            pending[s] = None
+                            pending_full[s] = None
+                    n_new = np.where(sl.active, n_new, 0).astype(np.int32)
+                    active_ix = np.flatnonzero(sl.active)
+                    if active_ix.size == 0:
+                        return self._step_result(res, n_done0)
+                    toks = np.zeros((self.num_slots, S), np.int32)
+                    for s in active_ix:
+                        if pending[s] is not None:
+                            c = int(n_new[s])
+                            toks[s, :c] = pending[s][:c]
+                        else:
+                            toks[s, 0] = cur[s]
+                    for ring in self._attn_rings:
+                        bs = block_stats(
+                            np.where(sl.active,
+                                     np.minimum(sl.lengths + n_new, ring),
+                                     0),
+                            ring, min(self._block_k, ring))
+                        st.blocks_visited += bs["visited"]
+                        st.blocks_dense += bs["dense"]
+                        st.kv_bytes += (bs["visited"]
+                                        * min(self._block_k, ring)
+                                        * self._ring_layers[ring]
+                                        * self._kv_token_bytes)
+                    self._record_dispatch(res, "mixed", n_new)
+                with TraceAnnotation(spans.UPLOAD):
+                    args = self._step_args(toks, n_new, inj)
+                with TraceAnnotation(spans.DISPATCH):
+                    nxt, sl.caches = self._mixed(*args)
+                with TraceAnnotation(spans.SYNC):
+                    nxt = np.asarray(nxt)  # the step's single host sync
+                with TraceAnnotation(spans.RETIRE):
+                    self._device_time += self._chunk_width
+                    st.steps += 1
+                    st.mixed_steps += 1
+                    st.active_slot_steps += active_ix.size
+                    if self.paged:
+                        st.pages_used_steps += res.pages_in_use
+                    for s in active_ix:
+                        tok = int(nxt[s])
+                        req = sl.request[s]
+                        if tok < 0:
+                            sl.release(int(s))
+                            pending[s] = None
+                            pending_full[s] = None
+                            self._finish(req, "failed",
+                                         "non-finite logits (NaN/Inf) in "
+                                         "the mixed step", done)
+                            continue
+                        if pending[s] is not None:
+                            c = int(n_new[s])
+                            if c <= 0:
+                                continue  # budget-starved: nothing this step
+                            sl.advance_n(int(s), c)
+                            st.chunk_tokens += c
+                            rest = pending[s][c:]
+                            if len(rest):
+                                # still mid-prefill: the sampled column is
+                                # a mid-prompt continuation, never an output
+                                pending[s] = rest
+                                continue
+                            # Prefill complete: ``tok`` IS the first token
+                            # — sampled from the same logits position (and,
+                            # in sampled mode, the same key) as the
+                            # serialized prefill's first token. Publish the
+                            # prompt's full pages now that they hold their
+                            # final bytes.
+                            if self.prefix_share:
+                                self._publish_prefix(int(s),
+                                                     pending_full[s])
+                            pending[s] = None
+                            pending_full[s] = None
+                            self._emit(req, tok)
+                            self._note_ttft(req)
+                            emitted[s] = len(req.output)
+                            cur[s] = tok
+                            if emitted[s] >= budget[s] or tok == self.eos_id:
+                                self._finish(req, "ok", None, done)
+                                sl.release(int(s))
+                            continue
+                        sl.advance(s)
+                        self._emit(req, tok)
+                        emitted[s] += 1
+                        cur[s] = tok
+                        st.decoded_tokens += 1
+                        if emitted[s] >= budget[s] or tok == self.eos_id:
+                            self._finish(req, "ok", None, done)
+                            sl.release(s)
+                return self._step_result(res, n_done0)
+
+            with TraceAnnotation(spans.PACK):
+                # Predicated-kernel work accounting: the TDA grid visits
+                # only the kv blocks covering each active lane's occupancy
+                # (+1 for the token being written, clamped to the lane's
+                # ring width); dense is the full slot-table sweep. One term
+                # per distinct attention-lane ring among the layers.
                 for ring in self._attn_rings:
                     bs = block_stats(
-                        np.where(sl.active,
-                                 np.minimum(sl.lengths + n_new, ring), 0),
+                        np.where(sl.active, np.minimum(sl.lengths + 1, ring),
+                                 0),
                         ring, min(self._block_k, ring))
                     st.blocks_visited += bs["visited"]
                     st.blocks_dense += bs["dense"]
+                    # KV bytes this step: visited blocks x tokens/block,
+                    # once per attention layer sharing this ring shape.
                     st.kv_bytes += (bs["visited"] * min(self._block_k, ring)
                                     * self._ring_layers[ring]
                                     * self._kv_token_bytes)
-                nxt, sl.caches = self._mixed(
-                    *self._step_args(toks, n_new, inj))
+                self._record_dispatch(res, "decode",
+                                      sl.active.astype(np.int32))
+            with TraceAnnotation(spans.UPLOAD):
+                args = self._step_args(cur[:, None], inj=inj)
+            with TraceAnnotation(spans.DISPATCH):
+                nxt, sl.caches = self._decode(*args)
+            with TraceAnnotation(spans.SYNC):
                 nxt = np.asarray(nxt)  # the step's single host sync
-                self._device_time += self._chunk_width
+            with TraceAnnotation(spans.RETIRE):
+                self._device_time += 1
                 st.steps += 1
-                st.mixed_steps += 1
                 st.active_slot_steps += active_ix.size
                 if self.paged:
-                    st.pages_used_steps += sl.pool.pages_in_use()
+                    st.pages_used_steps += res.pages_in_use
                 for s in active_ix:
+                    sl.advance(s)
                     tok = int(nxt[s])
                     req = sl.request[s]
                     if tok < 0:
-                        sl.release(int(s))
-                        pending[s] = None
-                        pending_full[s] = None
+                        # Non-finite logits: quarantine exactly this slot —
+                        # free its pages, mark it failed, keep serving the
+                        # rest (their tokens are bit-identical by
+                        # construction: lanes are independent and the
+                        # poison never reached the caches).
+                        sl.release(s)
                         self._finish(req, "failed",
                                      "non-finite logits (NaN/Inf) in the "
-                                     "mixed step", done)
+                                     "decode step", done)
                         continue
-                    if pending[s] is not None:
-                        c = int(n_new[s])
-                        if c <= 0:
-                            continue  # budget-starved: nothing this step
-                        sl.advance_n(int(s), c)
-                        st.chunk_tokens += c
-                        rest = pending[s][c:]
-                        if len(rest):
-                            # still mid-prefill: the sampled column is a
-                            # mid-prompt continuation, never an output
-                            pending[s] = rest
-                            continue
-                        # Prefill complete: ``tok`` IS the first token —
-                        # sampled from the same logits position (and, in
-                        # sampled mode, the same key) as the serialized
-                        # prefill's first token. Publish the prompt's full
-                        # pages now that they hold their final bytes.
-                        if self.prefix_share:
-                            self._publish_prefix(int(s), pending_full[s])
-                        pending[s] = None
-                        pending_full[s] = None
-                        self._emit(req, tok)
-                        self._note_ttft(req)
-                        emitted[s] = len(req.output)
-                        cur[s] = tok
-                        if emitted[s] >= budget[s] or tok == self.eos_id:
-                            self._finish(req, "ok", None, done)
-                            sl.release(int(s))
-                        continue
-                    sl.advance(s)
                     self._emit(req, tok)
                     emitted[s] += 1
                     cur[s] = tok
@@ -1019,58 +1115,19 @@ class Engine:
                     if emitted[s] >= budget[s] or tok == self.eos_id:
                         self._finish(req, "ok", None, done)
                         sl.release(s)
-                return self._step_result(res, n_done0)
-
-            # Predicated-kernel work accounting: the TDA grid visits only
-            # the kv blocks covering each active lane's occupancy (+1 for
-            # the token being written, clamped to the lane's ring width);
-            # dense is the full slot-table sweep. One term per distinct
-            # attention-lane ring among the layers.
-            for ring in self._attn_rings:
-                bs = block_stats(
-                    np.where(sl.active, np.minimum(sl.lengths + 1, ring), 0),
-                    ring, min(self._block_k, ring))
-                st.blocks_visited += bs["visited"]
-                st.blocks_dense += bs["dense"]
-                # KV bytes this step: visited blocks x tokens/block, once
-                # per attention layer sharing this ring shape.
-                st.kv_bytes += (bs["visited"] * min(self._block_k, ring)
-                                * self._ring_layers[ring]
-                                * self._kv_token_bytes)
-
-            nxt, sl.caches = self._decode(
-                *self._step_args(cur[:, None], inj=inj))
-            nxt = np.asarray(nxt)  # the step's single host sync
-            self._device_time += 1
-            st.steps += 1
-            st.active_slot_steps += active_ix.size
-            if self.paged:
-                st.pages_used_steps += sl.pool.pages_in_use()
-            for s in active_ix:
-                sl.advance(s)
-                tok = int(nxt[s])
-                req = sl.request[s]
-                if tok < 0:
-                    # Non-finite logits: quarantine exactly this slot —
-                    # free its pages, mark it failed, keep serving the
-                    # rest (their tokens are bit-identical by
-                    # construction: lanes are independent and the poison
-                    # never reached the caches).
-                    sl.release(s)
-                    self._finish(req, "failed",
-                                 "non-finite logits (NaN/Inf) in the "
-                                 "decode step", done)
-                    continue
-                self._emit(req, tok)
-                emitted[s] += 1
-                cur[s] = tok
-                st.decoded_tokens += 1
-                if emitted[s] >= budget[s] or tok == self.eos_id:
-                    self._finish(req, "ok", None, done)
-                    sl.release(s)
             return self._step_result(res, n_done0)
         finally:
             self._events = None
+
+    def _record_dispatch(self, res: StepResult, kind: str,
+                         n_new: np.ndarray) -> None:
+        """Fill the step record of the dispatch about to run: its kind,
+        each slot's lane length and fresh tokens, the pool's pages."""
+        res.kind = kind
+        res.starts = self.slots.lengths.copy()
+        res.n_new = n_new.copy()
+        if self.paged:
+            res.pages_in_use = self.slots.pool.pages_in_use()
 
     def finish_run(self) -> List[Request]:
         """Close the stepping session: build ``decode_stats`` from the
@@ -1168,11 +1225,12 @@ class Engine:
             "mixed_steps": st.mixed_steps,
             "prefill_chunk_tokens": st.chunk_tokens,
             "ttft": {
-                r.rid: {"wall_s": float(r._ttft_wall),
+                r.rid: {"wall_s": r.first_token_s - (
+                            r.submitted_s if r.submitted_s is not None
+                            else r.first_token_s),
                         "clock": int(r._ttft_clock),
-                        "device_tokens": int(getattr(r, "_ttft_dev", 0)),
-                        "first_token_clock": int(r._first_token_clock)}
-                for r in done if hasattr(r, "_ttft_wall")},
+                        "device_tokens": int(getattr(r, "_ttft_dev", 0))}
+                for r in done if r.first_token_s is not None},
         }
         self._counts = {s: 0 for s in TERMINAL_STATUSES}
         self._inj = None
@@ -1311,17 +1369,23 @@ class Engine:
         """Record time-to-first-token the moment a request's FIRST output
         token lands (continuations resume with prior output, so only a
         genuine first token — len(output) == 1 — qualifies)."""
-        if len(target.output) != 1 or hasattr(target, "_ttft_wall"):
+        if len(target.output) != 1 or target.first_token_s is not None:
             return
-        now = time.perf_counter()
-        target._ttft_wall = (  # type: ignore[attr-defined]
-            now - getattr(target, "_submit_wall", now))
+        target.first_token_s = time.perf_counter()
         target._ttft_clock = (  # type: ignore[attr-defined]
             self._clock - getattr(target, "_submit_clock", self._clock))
-        target._first_token_clock = self._clock  # type: ignore[attr-defined]
         target._ttft_dev = (  # type: ignore[attr-defined]
             self._device_time - getattr(target, "_submit_dev",
                                         self._device_time))
+
+    def _note_admit(self, req: Request) -> Request:
+        """Count ``req`` as admitted by this step and stamp its origin's
+        first admission; returns the origin."""
+        target = getattr(req, "_origin", req)
+        if target.admitted_s is None:
+            target.admitted_s = time.perf_counter()
+        self._admitted.append(target.rid)
+        return target
 
     def _admit_mixed(self, free: np.ndarray, cur, emitted, budget,
                      pending, pending_full, done: List[Request]) -> int:
@@ -1346,7 +1410,7 @@ class Engine:
         n_processed = 0
         for req, _est in adms:
             n_processed += 1
-            target = getattr(req, "_origin", req)
+            target = self._note_admit(req)
             total_budget = min(target.max_new_tokens, self.max_new)
             if len(target.output) >= total_budget:
                 self._finish(target, "ok", None, done)  # nothing left
@@ -1408,6 +1472,7 @@ class Engine:
         target = getattr(req, "_origin", req)
         target.status = status
         target.status_reason = reason
+        target.finished_s = time.perf_counter()
         self._counts[status] += 1
         done.append(target)
 
@@ -1419,6 +1484,7 @@ class Engine:
         target = getattr(req, "_origin", req)
         target.status = status
         target.status_reason = reason
+        target.finished_s = time.perf_counter()
         self._counts[status] += 1
         self._terminal.append(target)
 
@@ -1750,6 +1816,8 @@ class Engine:
         n_processed = 0
         for adm in groups:
             n_processed += len(adm.requests)
+            for req in adm.requests:
+                self._note_admit(req)
             logits, caches, slots_of, hits = self._prefill_admission(adm)
             logits = np.asarray(logits)
             assigns = []  # whole group lands in ONE fused lane copy

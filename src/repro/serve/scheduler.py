@@ -82,6 +82,13 @@ class Request:
     output: Optional[List[int]] = None
     status: Optional[str] = None         # one of TERMINAL_STATUSES when done
     status_reason: Optional[str] = None  # human-readable cause for non-ok
+    # Host-clock stamps (time.perf_counter seconds): submit, first
+    # admission (a slot claimed or a prefill sweep), first output token,
+    # terminal status. A preempted request keeps its first stamps.
+    submitted_s: Optional[float] = None
+    admitted_s: Optional[float] = None
+    first_token_s: Optional[float] = None
+    finished_s: Optional[float] = None
 
     def __post_init__(self):
         if self.output is None:

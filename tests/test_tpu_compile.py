@@ -91,3 +91,42 @@ def test_compressed_linear_compiles(one_chip, m):
         ((16,), F32), ((d_out,), I32), ((nnz - 1, d_out), U8),
         ((nnz, d_out), U8), ((), F32), ((), F32), ((), I32))
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_paged_decode_step_moves_pool_layers_under_kv_write(one_chip):
+    """The whole decode step at qwen1.5-4b widths (two layers, a 16-page
+    pool): every operation that moves a whole layer of the stacked page
+    pool (the layer's slice, its relayout copy, the update back) carries
+    the ``kv_write`` name scope in its metadata, so a trace can charge
+    the pool's copies to the KV write."""
+    import re
+
+    from repro.launch.serve import build_model
+    from repro.models.layers import KV_WRITE_SCOPE
+
+    L, P, H = 2, 16, 20
+    model = build_model("qwen1.5-4b", "full", n_layers=L, decode_attn="tda")
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    pool = jax.ShapeDtypeStruct((L, P, PAGE, H, D), BF16, sharding=one_chip)
+
+    def decode(params, tokens, caches, lengths, active, bt):
+        logits, new = model.decode_step(
+            params, {"inputs": tokens}, caches, lengths, slot_mask=active,
+            pages={"bt": bt, "width": PER_SLOT * PAGE, "page_size": PAGE})
+        return jnp.argmax(logits[:, 0, :], -1), new
+
+    shapes = [((B, 1), I32), ((B,), I32), ((B,), jnp.bool_),
+              ((B, PER_SLOT), I32)]
+    t, ln, act, bt = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                      for s, dt in shapes]
+    text = jax.jit(decode, donate_argnums=(2,)).lower(
+        params, t, {"k": pool, "v": pool}, ln, act, bt).compile().as_text()
+    moves = re.compile(
+        rf"^\s*(?:ROOT\s+)?%?\S+\s*=\s*bf16\[\d+,{P},{PAGE},{H},{D}\]\S*\s+"
+        r"(copy|fusion|dynamic-slice|dynamic-update-slice)\(")
+    scoped = [line for line in text.splitlines() if moves.match(line)]
+    assert scoped
+    for line in scoped:
+        assert f"/{KV_WRITE_SCOPE}/" in line, line[:200]
