@@ -9,11 +9,15 @@ so the predicate never visits it), and runs the kernel.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Dict, Optional
 
 import jax.numpy as jnp
 
 from repro.kernels.common import resolve_interpret
+from repro.kernels.tda.page_write import (
+    paged_kv_write,
+    paged_kv_write_reference,
+)
 from repro.kernels.tda.ref import (
     block_stats,
     decode_attention_reference,
@@ -26,7 +30,7 @@ from repro.kernels.tda.tda import (
 )
 
 __all__ = ["fused_decode_attention", "fused_mixed_attention",
-           "gather_paged_lanes", "paged_flat_positions", "block_stats"]
+           "gather_paged_lanes", "write_paged_kv", "block_stats"]
 
 
 def _pad_seq(x: Optional[jnp.ndarray], target: int) -> Optional[jnp.ndarray]:
@@ -37,33 +41,68 @@ def _pad_seq(x: Optional[jnp.ndarray], target: int) -> Optional[jnp.ndarray]:
     return jnp.pad(x, widths)
 
 
-def paged_flat_positions(block_table: jnp.ndarray,
-                         page_size: int) -> jnp.ndarray:
-    """Expand block-table rows to flattened-pool positions: ``(R, n) ->
-    (R, n * page_size)`` with lane position ``p`` at ``bt[r, p //
-    page_size] * page_size + p % page_size``. THE paged addressing
-    contract — the lane-view gather, the assign scatter, and (in spirit)
-    the kernel's scalar-prefetch index map all speak it. Sentinel entries
-    (``FREE == num_pages``) land at ``>= num_pages * page_size``: callers
-    clamp for gathers (the garbage sits beyond every ``hi`` bound) and
-    rely on scatter-drop for writes."""
-    R, n = block_table.shape
-    return (block_table[:, :, None] * page_size
-            + jnp.arange(page_size)[None, None, :]).reshape(R, n * page_size)
+def gather_paged_lanes(pool: jnp.ndarray, block_table: jnp.ndarray,
+                       layer=None) -> jnp.ndarray:
+    """Materialize per-slot lane views out of a head-major page pool:
+    ``(P, Hkv, page_size, ...) + (B, n) -> (B, n * page_size, Hkv, ...)``,
+    token-major like a contiguous lane. With ``layer`` the pool is the
+    layer-stacked ``(L, P, Hkv, page_size, ...)`` and the layer is read in
+    the same gather (no layer view first). Sentinel table entries clamp
+    into range; the garbage they gather sits beyond every ``hi`` bound (a
+    slot's pages are a logical prefix), so the masked softmax never reads
+    it. This is the jnp-reference mirror of what the paged kernels'
+    scalar-prefetch index maps do per block."""
+    P = pool.shape[0 if layer is None else 1]
+    ids = jnp.clip(block_table, 0, P - 1)
+    pages = pool[ids] if layer is None else pool[layer, ids]
+    pages = jnp.swapaxes(pages, 2, 3)  # (B, n, page_size, Hkv, ...)
+    B, n, ps = pages.shape[:3]
+    return pages.reshape((B, n * ps) + pages.shape[3:])
 
 
-def gather_paged_lanes(pool: jnp.ndarray,
-                       block_table: jnp.ndarray) -> jnp.ndarray:
-    """Materialize per-slot lane views out of a physical page pool:
-    ``(P, page_size, ...) + (B, n) -> (B, n * page_size, ...)``. Sentinel
-    table entries clamp into range; the garbage they gather sits beyond
-    every ``hi`` bound (a slot's pages are a logical prefix), so the
-    masked softmax never reads it. This is the jnp-reference mirror of
-    what the paged kernel's scalar-prefetch index map does per block."""
-    P, ps = pool.shape[0], pool.shape[1]
-    flat = pool.reshape((P * ps,) + pool.shape[2:])
-    pos = jnp.clip(paged_flat_positions(block_table, ps), 0, P * ps - 1)
-    return jnp.take(flat, pos, axis=0)
+def _stacked(pools, layer):
+    """Kernel operands for a pool and its layer: an unstacked pool
+    ``(P, ...)`` becomes a one-layer stack (a bitcast) at layer 0."""
+    if layer is None:
+        return tuple(None if p is None else p[None] for p in pools), 0
+    return tuple(pools), layer
+
+
+def write_paged_kv(
+    pools: Dict[str, jnp.ndarray],  # (L?, P, Hkv, page_size, ...) leaves
+    news: Dict[str, jnp.ndarray],   # (B, S, Hkv, ...) token-major values
+    cache_index: jnp.ndarray,       # (B,): lane depth before the write
+    n_new: jnp.ndarray,             # (B,): columns to write, 0 = none
+    *,
+    block_table: jnp.ndarray,       # (B, n)
+    ring: int,                      # logical lane width
+    layer=None,                     # the layer of layer-stacked pools
+    after: Optional[jnp.ndarray] = None,
+    use_kernel: bool = True,
+    interpret: Optional[bool] = None,
+) -> Dict[str, jnp.ndarray]:
+    """Write a step's new K/V into its page pools: row ``b``'s column
+    ``j`` lands at lane position ``(cache_index + j) % ring`` (the last
+    ``ring`` columns of a wrapping chunk only). With ``use_kernel`` it is
+    :func:`~repro.kernels.tda.page_write.paged_kv_write`, in place, and
+    ``after`` orders it after a read of the old pools; otherwise (and for
+    int8 scales of a multi-token step) one XLA scatter per leaf at
+    ``(layer, page, :, offset)``, with the same result. Returns the new
+    pools under the names of ``pools``."""
+    names = list(pools)
+    S = news[names[0]].shape[1]
+    if not use_kernel or (S > 1 and "k_scale" in pools):
+        out = paged_kv_write_reference(
+            [pools[n] for n in names], [news[n] for n in names], layer,
+            cache_index, n_new, block_table, ring=ring)
+        return dict(zip(names, out))
+    stacked, lyr = _stacked([pools[n] for n in names], layer)
+    out = paged_kv_write(stacked, [news[n] for n in names], lyr,
+                         cache_index, n_new, block_table, after, ring=ring,
+                         interpret=resolve_interpret(interpret))
+    if layer is None:
+        out = [x[0] for x in out]
+    return dict(zip(names, out))
 
 
 def fused_decode_attention(
@@ -78,6 +117,7 @@ def fused_decode_attention(
     lut_table: Optional[jnp.ndarray] = None,  # AFU exp LUT (else exact exp)
     block_k: int = 128,
     block_table: Optional[jnp.ndarray] = None,  # (B, n): paged lane pool
+    layer=None,  # paged: the layer of a layer-stacked pool
     use_kernel: bool = True,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
@@ -96,28 +136,28 @@ def fused_decode_attention(
     on the serving decode path.
 
     ``block_table`` selects the **paged** layout: ``k``/``v`` (and scales)
-    are physical page pools ``(P, page_size, ...)`` and
-    ``block_table[b, i]`` names the physical page backing logical kv block
-    ``i`` of slot ``b`` — one page is one kv block, read via scalar
-    prefetch (``block_k`` is ignored; the page size is the block size).
-    Bounds stay in logical lane coordinates, so the ``[lo, hi)`` contract
-    is unchanged.
+    are head-major physical page pools ``(P, Hkv, page_size, ...)`` — or,
+    with ``layer``, the layer-stacked ``(L, P, Hkv, page_size, ...)``,
+    read in place at that layer — and ``block_table[b, i]`` names the
+    physical page backing logical kv block ``i`` of slot ``b`` — one page
+    is one kv block, read via scalar prefetch (``block_k`` is ignored; the
+    page size is the block size). Bounds stay in logical lane
+    coordinates, so the ``[lo, hi)`` contract is unchanged.
     """
     squeeze = q.ndim == 4
     if squeeze:
         q = q[:, 0]
     if block_table is not None:
         B = q.shape[0]
-        S = block_table.shape[1] * k.shape[1]  # logical lane width
+        S = block_table.shape[1] * k.shape[-2]  # logical lane width
         if not use_kernel:
+            def lanes(x):
+                return None if x is None \
+                    else gather_paged_lanes(x, block_table, layer)
+
             out = decode_attention_reference(
-                q, gather_paged_lanes(k, block_table),
-                gather_paged_lanes(v, block_table), lengths,
-                k_scale=None if k_scale is None
-                else gather_paged_lanes(k_scale, block_table),
-                v_scale=None if v_scale is None
-                else gather_paged_lanes(v_scale, block_table),
-                window=window)
+                q, lanes(k), lanes(v), lengths, k_scale=lanes(k_scale),
+                v_scale=lanes(v_scale), window=window)
             return (out.astype(q.dtype)[:, None] if squeeze
                     else out.astype(q.dtype))
         hi = jnp.clip(jnp.broadcast_to(jnp.reshape(lengths, (-1,)), (B,)),
@@ -125,8 +165,10 @@ def fused_decode_attention(
         lo = jnp.zeros_like(hi) if window is None \
             else jnp.maximum(hi - window, 0)
         bounds = jnp.stack([lo, hi], axis=1).astype(jnp.int32)
+        (k, v, k_scale, v_scale), layer = _stacked(
+            (k, v, k_scale, v_scale), layer)
         out = tda_paged_decode_attention(
-            q, k, v, bounds, block_table.astype(jnp.int32), k_scale,
+            q, k, v, layer, bounds, block_table.astype(jnp.int32), k_scale,
             v_scale, lut_table,
             interpret=resolve_interpret(interpret)).astype(q.dtype)
         return out[:, None] if squeeze else out
@@ -153,7 +195,7 @@ def fused_decode_attention(
 
 def fused_mixed_attention(
     q: jnp.ndarray,      # (B, S, Hq, D) chunk queries, left-aligned
-    k: jnp.ndarray,      # (P, page_size, Hkv, D) PRE-write page pool
+    k: jnp.ndarray,      # (L?, P, Hkv, page_size, D) PRE-write page pool
     v: jnp.ndarray,
     k_row: jnp.ndarray,  # (B, S, Hkv, D) fp this-chunk keys
     v_row: jnp.ndarray,
@@ -163,9 +205,10 @@ def fused_mixed_attention(
     block_table: jnp.ndarray,  # (B, n) paged lane pool table
     ring: int,                 # logical lane width
     window: Optional[int] = None,
-    k_scale: Optional[jnp.ndarray] = None,  # (P, page_size, Hkv)
+    k_scale: Optional[jnp.ndarray] = None,  # (L?, P, Hkv, page_size)
     v_scale: Optional[jnp.ndarray] = None,
     lut_table: Optional[jnp.ndarray] = None,
+    layer=None,  # the layer of a layer-stacked pool
     use_kernel: bool = True,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
@@ -184,17 +227,19 @@ def fused_mixed_attention(
     ci = jnp.reshape(cache_index, (-1,)).astype(jnp.int32)
     nn = jnp.reshape(n_new, (-1,)).astype(jnp.int32)
     if not use_kernel:
+        def lanes(x):
+            return None if x is None \
+                else gather_paged_lanes(x, block_table, layer)
+
         out = mixed_attention_reference(
-            q, gather_paged_lanes(k, block_table),
-            gather_paged_lanes(v, block_table), k_row, v_row, ci, nn,
-            ring=ring, window=window,
-            k_scale=None if k_scale is None
-            else gather_paged_lanes(k_scale, block_table),
-            v_scale=None if v_scale is None
-            else gather_paged_lanes(v_scale, block_table))
+            q, lanes(k), lanes(v), k_row, v_row, ci, nn, ring=ring,
+            window=window, k_scale=lanes(k_scale), v_scale=lanes(v_scale))
         return out.astype(q.dtype)
     bounds = jnp.stack([ci, nn], axis=1).astype(jnp.int32)
+    (k, v, k_scale, v_scale), layer = _stacked((k, v, k_scale, v_scale),
+                                               layer)
     return tda_mixed_attention(
-        q, k, v, k_row, v_row, bounds, block_table.astype(jnp.int32),
-        k_scale, v_scale, lut_table, ring=ring, window=window,
+        q, k, v, jnp.swapaxes(k_row, 1, 2), jnp.swapaxes(v_row, 1, 2),
+        layer, bounds, block_table.astype(jnp.int32), k_scale, v_scale,
+        lut_table, ring=ring, window=window,
         interpret=resolve_interpret(interpret)).astype(q.dtype)

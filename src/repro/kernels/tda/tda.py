@@ -35,17 +35,20 @@ AFU's 64-entry piecewise-linear exp — the same table
 modelling the chip's LUT-assisted AFU on the decode path.
 
 **Paged variant** (:func:`tda_paged_decode_attention`): KV lanes live in a
-physical page pool (``serve/pages.py``) and a per-slot int32 block table
-maps logical kv block ``i`` to its physical page — one page is exactly one
-kv block. Bounds and block tables ride *scalar prefetch*
+layer-stacked pool of head-major physical pages ``(L, P, Hkv, page_size,
+D)`` (``serve/pages.py``; the layout is stated in ``docs/serving.md``)
+and a per-slot int32 block table maps logical kv block ``i`` to its
+physical page — one page is exactly one kv block. The layer index, bounds
+and block tables ride *scalar prefetch*
 (``pltpu.PrefetchScalarGridSpec``) so the K/V block specs can DMA the
-right physical page before the kernel body runs; everything else — the
-``[lo, hi)`` predication over **logical** block positions, online softmax,
-in-VMEM int8 dequant — is byte-identical to the contiguous kernel (the
-two share one body). Unallocated table entries carry an out-of-bounds
-sentinel; their logical blocks always sit outside ``[lo, hi)`` (a slot's
-pages are a logical prefix), so predication skips them and the index map
-only has to clamp.
+right physical page of the right layer straight out of the stacked pool
+before the kernel body runs; everything else — the ``[lo, hi)``
+predication over **logical** block positions, online softmax, in-VMEM
+int8 dequant — is the contiguous kernel's (the two share one body; only
+the block's axis order differs). Unallocated table entries carry an
+out-of-bounds sentinel; their logical blocks always sit outside ``[lo,
+hi)`` (a slot's pages are a logical prefix), so predication skips them and
+the index map only has to clamp.
 """
 from __future__ import annotations
 
@@ -76,10 +79,14 @@ def _exp(x, table):
 
 def _tda_body(lo, hi, q_ref, k_ref, v_ref, ks_ref, vs_ref, table,
               o_ref, o_acc, m_acc, l_acc, *, bk: int, groups: int,
-              quant: bool):
+              quant: bool, paged: bool):
     """Shared kernel body: init / predicated online-softmax block / finish.
     The contiguous and paged kernels differ only in how ``lo``/``hi`` (and
-    the K/V blocks) reach the grid step; the math is this one function."""
+    the K/V blocks) reach the grid step, and in the block's layout: a
+    contiguous block is token-major ``(bk, Hkv, D)``, a page is head-major
+    ``(Hkv, page_size, D)``; the math is this one function."""
+    lead = (0, 0) if paged else (0,)  # unit block dims before the block
+    ha = 0 if paged else 1            # kv-head axis of a K/V block
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
 
@@ -97,17 +104,17 @@ def _tda_body(lo, hi, q_ref, k_ref, v_ref, ks_ref, vs_ref, table,
     @pl.when((blk0 < hi) & (blk0 + bk > lo))
     def _attend():
         q = q_ref[0].astype(jnp.float32)          # (Hq, D)
-        k = k_ref[0].astype(jnp.float32)          # (bk, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[lead].astype(jnp.float32)  # (bk, Hkv, D) | (Hkv, bk, D)
+        v = v_ref[lead].astype(jnp.float32)
         if quant:  # in-VMEM dequant: codes * per-(token, head) scale
-            k = k * ks_ref[0][..., None]
-            v = v * vs_ref[0][..., None]
+            k = k * ks_ref[lead][..., None]
+            v = v * vs_ref[lead][..., None]
         Hq, D = q.shape
-        Hkv = k.shape[1]
+        Hkv = k.shape[ha]
         qg = q.reshape(Hkv, groups, D)
         # scores (Hkv, G, bk): batch over kv heads, contract head_dim
         s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
+            qg, k, (((2,), (2,)), ((0,), (ha,))),
             preferred_element_type=jnp.float32) * (1.0 / np.sqrt(D))
         pos = blk0 + jax.lax.iota(jnp.int32, bk)
         valid = (pos >= lo) & (pos < hi)
@@ -121,7 +128,7 @@ def _tda_body(lo, hi, q_ref, k_ref, v_ref, ks_ref, vs_ref, table,
                       + p.sum(-1)).reshape(Hq, 1)
         # P@V (Hkv, G, D): contract the block axis, batch over kv heads
         pv = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
+            p, v, (((2,), (1 - ha,)), ((0,), (ha,))),
             preferred_element_type=jnp.float32)
         o_acc[...] = (o_acc[...].reshape(Hkv, groups, D) * alpha[..., None]
                       + pv).reshape(Hq, D)
@@ -134,13 +141,17 @@ def _tda_body(lo, hi, q_ref, k_ref, v_ref, ks_ref, vs_ref, table,
                     jnp.maximum(l_acc[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _tda_kernel(bounds_ref, *refs, bk: int, groups: int, quant: bool,
-                lut: bool, paged: bool):
+def _tda_kernel(*refs, bk: int, groups: int, quant: bool, lut: bool,
+                paged: bool):
     """One grid step of either layout. Bounds arrive as a scalar-prefetch
-    ref indexed by the slot program id; the paged kernel's block-table
-    prefetch ref is consumed by the K/V index maps, not the body, and
-    predication runs over *logical* block positions in both layouts."""
+    ref indexed by the slot program id; the paged kernel's layer and
+    block-table prefetch refs are consumed by the K/V index maps, not the
+    body, and predication runs over *logical* block positions in both
+    layouts."""
     refs = list(refs)
+    if paged:
+        refs.pop(0)  # layer
+    bounds_ref = refs.pop(0)
     if paged:
         refs.pop(0)  # block table
     q_ref, k_ref, v_ref = refs[:3]
@@ -152,7 +163,7 @@ def _tda_kernel(bounds_ref, *refs, bk: int, groups: int, quant: bool,
     b = pl.program_id(0)
     _tda_body(bounds_ref[b, 0], bounds_ref[b, 1], q_ref, k_ref, v_ref,
               ks_ref, vs_ref, table, o_ref, o_acc, m_acc, l_acc,
-              bk=bk, groups=groups, quant=quant)
+              bk=bk, groups=groups, quant=quant, paged=paged)
 
 
 def _live_block(kb, lo, hi, bk: int, nk: int):
@@ -229,63 +240,65 @@ def tda_decode_attention(q, k, v, bounds, k_scale=None, v_scale=None,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def tda_paged_decode_attention(q, k, v, bounds, block_table, k_scale=None,
-                               v_scale=None, lut_table=None, *,
-                               interpret: bool = True) -> jnp.ndarray:
-    """Fused slot-decode attention over a paged KV lane pool.
+def tda_paged_decode_attention(q, k, v, layer, bounds, block_table,
+                               k_scale=None, v_scale=None, lut_table=None,
+                               *, interpret: bool = True) -> jnp.ndarray:
+    """Fused slot-decode attention over a paged KV lane pool, read in place.
 
-    q (B, Hq, D); k/v are physical page pools (P, page_size, Hkv, D) — fp
-    or int8 codes (then ``k_scale``/``v_scale`` (P, page_size, Hkv) must be
-    given); bounds (B, 2) int32 per-slot ``[lo, hi)`` spans in *logical*
-    lane coordinates; block_table (B, n) int32 maps logical kv block ``i``
-    of slot ``b`` to its physical page (one page = one kv block;
-    ``block_k == page_size``). Entries whose logical block lies outside
-    ``[lo, hi)`` may carry any value — including the allocator's
-    out-of-bounds FREE sentinel — because predication skips them; the
-    index map clamps so the prefetch itself stays in range. Returns
-    (B, Hq, D) f32.
+    q (B, Hq, D); k/v are the layer-stacked physical page pools (L, P, Hkv,
+    page_size, D) in the head-major page layout (``docs/serving.md``) —
+    fp or int8 codes (then ``k_scale``/``v_scale`` (L, P, Hkv, page_size)
+    must be given); ``layer`` (int32 scalar or (1,)) picks the layer, and
+    rides scalar prefetch with the bounds and the table, so no layer view
+    of the pool is ever materialized. bounds (B, 2) int32 per-slot
+    ``[lo, hi)`` spans in *logical* lane coordinates; block_table (B, n)
+    int32 maps logical kv block ``i`` of slot ``b`` to its physical page
+    (one page = one kv block; ``block_k == page_size``). Entries whose
+    logical block lies outside ``[lo, hi)`` may carry any value —
+    including the allocator's out-of-bounds FREE sentinel — because
+    predication skips them; the index map clamps so the prefetch itself
+    stays in range. Returns (B, Hq, D) f32.
     """
     B, Hq, D = q.shape
-    P, ps, Hkv = k.shape[0], k.shape[1], k.shape[2]
+    P, Hkv, ps = k.shape[1], k.shape[2], k.shape[3]
     nk = block_table.shape[1]
     assert Hq % Hkv == 0, (Hq, Hkv)
     quant = k_scale is not None
     lut = lut_table is not None
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
 
     def page(b, kb, bounds_ref, bt_ref):
         live = _live_block(kb, bounds_ref[b, 0], bounds_ref[b, 1], ps, nk)
         return jnp.clip(bt_ref[b, live], 0, P - 1)
 
+    def pool_map(b, kb, lyr, bounds, bt):
+        return (lyr[0], page(b, kb, bounds, bt), 0, 0, 0)
+
+    def scale_map(b, kb, lyr, bounds, bt):
+        return (lyr[0], page(b, kb, bounds, bt), 0, 0)
+
+    def slot_map(b, kb, lyr, bounds, bt):
+        return (b, 0, 0)
+
     in_specs = [
-        pl.BlockSpec((1, Hq, D), lambda b, kb, bounds, bt: (b, 0, 0)),
-        pl.BlockSpec((1, ps, Hkv, D),
-                     lambda b, kb, bounds, bt: (page(b, kb, bounds, bt),
-                                                0, 0, 0)),
-        pl.BlockSpec((1, ps, Hkv, D),
-                     lambda b, kb, bounds, bt: (page(b, kb, bounds, bt),
-                                                0, 0, 0)),
+        pl.BlockSpec((1, Hq, D), slot_map),
+        pl.BlockSpec((1, 1, Hkv, ps, D), pool_map),
+        pl.BlockSpec((1, 1, Hkv, ps, D), pool_map),
     ]
-    args = [bounds, block_table, q, k, v]
+    args = [layer, bounds, block_table, q, k, v]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, ps, Hkv),
-                         lambda b, kb, bounds, bt: (page(b, kb, bounds, bt),
-                                                    0, 0)),
-            pl.BlockSpec((1, ps, Hkv),
-                         lambda b, kb, bounds, bt: (page(b, kb, bounds, bt),
-                                                    0, 0)),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, Hkv, ps), scale_map),
+                     pl.BlockSpec((1, 1, Hkv, ps), scale_map)]
         args += [k_scale, v_scale]
     if lut:
-        in_specs.append(pl.BlockSpec((LUT_SIZE,),
-                                     lambda b, kb, bounds, bt: (0,)))
+        in_specs.append(pl.BlockSpec((LUT_SIZE,), lambda *_: (0,)))
         args.append(lut_table)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # bounds + block table ride SMEM prefetch
+        num_scalar_prefetch=3,  # layer, bounds, block table ride SMEM
         grid=(B, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, kb, bounds, bt: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, D), slot_map),
         scratch_shapes=[
             pltpu.VMEM((Hq, D), jnp.float32),  # o accumulator
             pltpu.VMEM((Hq, 1), jnp.float32),  # running max
@@ -301,17 +314,19 @@ def tda_paged_decode_attention(q, k, v, bounds, block_table, k_scale=None,
     )(*args)
 
 
-def _tda_mixed_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, kr_ref,
-                      vr_ref, *rest, bk: int, groups: int, quant: bool,
-                      lut: bool, ring: int, window, tq: int, nk: int):
+def _tda_mixed_kernel(layer_ref, bounds_ref, bt_ref, q_ref, k_ref, v_ref,
+                      kr_ref, vr_ref, *rest, bk: int, groups: int,
+                      quant: bool, lut: bool, ring: int, window, tq: int,
+                      nk: int):
     """Mixed (multi-query) grid step over one ``tq``-column query tile:
     cache blocks ``0..nk-1`` are predicated on the slot's pre-write
     occupancy exactly like decode, then the in-row chunk's key tiles at or
     before the query tile fold in, and the last step normalizes.
     Online-softmax state is per (query column, head) of this tile only —
     scratch rows are laid out (Hkv, tq, G) — so VMEM is bounded by ``tq``,
-    not by the chunk width."""
-    del bt_ref  # consumed by the in_specs index maps
+    not by the chunk width. Pool pages and in-row key tiles are both
+    head-major, ``(Hkv, keys, D)``."""
+    del layer_ref, bt_ref  # consumed by the in_specs index maps
     rest = list(rest)
     ks_ref = rest.pop(0) if quant else None
     vs_ref = rest.pop(0) if quant else None
@@ -335,15 +350,15 @@ def _tda_mixed_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, kr_ref,
         l_acc[...] = jnp.zeros_like(l_acc)
 
     def attend(kblk, vblk, valid):
-        """One online-softmax block over ``kblk`` (bkk, Hkv, D) with a
+        """One online-softmax block over ``kblk`` (Hkv, bkk, D) with a
         per-(query, key) ``valid`` mask (tq, bkk)."""
         q = q_ref[0].astype(jnp.float32)  # (tq, Hq, D)
         Hq, D = q.shape[1], q.shape[2]
-        Hkv = kblk.shape[1]
+        Hkv = kblk.shape[0]
         qg = q.reshape(tq, Hkv, groups, D).transpose(1, 0, 2, 3)
         qg = qg.reshape(Hkv, tq * groups, D)
         s = jax.lax.dot_general(
-            qg, kblk, (((2,), (2,)), ((0,), (1,))),
+            qg, kblk, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * (1.0 / np.sqrt(D))
         vmask = valid[None, :, None, :]  # (1, tq, 1, bkk)
         s4 = s.reshape(Hkv, tq, groups, -1)
@@ -357,7 +372,7 @@ def _tda_mixed_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, kr_ref,
         l_acc[...] = (l_acc[...].reshape(Hkv, tq * groups) * alpha
                       + p.sum(-1)).reshape(tq * Hq, 1)
         pv = jax.lax.dot_general(
-            p, vblk, (((2,), (0,)), ((0,), (1,))),
+            p, vblk, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         o_acc[...] = (o_acc[...].reshape(Hkv, tq * groups, D)
                       * alpha[..., None] + pv).reshape(tq * Hq, D)
@@ -368,11 +383,11 @@ def _tda_mixed_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, kr_ref,
 
     @pl.when(live & (kb < nk) & (blk0 < hi))
     def _cache_block():
-        k = k_ref[0].astype(jnp.float32)  # (bk, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)  # (Hkv, bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            k = k * ks_ref[0][..., None]
-            v = v * vs_ref[0][..., None]
+            k = k * ks_ref[0, 0][..., None]
+            v = v * vs_ref[0, 0][..., None]
         # Lane position r holds absolute token p_r = ci-1 - ((ci-1-r) % ring)
         # (canonical ring phase walked back from the newest resident token);
         # one formula covers full lanes (p_r == r for r < ci) and wrapped
@@ -389,7 +404,7 @@ def _tda_mixed_kernel(bounds_ref, bt_ref, q_ref, k_ref, v_ref, kr_ref,
 
     @pl.when(live & (rt >= 0) & (rt <= qi) & (rt * tq < nn))
     def _row_block():
-        kr = kr_ref[0].astype(jnp.float32)  # (tq, Hkv, D)
+        kr = kr_ref[0].astype(jnp.float32)  # (Hkv, tq, D)
         vr = vr_ref[0].astype(jnp.float32)
         j = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 0)
         i = rt * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 1)
@@ -425,17 +440,18 @@ def mixed_query_tile(n_heads: int) -> int:
     return t
 
 
-def _pad_cols(x, target: int):
-    if x.shape[1] == target:
+def _pad_cols(x, target: int, axis: int = 1):
+    if x.shape[axis] == target:
         return x
     widths = [(0, 0)] * x.ndim
-    widths[1] = (0, target - x.shape[1])
+    widths[axis] = (0, target - x.shape[axis])
     return jnp.pad(x, widths)
 
 
 def _mixed_maps(*, nk: int, nq: int, tq: int, ps: int, ring: int, P: int):
     """Index maps of the mixed kernel's grid ``(B, nq, nk + nq)``: query
-    tile, pool page (K/V and their scales), in-row key tile, output tile.
+    tile, pool page (K/V and their scales, at the prefetched layer),
+    in-row key tile, output tile.
 
     DMA follows occupancy: a step the kernel skips repeats the previous
     step's block index, so Pallas fetches nothing for it. Query tiles
@@ -452,21 +468,21 @@ def _mixed_maps(*, nk: int, nq: int, tq: int, ps: int, ring: int, P: int):
         kb = _live_block(kb, 0, jnp.minimum(bounds[b, 0], ring), ps, nk)
         return jnp.clip(bt[b, kb], 0, P - 1)
 
-    def pool_map(b, qi, kb, bounds, bt):
-        return (page(b, qi, kb, bounds, bt), 0, 0, 0)
+    def pool_map(b, qi, kb, lyr, bounds, bt):
+        return (lyr[0], page(b, qi, kb, bounds, bt), 0, 0, 0)
 
-    def pool_scale_map(b, qi, kb, bounds, bt):
-        return (page(b, qi, kb, bounds, bt), 0, 0)
+    def pool_scale_map(b, qi, kb, lyr, bounds, bt):
+        return (lyr[0], page(b, qi, kb, bounds, bt), 0, 0)
 
-    def row_map(b, qi, kb, bounds, bt):
+    def row_map(b, qi, kb, lyr, bounds, bt):
         qt = jnp.minimum(qi, last_tile(b, bounds))
         rt = jnp.where(qi * tq < bounds[b, 1], kb - nk, nq)
-        return (b, jnp.clip(rt, 0, qt), 0, 0)
+        return (b, 0, jnp.clip(rt, 0, qt), 0)
 
-    def q_map(b, qi, kb, bounds, bt):
+    def q_map(b, qi, kb, lyr, bounds, bt):
         return (b, jnp.minimum(qi, last_tile(b, bounds)), 0, 0)
 
-    def out_map(b, qi, kb, bounds, bt):
+    def out_map(b, qi, kb, lyr, bounds, bt):
         return (b, qi, 0, 0)
 
     return q_map, pool_map, pool_scale_map, row_map, out_map
@@ -474,17 +490,19 @@ def _mixed_maps(*, nk: int, nq: int, tq: int, ps: int, ring: int, P: int):
 
 @functools.partial(jax.jit,
                    static_argnames=("ring", "window", "block_q", "interpret"))
-def tda_mixed_attention(q, k, v, k_row, v_row, bounds, block_table,
+def tda_mixed_attention(q, k, v, k_row, v_row, layer, bounds, block_table,
                         k_scale=None, v_scale=None, lut_table=None, *,
                         ring: int, window=None, block_q=None,
                         interpret: bool = True) -> jnp.ndarray:
     """Fused multi-query mixed-step attention over a paged KV lane pool.
 
     q (B, S, Hq, D) chunk queries (column j of row b sits at absolute
-    position ``bounds[b, 0] + j``); k/v physical page pools (P, page_size,
-    Hkv, D), fp or int8 codes (+ per-(token, head) pool scales);
-    k_row/v_row (B, S, Hkv, D) the chunk's own fp keys/values; bounds
-    (B, 2) int32 ``[cache_index, n_new]``; block_table (B, n) as in
+    position ``bounds[b, 0] + j``); k/v the layer-stacked physical page
+    pools (L, P, Hkv, page_size, D), head-major, fp or int8 codes (+ pool
+    scales (L, P, Hkv, page_size)), read in place at ``layer`` as in
+    :func:`tda_paged_decode_attention`; k_row/v_row (B, Hkv, S, D) the
+    chunk's own fp keys/values, head-major like the pages; bounds (B, 2)
+    int32 ``[cache_index, n_new]``; block_table (B, n) as in
     :func:`tda_paged_decode_attention`. ``ring`` is the logical lane
     width. Chunked-prefill attention is predicated the same way decode is:
     cache blocks outside ``[0, min(cache_index, ring))`` are skipped, and
@@ -495,7 +513,7 @@ def tda_mixed_attention(q, k, v, k_row, v_row, bounds, block_table,
     garbage the caller ignores. Returns (B, S, Hq, D) f32.
     """
     B, S, Hq, D = q.shape
-    P, ps, Hkv = k.shape[0], k.shape[1], k.shape[2]
+    P, Hkv, ps = k.shape[1], k.shape[2], k.shape[3]
     nk = block_table.shape[1]
     assert Hq % Hkv == 0, (Hq, Hkv)
     quant = k_scale is not None
@@ -503,29 +521,30 @@ def tda_mixed_attention(q, k, v, k_row, v_row, bounds, block_table,
     tq = min(block_q or mixed_query_tile(Hq), S)
     nq = -(-S // tq)
     Sp = nq * tq
-    q, k_row, v_row = (_pad_cols(x, Sp) for x in (q, k_row, v_row))
+    q = _pad_cols(q, Sp)
+    k_row, v_row = (_pad_cols(x, Sp, axis=2) for x in (k_row, v_row))
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
 
     q_map, pool_map, pool_scale_map, row_map, out_map = _mixed_maps(
         nk=nk, nq=nq, tq=tq, ps=ps, ring=ring, P=P)
     in_specs = [
         pl.BlockSpec((1, tq, Hq, D), q_map),
-        pl.BlockSpec((1, ps, Hkv, D), pool_map),
-        pl.BlockSpec((1, ps, Hkv, D), pool_map),
-        pl.BlockSpec((1, tq, Hkv, D), row_map),
-        pl.BlockSpec((1, tq, Hkv, D), row_map),
+        pl.BlockSpec((1, 1, Hkv, ps, D), pool_map),
+        pl.BlockSpec((1, 1, Hkv, ps, D), pool_map),
+        pl.BlockSpec((1, Hkv, tq, D), row_map),
+        pl.BlockSpec((1, Hkv, tq, D), row_map),
     ]
-    args = [bounds, block_table, q, k, v, k_row, v_row]
+    args = [layer, bounds, block_table, q, k, v, k_row, v_row]
     if quant:
-        in_specs += [pl.BlockSpec((1, ps, Hkv), pool_scale_map),
-                     pl.BlockSpec((1, ps, Hkv), pool_scale_map)]
+        in_specs += [pl.BlockSpec((1, 1, Hkv, ps), pool_scale_map),
+                     pl.BlockSpec((1, 1, Hkv, ps), pool_scale_map)]
         args += [k_scale, v_scale]
     if lut:
-        in_specs.append(pl.BlockSpec((LUT_SIZE,),
-                                     lambda b, qi, kb, bounds, bt: (0,)))
+        in_specs.append(pl.BlockSpec((LUT_SIZE,), lambda *_: (0,)))
         args.append(lut_table)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, nq, nk + nq),  # cache blocks, then in-row key tiles
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, tq, Hq, D), out_map),

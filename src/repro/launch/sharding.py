@@ -190,35 +190,35 @@ def cache_specs(cache_shapes: Any, mesh: Mesh, stacked: bool = True,
         treedef, [spec(p, l) for p, l in flat])
 
 
-def slot_cache_specs(cache_shapes: Any, mesh: Mesh) -> Any:
+def slot_cache_specs(cache_shapes: Any, mesh: Mesh,
+                     paged: bool = False) -> Any:
     """Serving slot/page caches: **KV-head**-sharded over ``model``.
 
-    The continuous-batching engine's cache leaves are
-    ``(L?, P, page_size, Hkv, D)`` page pools or ``(L?, B, ring, Hkv, D)``
-    contiguous lanes — the slot/batch axis is tiny (num_slots) and the
-    decode step's per-slot scatter writes would gather the whole cache if
-    sequence were split, so unlike :func:`cache_specs` the shard axis is
-    the KV head: each rank owns all pages of ``Hkv / tp`` heads (its
-    head-slice of every physical page), block tables and scalar slot
-    metadata replicate, and the sharded decode attention merges per-rank
-    softmax partials (kernels/tda/sharded.py). int8 KV scale leaves
-    ``(..., Hkv)`` mirror their codes; recurrent state leaves replicate
-    (they are neither paged nor head-structured).
+    The continuous-batching engine's cache leaves are head-major
+    ``(L?, P, Hkv, page_size, D)`` page pools (``paged``) or
+    ``(L?, B, ring, Hkv, D)`` contiguous lanes — the slot/batch axis is
+    tiny (num_slots) and the decode step's per-slot scatter writes would
+    gather the whole cache if sequence were split, so unlike
+    :func:`cache_specs` the shard axis is the KV head: each rank owns all
+    pages of ``Hkv / tp`` heads (its head-slice of every physical page),
+    block tables and scalar slot metadata replicate, and the sharded
+    decode attention merges per-rank softmax partials
+    (kernels/tda/sharded.py). int8 KV scale leaves (``(L?, P, Hkv,
+    page_size)`` / ``(..., Hkv)``) mirror their codes; recurrent state
+    leaves replicate (they are neither paged nor head-structured).
     """
     def spec(path, leaf):
         names = _path_names(path)
         nd = leaf.ndim
         s = [None] * nd
         if names and names[-1] in ("k", "v") and nd >= 4:
-            # (L?, P|B, ps|ring, Hkv, D): heads at -2
-            if _axis_ok(mesh, "model", leaf.shape[nd - 2]):
-                s[nd - 2] = "model"
+            head = nd - 3 if paged else nd - 2
+        elif names and names[-1] in ("k_scale", "v_scale") and nd >= 3:
+            head = nd - 2 if paged else nd - 1
+        else:
             return P(*s)
-        if names and names[-1] in ("k_scale", "v_scale") and nd >= 3:
-            # (L?, P|B, ps|ring, Hkv): heads at -1
-            if _axis_ok(mesh, "model", leaf.shape[nd - 1]):
-                s[nd - 1] = "model"
-            return P(*s)
+        if _axis_ok(mesh, "model", leaf.shape[head]):
+            s[head] = "model"
         return P(*s)
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(cache_shapes)

@@ -34,6 +34,7 @@ from repro.kernels.tda.ops import (
     fused_decode_attention,
     fused_mixed_attention,
     gather_paged_lanes,
+    write_paged_kv,
 )
 from repro.models.common import ModelConfig
 
@@ -41,7 +42,7 @@ NEG_INF = -1e30
 
 # Name scopes of a paged serving step's device work, carried into the
 # compiled program's op metadata (``op_name``): the write of the step's
-# K/V into the layer's page pool, and the attention call over it.
+# K/V into the page pool, and the attention call over it.
 KV_WRITE_SCOPE = "kv_write"
 ATTN_SCOPE = "attn_kernel"
 
@@ -337,6 +338,7 @@ def decode_attention(
     impl: str = "dense",
     block_k: int = 128,
     block_table: Optional[jnp.ndarray] = None,  # (B, n) paged lane pool
+    layer=None,  # paged: the layer of a layer-stacked pool
     mesh=None,  # tensor-parallel mesh: dispatch to the sharded merge path
 ) -> jnp.ndarray:
     """Single-token attention against a (possibly ring-buffered) KV cache.
@@ -355,27 +357,27 @@ def decode_attention(
     the whole cache first, which the kernel exists to avoid.
 
     ``block_table`` switches the tda path to the **paged lane pool**
-    layout: ``k_cache``/``v_cache`` are then ``(P, page_size, Hkv, D)``
-    physical page pools and ``block_table[b, i]`` names the physical page
-    holding logical kv block ``i`` of slot ``b`` (one page = one kv
-    block); the kernel reads it by scalar prefetch. Bounds semantics are
-    unchanged.
+    layout: ``k_cache``/``v_cache`` are then head-major page pools
+    ``(P, Hkv, page_size, D)``, or with ``layer`` the layer-stacked
+    ``(L, P, Hkv, page_size, D)``, and ``block_table[b, i]`` names the
+    physical page holding logical kv block ``i`` of slot ``b`` (one page
+    = one kv block); the kernel reads it in place by scalar prefetch.
+    Bounds semantics are unchanged.
 
     ``mesh`` with a >1 ``model`` axis dispatches to the tensor-parallel
     form (:mod:`repro.kernels.tda.sharded`): caches sharded on the KV-head
     axis, per-rank online-softmax partials merged by one cross-rank
     rescale/psum. Paged pools are gathered to lane views first (the
-    gather is shard-local — page and position axes are replicated).
+    gather is shard-local — layer, page and position axes are replicated).
     """
     from repro.launch.mesh import tensor_parallel_size
     if tensor_parallel_size(mesh) > 1:
         from repro.kernels.tda.sharded import sharded_decode_attention
         if block_table is not None:
-            k_cache = gather_paged_lanes(k_cache, block_table)
-            v_cache = gather_paged_lanes(v_cache, block_table)
-            if k_scale is not None:
-                k_scale = gather_paged_lanes(k_scale, block_table)
-                v_scale = gather_paged_lanes(v_scale, block_table)
+            k_cache, v_cache, k_scale, v_scale = (
+                None if x is None
+                else gather_paged_lanes(x, block_table, layer)
+                for x in (k_cache, v_cache, k_scale, v_scale))
         return sharded_decode_attention(
             q, k_cache, v_cache, cache_index, mesh=mesh, window=window,
             k_scale=k_scale, v_scale=v_scale)
@@ -383,7 +385,7 @@ def decode_attention(
         return fused_decode_attention(
             q, k_cache, v_cache, cache_index, k_scale=k_scale,
             v_scale=v_scale, window=window, block_k=block_k,
-            block_table=block_table)
+            block_table=block_table, layer=layer)
     if k_scale is not None:
         k_cache = k_cache.astype(jnp.float32) * k_scale[..., None]
         v_cache = v_cache.astype(jnp.float32) * v_scale[..., None]
@@ -504,14 +506,12 @@ def attention_block(
         # forward over paged lanes (engine-only). Row b carries n_new[b]
         # fresh tokens, left-aligned, at absolute positions [cache_index,
         # cache_index + n_new). Queries attend the PRE-write lane view plus
-        # the causal in-row chunk, and only then does the chunk K/V scatter
+        # the causal in-row chunk, and only then is the chunk K/V written
         # into the pool — a chunk write may land on a ring position an
         # earlier query still needs, so attend-then-write is load-bearing.
         assert pages is not None, "mixed step requires paged lanes"
-        ps = pages["page_size"]
         ringw = pages["width"]  # logical lane width (static int)
         bt = pages["bt"]        # (B, n) int32; FREE sentinel == num_pages
-        P = cache["k"].shape[-4]
         ci = jnp.reshape(cache_index, (-1,)).astype(jnp.int32)
         nn = jnp.reshape(n_new, (-1,)).astype(jnp.int32)
         if slot_mask is not None:
@@ -534,100 +534,50 @@ def attention_block(
         from repro.launch.mesh import tensor_parallel_size
         impl = resolve_decode_attn(cfg.decode_attn)
         use_kernel = impl == "tda" and tensor_parallel_size(mesh) <= 1
-        kcs = vcs = None
-        if quant:
-            kcs = layer_view(cache["k_scale"])
-            vcs = layer_view(cache["v_scale"])
+        # The pool is read and written in place, addressed by (layer,
+        # page); the write is ordered after the attention by a data edge
+        # (its output), since a chunk may overwrite a ring position an
+        # earlier query still reads.
         with jax.named_scope(ATTN_SCOPE):
             o = fused_mixed_attention(
-                q, layer_view(cache["k"]), layer_view(cache["v"]),
-                k_row, v_row, ci, nn, block_table=bt, ring=ringw,
-                window=window, k_scale=kcs, v_scale=vcs,
+                q, cache["k"], cache["v"], k_row, v_row, ci, nn,
+                block_table=bt, ring=ringw, window=window,
+                k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
+                layer=layer_idx, use_kernel=use_kernel)
+        with jax.named_scope(KV_WRITE_SCOPE):
+            new = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc} \
+                if quant else {"k": k, "v": v}
+            new_cache = write_paged_kv(
+                {name: cache[name] for name in new}, new, ci, nn,
+                block_table=bt, ring=ringw, layer=layer_idx, after=o,
                 use_kernel=use_kernel)
         o = o.reshape(B, S, cfg.n_heads * hd)
-
-        def paged_write_chunk(buf, new):  # new: (B, S, ...)
-            lv = layer_view(buf)  # (P, ps, ...)
-            lvf = lv.reshape((P * ps,) + lv.shape[2:])
-            newf = new.astype(buf.dtype).reshape((B * S,) + new.shape[2:])
-            lvf = lvf.at[physf].set(newf, mode="drop")
-            lv2 = lvf.reshape(lv.shape)
-            if layer_idx is None:
-                return lv2
-            return jax.lax.dynamic_update_slice(
-                buf, lv2[None], (layer_idx,) + (0,) * lv2.ndim)
-
-        with jax.named_scope(KV_WRITE_SCOPE):
-            # Chunk scatter: token j lands at lane position (ci + j) %
-            # ringw. Only the last min(n_new, ringw) columns write —
-            # earlier columns of a wrapping chunk alias the same lane
-            # position and a duplicate scatter index would make the result
-            # order-dependent.
-            cols = jax.lax.iota(jnp.int32, S)[None, :]          # (1, S)
-            lanepos = (ci[:, None] + cols) % ringw              # (B, S)
-            wvalid = (cols < nn[:, None]) & (cols >= nn[:, None] - ringw)
-            page = jnp.take_along_axis(bt, lanepos // ps, axis=1)
-            phys = jnp.where(wvalid, page * ps + lanepos % ps, P * ps)
-            physf = phys.reshape(-1)
-            if quant:
-                new_cache = {
-                    "k": paged_write_chunk(cache["k"], kq),
-                    "v": paged_write_chunk(cache["v"], vq),
-                    "k_scale": paged_write_chunk(cache["k_scale"], ksc),
-                    "v_scale": paged_write_chunk(cache["v_scale"], vsc)}
-            else:
-                new_cache = {"k": paged_write_chunk(cache["k"], k),
-                             "v": paged_write_chunk(cache["v"], v)}
     elif cache is not None and S == 1 and pages is not None:
         # ---- paged decode: lanes live in a page pool (serve/pages.py) ----
         # Logical lane coordinates are the contiguous layout's (canonical
         # ring phase, [lo, hi) bounds); only the *physical* home of logical
         # page ``p // page_size`` is indirected through the block table.
-        ps = pages["page_size"]
+        # The token is written into the pool in place, then attention
+        # reads the written pool at (layer, page).
         ringw = pages["width"]  # logical lane width (static int)
         bt = pages["bt"]        # (B, n) int32; FREE sentinel == num_pages
-        P = cache["k"].shape[-4]  # physical pages in this leaf's pool
-
-        # This layer's written pools. Attention reads these, never a
-        # slice of the stacked cache after its update: reading the updated
-        # carry back makes XLA copy the whole stacked pool every step.
-        views = {}
-
-        def paged_write(name, new):
-            buf = cache[name]
-            lv = layer_view(buf)  # (P, ps, ...)
-            lvf = lv.reshape((P * ps,) + lv.shape[2:])
-            lvf = lvf.at[phys].set(new.astype(buf.dtype), mode="drop")
-            views[name] = lvf.reshape(lv.shape)
-            if layer_idx is None:
-                return views[name]
-            return jax.lax.dynamic_update_slice(
-                buf, views[name][None], (layer_idx,) + (0,) * lv.ndim)
-
+        from repro.launch.mesh import tensor_parallel_size
         impl = resolve_decode_attn(cfg.decode_attn)
         if quant:
             kq, ksc = kv_quantize(k)
             vq, vsc = kv_quantize(v)
+            new = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
+        else:
+            new = {"k": k, "v": v}
+        # Inactive slots write nothing: the lane stays untouched.
+        nn = jnp.ones((B,), jnp.int32) if slot_mask is None \
+            else jnp.reshape(slot_mask, (-1,)).astype(jnp.int32)
         with jax.named_scope(KV_WRITE_SCOPE):
-            pos = cache_index if window is None else cache_index % ringw
-            pos = jnp.reshape(pos, (-1,))
-            page = jnp.take_along_axis(bt, (pos // ps)[:, None],
-                                       axis=1)[:, 0]
-            phys = page * ps + pos % ps
-            if slot_mask is not None:
-                # Inactive slots (and unallocated sentinel pages) land out
-                # of bounds — the scatter drops them, the lane stays
-                # untouched.
-                phys = jnp.where(jnp.reshape(slot_mask, (-1,)), phys,
-                                 P * ps)
-            if quant:
-                new_cache = {"k": paged_write("k", kq[:, 0]),
-                             "v": paged_write("v", vq[:, 0]),
-                             "k_scale": paged_write("k_scale", ksc[:, 0]),
-                             "v_scale": paged_write("v_scale", vsc[:, 0])}
-            else:
-                new_cache = {"k": paged_write("k", k[:, 0]),
-                             "v": paged_write("v", v[:, 0])}
+            new_cache = write_paged_kv(
+                {name: cache[name] for name in new}, new,
+                jnp.reshape(cache_index, (-1,)), nn, block_table=bt,
+                ring=ringw, layer=layer_idx,
+                use_kernel=impl == "tda" and tensor_parallel_size(mesh) <= 1)
         if slot_mask is not None:
             cache_index = jnp.where(jnp.reshape(slot_mask, (-1,)),
                                     cache_index, -1)
@@ -637,18 +587,21 @@ def attention_block(
             else jnp.minimum(cache_index + 1, ringw)
         with jax.named_scope(ATTN_SCOPE):
             if impl == "tda":
-                # The kernel consumes the page pools directly: the block
-                # table rides scalar prefetch and one page is one kv block.
+                # The kernel consumes the stacked page pools directly: the
+                # layer and block table ride scalar prefetch and one page
+                # is one kv block.
                 o = decode_attention(
-                    q, views["k"], views["v"], hi,
-                    k_scale=views.get("k_scale"),
-                    v_scale=views.get("v_scale"), impl="tda",
-                    block_k=cfg.decode_block_k, block_table=bt, mesh=mesh)
+                    q, new_cache["k"], new_cache["v"], hi,
+                    k_scale=new_cache.get("k_scale"),
+                    v_scale=new_cache.get("v_scale"), impl="tda",
+                    block_k=cfg.decode_block_k, block_table=bt,
+                    layer=layer_idx, mesh=mesh)
             else:
                 # Dense path: gather each slot's lane view out of the pool
                 # (same data volume as reading a dense lane), then attend.
                 def lanes(name):
-                    return gather_paged_lanes(views[name], bt)
+                    return gather_paged_lanes(new_cache[name], bt,
+                                              layer_idx)
 
                 if quant:
                     kc = kv_dequantize(lanes("k"), lanes("k_scale"), dt)

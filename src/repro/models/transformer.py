@@ -515,7 +515,7 @@ class Model:
         those B columns only: all-position logits of a 512-wide chunk over a
         152k vocabulary would take gigabytes.
         ``cache_index``/``slot_mask`` semantics match :meth:`decode_step`;
-        the per-row chunk K/V is scattered into the paged lanes through the
+        the per-row chunk K/V is written into the paged lanes through the
         block tables after attention (pre-write lane view + causal in-row
         chunk — see :func:`repro.kernels.tda.ref.mixed_attention_reference`
         for the mask contract).

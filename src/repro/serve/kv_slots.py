@@ -38,9 +38,11 @@ Every cache leaf is typed by a *lane spec* from
 
 ``page_size`` switches the kv lanes to the **paged layout**
 (:mod:`repro.serve.pages`): each kv leaf becomes a pool of
-``page_size``-token physical pages (shape ``(L?, num_pages, page_size,
-...)``) and a per-slot block table maps logical page ``p // page_size`` to
-its physical page. Logical lane coordinates — canonical ring phase, the
+``page_size``-token physical pages, head-major — ``(L?, num_pages, Hkv,
+page_size, D)``, int8 scales ``(L?, num_pages, Hkv, page_size)``; the
+layout and why are stated in ``docs/serving.md`` ("Page layout") — and a
+per-slot block table maps logical page ``p // page_size`` to its physical
+page. Logical lane coordinates — canonical ring phase, the
 TDA ``[lo, hi)`` bounds — are untouched; ``assign``/``release`` also
 allocate/free pages, and the fused assign copy scatters through the block
 tables (unallocated entries carry the out-of-bounds ``FREE`` sentinel, so
@@ -63,13 +65,14 @@ metric (pages in use over pool capacity).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.tda.ops import paged_flat_positions
+from repro.kernels.tda.ops import gather_paged_lanes
 from repro.models.layers import kv_dequantize
 from repro.models.transformer import Model
 from repro.serve.pages import PagePool
@@ -128,8 +131,11 @@ class SlotKVCache:
                 if spec != "kv":
                     return jnp.zeros(leaf.shape, leaf.dtype)
                 P = self.pool.classes[w].num_pages
-                shape = (leaf.shape[:ba] + (P, page_size)
-                         + leaf.shape[ba + 2:])
+                # (L?, B, W, Hkv, ...) lanes -> (L?, P, Hkv, page_size,
+                # ...) head-major pages
+                shape = (leaf.shape[:ba] + (P, leaf.shape[ba + 2],
+                                            page_size)
+                         + leaf.shape[ba + 3:])
                 return jnp.zeros(shape, leaf.dtype)
 
             def make():
@@ -149,7 +155,8 @@ class SlotKVCache:
         from repro.launch.mesh import tensor_parallel_size
         if tensor_parallel_size(mesh) > 1:
             from repro.launch import sharding as shd
-            specs = shd.slot_cache_specs(jax.eval_shape(make), mesh)
+            specs = shd.slot_cache_specs(jax.eval_shape(make), mesh,
+                                         paged=self.pool is not None)
             self.caches = jax.jit(make,
                                   out_shardings=shd.named(specs, mesh))()
         else:
@@ -266,10 +273,10 @@ class SlotKVCache:
         block table — position ``p`` lands in physical page ``bt[slot, p //
         page_size]`` at offset ``p % page_size``. Sentinel table entries
         (unallocated pages, and the padded ``slot == num_slots`` row)
-        produce out-of-bounds flat positions, which the scatter drops —
-        and so does every position outside ``[offs, total)``, which is
-        what keeps shared prefix pages (below ``offs``) byte-identical
-        while the suffix lands around them."""
+        name a page out of bounds, which the scatter drops — and so does
+        every position outside ``[offs, total)``, which is what keeps
+        shared prefix pages (below ``offs``) byte-identical while the
+        suffix lands around them."""
         ba = 1 if self._stacked else 0
         ps = self.page_size
 
@@ -283,19 +290,22 @@ class SlotKVCache:
             W = bt.shape[1] * ps  # page-quantized width (tail never read)
             lanes, valid = self._gather_lanes(src, rows, starts, lengths,
                                               w, W, dst.dtype, offs)
-            pages = jnp.take(bt, slots, axis=0)  # (J, lane_pages)
-            flatpos = paged_flat_positions(pages, ps)  # (J, W)
             P = dst.shape[ba]
-            # Invalid positions scatter out of bounds (dropped) instead of
-            # writing zeros: a lane's pages may be shared with other slots.
-            flatpos = jnp.where(valid, flatpos, P * ps)
-            dstf = dst.reshape(dst.shape[:ba] + (P * ps,)
-                               + dst.shape[ba + 2:])
+            pos = jnp.arange(W)
+            # Invalid positions name no page (dropped) instead of writing
+            # zeros: a lane's pages may be shared with other slots.
+            page = jnp.where(valid, jnp.take(bt, slots, axis=0)[:, pos // ps],
+                             P).reshape(-1)
+            off = jnp.broadcast_to(pos % ps, valid.shape).reshape(-1)
+            J = valid.shape[0]
             if ba == 0:
-                dstf = dstf.at[flatpos].set(lanes, mode="drop")
-            else:
-                dstf = dstf.at[:, flatpos].set(lanes, mode="drop")
-            return dstf.reshape(dst.shape)
+                vals = lanes.reshape((J * W,) + lanes.shape[2:])
+                return dst.at[page, :, off].set(vals, mode="drop")
+            # (L, J, W, Hkv, ...) -> (J * W, L, Hkv, ...): the indexed
+            # axes lead the update, as numpy puts split index axes first.
+            vals = jnp.moveaxis(lanes.reshape(
+                (lanes.shape[0], J * W) + lanes.shape[3:]), 0, 1)
+            return dst.at[:, page, :, off].set(vals, mode="drop")
 
         return jax.tree.map(per_leaf, dst_caches, src_caches, self.specs,
                             self.widths)
@@ -454,35 +464,25 @@ class SlotKVCache:
         ``(n_pages,)`` produce batch-1 views ``(L?, 1, n_pages *
         page_size, Hkv, D)``; 2-D ids ``(R, n_pages)`` (a batched suffix
         sweep — one prefix per row) produce ``(L?, R, n_pages *
-        page_size, Hkv, D)``."""
+        page_size, Hkv, D)``: token-major, like a prefill cache."""
         ids = {w: jnp.asarray(v, jnp.int32) for w, v in page_ids.items()}
         return self._prefix_gather(self.caches, ids)
 
     def _gather_prefix_fn(self, caches, ids):
-        ba = 1 if self._stacked else 0
-        ps = self.page_size
-
         def block(d, widths_d):
             w = widths_d.get("k", 0) if isinstance(widths_d, dict) else 0
             if not w:
                 return None, None  # state-lane layer: sharing is gated off
-            page_ix = jnp.clip(ids[w], 0, self.pool.classes[w].num_pages - 1)
-            batched = page_ix.ndim == 2  # (R, n): one prefix per row
+            # (R, n): one prefix per row — the row axis IS the batch axis
+            # of a batched suffix sweep; (n,): one row
+            page_ix = ids[w] if ids[w].ndim == 2 else ids[w][None]
+            gather = functools.partial(gather_paged_lanes,
+                                       block_table=page_ix)
+            if self._stacked:
+                gather = jax.vmap(gather)  # every layer of the stack
 
             def lanes(name):
-                if batched:
-                    # (L?, R, n, ps, ..) -> (L?, R, n * ps, ..): the row
-                    # axis IS the batch axis of the suffix sweep.
-                    leaf = jnp.take(d[name], page_ix, axis=ba)
-                    sh = leaf.shape
-                    return leaf.reshape(sh[:ba + 1]
-                                        + (sh[ba + 1] * sh[ba + 2],)
-                                        + sh[ba + 3:])
-                leaf = jnp.take(d[name], page_ix, axis=ba)  # (L?, n, ps, ..)
-                sh = leaf.shape
-                leaf = leaf.reshape(sh[:ba] + (sh[ba] * sh[ba + 1],)
-                                    + sh[ba + 2:])
-                return jnp.expand_dims(leaf, ba)  # batch axis: (L?, 1, Np, ..)
+                return gather(d[name])  # (L?, R|1, n * page_size, Hkv, ..)
 
             k, v = lanes("k"), lanes("v")
             if "k_scale" in d:

@@ -116,10 +116,19 @@ def test_sharing_invariants_under_random_schedule(seed):
             hit = pool.probe_prefix(prompt)
             off = hit.n_shared if hit else 0
             shared = -(-off // pool.page_size)
-            ok = all(
-                -(-min(L + 1, c.width) // pool.page_size)
-                - (shared if L <= c.width else 0) <= c.available()
-                for c in pool.classes.values())
+            # The engine's page budget (Engine._page_reserve): new pages,
+            # plus shared pages the writes copy, plus retained pages the
+            # mapping takes out of the evictable set.
+            ok = True
+            for w, c in pool.classes.items():
+                need = -(-min(L + 1, w) // pool.page_size)
+                if hit:
+                    cow = len({(p % w) // pool.page_size
+                               for p in range(off, L + 1)}
+                              & set(range(shared)))
+                    r0 = sum(c.refcount[pg] == 0 for pg in hit.pages[w])
+                    need = max(0, need - shared) + cow + r0
+                ok &= need <= c.available()
             if not ok:
                 continue
             if hit:

@@ -99,12 +99,16 @@ def test_tda_lut_exp_close_to_exact():
 # ---- paged lane pool: block-table scalar prefetch --------------------------
 
 
+LAYERS = 3  # layers of a stacked test pool
+
+
 def _mk_paged(B, P, ps, Hkv, D, lengths, rng, quant=False):
-    """Physical page pools + prefix-allocated block tables over a shuffled
-    free list (fragmented physical order on purpose)."""
+    """Layer-stacked head-major page pools ``(LAYERS, P, Hkv, ps, D)`` +
+    prefix-allocated block tables over a shuffled free list (fragmented
+    physical order on purpose)."""
     n = max(-(-int(max(lengths)) // ps), 1)
-    kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(LAYERS, P, Hkv, ps, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(LAYERS, P, Hkv, ps, D)), jnp.float32)
     free = rng.permutation(P).tolist()
     bt = np.full((B, n), P, np.int32)  # FREE sentinel == P
     for b in range(B):
@@ -117,21 +121,29 @@ def _mk_paged(B, P, ps, Hkv, D, lengths, rng, quant=False):
     return kq, vq, ks, vs, jnp.asarray(bt)
 
 
+def _layer(x, l):
+    return None if x is None else x[l]
+
+
 @pytest.mark.parametrize("quant", [False, True])
 def test_tda_paged_matches_gathered_reference(quant):
+    """The paged kernel over the stacked pool at layer ``l`` equals the
+    gathered reference over that layer's pool, for every ``l``."""
     rng = np.random.default_rng(3)
     B, P, ps, Hq, Hkv, D = 4, 14, 8, 8, 2, 16
     lengths = np.asarray([3, 17, 40, 0], np.int32)
     k, v, ks, vs, bt = _mk_paged(B, P, ps, Hkv, D, lengths, rng, quant)
     q = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.float32)
     lens = jnp.asarray(lengths)
-    out = fused_decode_attention(q, k, v, lens, k_scale=ks, v_scale=vs,
-                                 block_table=bt)
-    ref = fused_decode_attention(q, k, v, lens, k_scale=ks, v_scale=vs,
-                                 block_table=bt, use_kernel=False)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-    assert np.all(np.asarray(out)[3] == 0.0)  # empty lane -> zeros
+    for l in range(LAYERS):
+        out = fused_decode_attention(q, k, v, lens, k_scale=ks, v_scale=vs,
+                                     block_table=bt, layer=jnp.int32(l))
+        ref = fused_decode_attention(
+            q, k[l], v[l], lens, k_scale=_layer(ks, l),
+            v_scale=_layer(vs, l), block_table=bt, use_kernel=False)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+        assert np.all(np.asarray(out)[3] == 0.0)  # empty lane -> zeros
 
 
 def test_tda_paged_equals_contiguous_layout():
@@ -144,9 +156,11 @@ def test_tda_paged_equals_contiguous_layout():
     kp, vp, _, _, bt = _mk_paged(B, P, ps, Hkv, D, lengths, rng)
     q = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.float32)
     lens = jnp.asarray(lengths)
-    paged = fused_decode_attention(q, kp, vp, lens, block_table=bt)
+    paged = fused_decode_attention(q, kp, vp, lens, block_table=bt,
+                                   layer=jnp.int32(1))
     # contiguous layout = the gathered lane views, through the dense kernel
-    kd, vd = gather_paged_lanes(kp, bt), gather_paged_lanes(vp, bt)
+    kd = gather_paged_lanes(kp, bt, jnp.int32(1))
+    vd = gather_paged_lanes(vp, bt, jnp.int32(1))
     contiguous = fused_decode_attention(q, kd, vd, lens, block_k=ps)
     np.testing.assert_allclose(np.asarray(paged), np.asarray(contiguous),
                                rtol=1e-5, atol=1e-5)
@@ -160,9 +174,11 @@ def test_tda_paged_equals_contiguous_layout():
     (False, 32, None, 2),     # five query tiles: dead tiles repeat blocks
 ])
 def test_tda_mixed_matches_reference(quant, ring, window, block_q):
-    """The mixed kernel equals the gathered jnp reference on every fresh
-    column (columns >= n_new are garbage by contract), whatever the query
-    tile — chunk rows, a decode row, an inert row and an empty lane."""
+    """The mixed kernel over the stacked pool at layer ``l`` equals the
+    gathered jnp reference over that layer on every fresh column (columns
+    >= n_new are garbage by contract), for every ``l`` and whatever the
+    query tile — chunk rows, a decode row, an inert row and an empty
+    lane."""
     from repro.kernels.tda.ops import fused_mixed_attention
     from repro.kernels.tda.tda import tda_mixed_attention
     rng = np.random.default_rng(5)
@@ -175,19 +191,67 @@ def test_tda_mixed_matches_reference(quant, ring, window, block_q):
     q = jnp.asarray(rng.normal(size=(B, S, Hq, D)), jnp.float32)
     kr = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
     vr = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
-    ref = fused_mixed_attention(q, k, v, kr, vr, jnp.asarray(ci),
-                                jnp.asarray(nn), block_table=bt, ring=ring,
-                                window=window, k_scale=ks, v_scale=vs,
-                                use_kernel=False)
     bounds = jnp.asarray(np.stack([ci, nn], axis=1))
-    out = tda_mixed_attention(q, k, v, kr, vr, bounds, bt, ks, vs,
-                              ring=ring, window=window, block_q=block_q,
-                              interpret=True)
-    assert out.shape == ref.shape
-    for b in range(B):
-        np.testing.assert_allclose(np.asarray(out)[b, :nn[b]],
-                                   np.asarray(ref)[b, :nn[b]],
-                                   rtol=1e-5, atol=1e-5)
+    for l in range(LAYERS):
+        ref = fused_mixed_attention(
+            q, k[l], v[l], kr, vr, jnp.asarray(ci), jnp.asarray(nn),
+            block_table=bt, ring=ring, window=window, k_scale=_layer(ks, l),
+            v_scale=_layer(vs, l), use_kernel=False)
+        out = tda_mixed_attention(
+            q, k, v, jnp.swapaxes(kr, 1, 2), jnp.swapaxes(vr, 1, 2),
+            jnp.int32(l), bounds, bt, ks, vs, ring=ring, window=window,
+            block_q=block_q, interpret=True)
+        assert out.shape == ref.shape
+        for b in range(B):
+            np.testing.assert_allclose(np.asarray(out)[b, :nn[b]],
+                                       np.asarray(ref)[b, :nn[b]],
+                                       rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("S,ring,ps,ci,nn,quant", [
+    # decode rows: an inactive row, a write into the last offset of a page
+    (1, 32, 8, [0, 5, 31, 7], [1, 1, 1, 0], False),
+    (1, 32, 8, [0, 5, 31, 7], [1, 1, 1, 0], True),   # int8 codes + scales
+    (1, 12, 8, [3, 12, 25, 0], [1, 1, 1, 1], False),  # ring write pointer
+    (1, 32, 8, [9, 5, 6, 7], [1, 1, 1, 1], True),  # rows 1-3 share a page
+    # chunks crossing page boundaries, a decode row and an n_new == 0 row
+    (10, 32, 8, [0, 5, 20, 13], [10, 1, 7, 0], False),
+    (10, 12, 8, [0, 5, 20, 13], [10, 1, 7, 9], False),  # ring wraps
+    (24, 12, 8, [3, 5, 20, 0], [24, 13, 12, 0], False),  # chunk > ring
+    (10, 6, 4, [3, 5, 20, 1], [10, 3, 4, 2], False),  # ring not in tiles
+])
+def test_paged_kv_write_matches_scatter(S, ring, ps, ci, nn, quant):
+    """The in-place write kernel leaves the stacked pool exactly as the
+    XLA ``mode="drop"`` scatter does: rows with ``n_new == 0`` and
+    sentinel pages write nothing, only the last ``ring`` columns of a
+    wrapping chunk write, rows meeting in one tile all land, and every
+    other layer stays untouched."""
+    from repro.kernels.tda.page_write import (paged_kv_write,
+                                              paged_kv_write_reference)
+    rng = np.random.default_rng(6)
+    B, P, Hkv, D, layer = 4, 24, 2, 16, 1
+    n = -(-ring // ps)
+    bt = rng.permutation(P)[:B * n].reshape(B, n).astype(np.int32)
+    bt[0, -1] = P  # an unallocated (FREE) page
+    if ci[1:] == [5, 6, 7]:  # three rows write one page, one tile of it
+        bt[2:] = bt[1]
+    pools = [jnp.asarray(rng.normal(size=(LAYERS, P, Hkv, ps, D)),
+                         jnp.float32) for _ in range(2)]
+    news = [jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
+            for _ in range(2)]
+    if quant:
+        (kq, ks), (vq, vs) = (L.kv_quantize(x) for x in pools)
+        (nkq, nks), (nvq, nvs) = (L.kv_quantize(x) for x in news)
+        pools, news = [kq, vq, ks, vs], [nkq, nvq, nks, nvs]
+    args = (jnp.int32(layer), jnp.asarray(ci, jnp.int32),
+            jnp.asarray(nn, jnp.int32), jnp.asarray(bt))
+    want = paged_kv_write_reference(pools, news, *args, ring=ring)
+    got = paged_kv_write(pools, news, *args, ring=ring, interpret=True)
+    for w, g, p in zip(want, got, pools):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        keep = np.arange(LAYERS) != layer
+        np.testing.assert_array_equal(np.asarray(g)[keep],
+                                      np.asarray(p)[keep])
 
 
 def _fetches(index_map, steps, *prefetch):
@@ -228,12 +292,13 @@ def test_mixed_fetches_follow_occupancy():
     nn = np.asarray([12, 1, 6, 0, 4], np.int32)
     bounds = np.stack([ci, nn], axis=1)
     bt = np.arange(5 * nk, dtype=np.int32).reshape(5, nk)
+    layer = np.asarray([2], np.int32)
     for b in range(len(ci)):
         steps = [(b, qi, kb) for qi in range(nq) for kb in range(nk + nq)]
         n_live = -(-nn[b] // tq)
         n_res = max(-(-min(ci[b], ring) // ps), 1)
-        pool = _fetches(pool_map, steps, bounds, bt)
-        rows = _fetches(row_map, steps, bounds, bt)
+        pool = _fetches(pool_map, steps, layer, bounds, bt)
+        rows = _fetches(row_map, steps, layer, bounds, bt)
         # an inert row (no live tile) pins its last resident page
         assert pool == (1 if n_live == 0 or n_res == 1
                         else n_live * n_res), b
@@ -241,8 +306,8 @@ def test_mixed_fetches_follow_occupancy():
         # previous tile ended on it) and then 1..qi
         assert rows == max(1, sum(qi + 1 for qi in range(n_live))
                            - (n_live >= 2)), b
-        assert _fetches(q_map, steps, bounds, bt) == max(n_live, 1), b
-        assert _fetches(out_map, steps, bounds, bt) == nq, b
+        assert _fetches(q_map, steps, layer, bounds, bt) == max(n_live, 1), b
+        assert _fetches(out_map, steps, layer, bounds, bt) == nq, b
 
 
 # ---- property: predication changes work, never results --------------------

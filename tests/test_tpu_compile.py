@@ -20,12 +20,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.dmm.ops import lut_matmul
 from repro.kernels.smm.ops import compressed_matmul
+from repro.kernels.tda.page_write import paged_kv_write
 from repro.kernels.tda.tda import (tda_decode_attention, tda_mixed_attention,
                                    tda_paged_decode_attention)
 
 BF16, I32, U8, F32 = jnp.bfloat16, jnp.int32, jnp.uint8, jnp.float32
 B, PAGE, D = 8, 128, 128
-PAGES, PER_SLOT = 64, 8          # page pool, pages per slot lane
+LAYERS, PAGES, PER_SLOT = 2, 64, 8  # stacked page pool, pages per lane
 
 
 @pytest.fixture(scope="module")
@@ -48,22 +49,47 @@ def _compiled_text(fn, sharding, *shapes):
 
 @pytest.mark.parametrize("hq,hkv", [(20, 20), (48, 4)])
 def test_paged_decode_compiles(one_chip, hq, hkv):
+    pool = ((LAYERS, PAGES, hkv, PAGE, D), BF16)
     text = _compiled_text(
-        lambda q, k, v, bd, bt: tda_paged_decode_attention(
-            q, k, v, bd, bt, interpret=False), one_chip,
-        ((B, hq, D), BF16), ((PAGES, PAGE, hkv, D), BF16),
-        ((PAGES, PAGE, hkv, D), BF16), ((B, 2), I32), ((B, PER_SLOT), I32))
+        lambda q, k, v, l, bd, bt: tda_paged_decode_attention(
+            q, k, v, l, bd, bt, interpret=False), one_chip,
+        ((B, hq, D), BF16), pool, pool, ((), I32), ((B, 2), I32),
+        ((B, PER_SLOT), I32))
     assert "tpu_custom_call" in text
 
 
 def test_mixed_compiles_at_chunk_512(one_chip):
     S, H = 512, 20
+    pool = ((LAYERS, PAGES, H, PAGE, D), BF16)
     text = _compiled_text(
-        lambda q, k, v, kr, vr, bd, bt: tda_mixed_attention(
-            q, k, v, kr, vr, bd, bt, ring=PER_SLOT * PAGE, interpret=False),
-        one_chip, ((B, S, H, D), BF16), ((PAGES, PAGE, H, D), BF16),
-        ((PAGES, PAGE, H, D), BF16), ((B, S, H, D), BF16),
-        ((B, S, H, D), BF16), ((B, 2), I32), ((B, PER_SLOT), I32))
+        lambda q, k, v, kr, vr, l, bd, bt: tda_mixed_attention(
+            q, k, v, kr, vr, l, bd, bt, ring=PER_SLOT * PAGE,
+            interpret=False),
+        one_chip, ((B, S, H, D), BF16), pool, pool, ((B, H, S, D), BF16),
+        ((B, H, S, D), BF16), ((), I32), ((B, 2), I32),
+        ((B, PER_SLOT), I32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("S,quant", [(1, False), (1, True), (512, False)])
+def test_paged_kv_write_compiles(one_chip, S, quant):
+    """The in-place pool write: a decode step's tokens (bf16, or int8
+    codes with their scales) and a mixed step's 512-column chunks."""
+    H = 20
+    dt = jnp.int8 if quant else BF16
+    leaves = [((LAYERS, PAGES, H, PAGE, D), dt)] * 2
+    news = [((B, S, H, D), dt)] * 2
+    if quant:
+        leaves += [((LAYERS, PAGES, H, PAGE), F32)] * 2
+        news += [((B, S, H), F32)] * 2
+    n = len(leaves)
+
+    def write(*a):
+        return paged_kv_write(a[:n], a[n:2 * n], *a[2 * n:],
+                              ring=PER_SLOT * PAGE, interpret=False)
+
+    text = _compiled_text(write, one_chip, *leaves, *news, ((), I32),
+                          ((B,), I32), ((B,), I32), ((B, PER_SLOT), I32))
     assert "tpu_custom_call" in text
 
 
@@ -93,40 +119,63 @@ def test_compressed_linear_compiles(one_chip, m):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
-def test_paged_decode_step_moves_pool_layers_under_kv_write(one_chip):
-    """The whole decode step at qwen1.5-4b widths (two layers, a 16-page
-    pool): every operation that moves a whole layer of the stacked page
-    pool (the layer's slice, its relayout copy, the update back) carries
-    the ``kv_write`` name scope in its metadata, so a trace can charge
-    the pool's copies to the KV write."""
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_paged_step_moves_no_pool_layer(one_chip, monkeypatch, step):
+    """The whole decode step, and the mixed step at S = 512, at qwen1.5-4b
+    widths (two layers, a 16-page pool): no XLA operation (copy, fusion,
+    slice, update, scatter) yields an array of a layer's pool or of the
+    stacked pool, in any layout — the pool is read and written in place
+    by kernels that address it by (layer, page) — and the write's Mosaic
+    kernel carries the ``kv_write`` name scope, so a trace charges it to
+    the KV write."""
     import re
 
+    from repro.kernels import common
     from repro.launch.serve import build_model
     from repro.models.layers import KV_WRITE_SCOPE
 
-    L, P, H = 2, 16, 20
+    # The program sees the CPU backend here; compile its kernels for the
+    # described chip, not the interpreter.
+    monkeypatch.setattr(common, "pallas_interpret_default", lambda: False)
+    L, P, H, S = 2, 16, 20, 512
     model = build_model("qwen1.5-4b", "full", n_layers=L, decode_attn="tda")
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(model.init, jax.random.key(0)))
-    pool = jax.ShapeDtypeStruct((L, P, PAGE, H, D), BF16, sharding=one_chip)
+    pool = jax.ShapeDtypeStruct((L, P, H, PAGE, D), BF16, sharding=one_chip)
+    pages = {"width": PER_SLOT * PAGE, "page_size": PAGE}
 
-    def decode(params, tokens, caches, lengths, active, bt):
+    def decode_fn(params, tokens, caches, lengths, n_new, active, bt):
         logits, new = model.decode_step(
             params, {"inputs": tokens}, caches, lengths, slot_mask=active,
-            pages={"bt": bt, "width": PER_SLOT * PAGE, "page_size": PAGE})
+            pages=dict(pages, bt=bt))
         return jnp.argmax(logits[:, 0, :], -1), new
 
-    shapes = [((B, 1), I32), ((B,), I32), ((B,), jnp.bool_),
+    def mixed_fn(params, tokens, caches, lengths, n_new, active, bt):
+        logits, new = model.mixed_step(
+            params, {"inputs": tokens}, caches, lengths, n_new,
+            slot_mask=active, pages=dict(pages, bt=bt))
+        return jnp.argmax(logits, -1), new
+
+    fn, width = (decode_fn, 1) if step == "decode" else (mixed_fn, S)
+    shapes = [((B, width), I32), ((B,), I32), ((B,), I32), ((B,), jnp.bool_),
               ((B, PER_SLOT), I32)]
-    t, ln, act, bt = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-                      for s, dt in shapes]
-    text = jax.jit(decode, donate_argnums=(2,)).lower(
-        params, t, {"k": pool, "v": pool}, ln, act, bt).compile().as_text()
-    moves = re.compile(
-        rf"^\s*(?:ROOT\s+)?%?\S+\s*=\s*bf16\[\d+,{P},{PAGE},{H},{D}\]\S*\s+"
-        r"(copy|fusion|dynamic-slice|dynamic-update-slice)\(")
-    scoped = [line for line in text.splitlines() if moves.match(line)]
-    assert scoped
-    for line in scoped:
-        assert f"/{KV_WRITE_SCOPE}/" in line, line[:200]
+    t, ln, nn, act, bt = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                          for s, dt in shapes]
+    text = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, t, {"k": pool, "v": pool}, ln, nn, act,
+        bt).compile().as_text()
+    pool_dims = (sorted((L, P, H, PAGE, D)), sorted((P, H, PAGE, D)))
+    op = re.compile(r"=\s*(.*?)\s(copy|fusion|dynamic-slice|"
+                    r"dynamic-update-slice|scatter)\(")
+    moves = []
+    for line in text.splitlines():
+        m = op.search(line)
+        if m and any(sorted(int(d) for d in dims.split(",")) in pool_dims
+                     for dims in re.findall(r"\w+\[([\d,]+)\]",
+                                            m.group(1))):
+            moves.append(line[:200])
+    assert not moves, moves
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert any(f"/{KV_WRITE_SCOPE}/" in line for line in kernels)
