@@ -11,8 +11,8 @@ checks:
 
 * every request ends with status ``ok``;
 * the compiled mixed and decode steps hold the phase's Pallas kernels
-  (``tpu_custom_call``): TDA on one chip, plus DMM and SMM when the weights
-  are compressed;
+  (``tpu_custom_call``): TDA, on one chip or per rank on four, plus DMM
+  and SMM when the weights are compressed;
 * teacher forcing: each request's prompt + output is re-run through
   ``Model.hidden`` on the jnp reference path (no paging, no kernels, and
   for compressed weights the explicitly decompressed factors), with the
@@ -108,14 +108,16 @@ PHASES = {
                  "decode": ("tda_paged_decode_attention", "dmm_matmul",
                             "smm_matmul")}),
     # starcoder2-15b at full width and depth (32 GB of bf16 weights) over
-    # a 1x4 mesh: KV heads and weights split four ways. The sharded path
-    # attends through jnp partials, so no kernel is required.
+    # a 1x4 mesh: KV heads and weights split four ways. The TDA kernels
+    # run once per rank on the rank's heads (no merge across ranks).
     "sharded": Phase(
         "sharded", "starcoder2-15b",
         EngineConfig(max_len=256, max_new_tokens=32, num_slots=8,
                      max_prompt_len=480),
         requests=8, prompt_len=(100, 480), max_new=(16, 32),
-        shared_prefix=128),
+        shared_prefix=128,
+        kernels={"mixed": ("tda_mixed_attention",),
+                 "decode": ("tda_paged_decode_attention",)}),
 }
 
 

@@ -36,7 +36,13 @@ and bitwise-stable — corner of the same contract.
 ``shard_map`` over the mesh's ``model`` axis and is the drop-in the dense
 ``decode_attention`` path dispatches to when the mesh is tensor-parallel;
 ``decode_partials`` / ``merge_partials`` are the pure pieces the unit
-tests (and a future per-rank Pallas dispatch) build on.
+tests build on.
+
+The TDA serving path needs no merge at all: :func:`per_rank` runs a
+region of the paged step — the page write and the paged decode or mixed
+kernel — once per rank in one ``shard_map``, each rank on its own heads
+of the stacked page pool, and its output leaves sharded on heads for the
+row-parallel output projection.
 """
 from __future__ import annotations
 
@@ -47,7 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["decode_partials", "merge_partials", "sharded_decode_attention"]
+__all__ = ["decode_partials", "merge_partials", "sharded_decode_attention",
+           "per_rank"]
 
 NEG_INF = -1e30  # matches models/layers.py: finite masked-score sentinel
 _EPS = 1e-30     # matches the TDA kernel's finish division guard
@@ -186,3 +193,28 @@ def sharded_decode_attention(
         in_specs=(P(), kv_spec, kv_spec, P(), sc_spec, sc_spec),
         out_specs=P())(q, k_cache, v_cache, hi, ksl, vsl)
     return out.reshape(B, 1, Hq, D).astype(q.dtype)
+
+
+def per_rank(fn, mesh, heads, out_heads, *, axis: str = "model"):
+    """``fn`` as one ``shard_map`` over ``mesh``'s ``axis``: each rank runs
+    it on its own block of heads. ``heads[i]`` is the head axis of every
+    array in the ``i``-th argument (a pytree: a pool dict, a token-major
+    tensor), ``None`` for arguments every rank reads whole (block tables,
+    bounds, the layer); ``out_heads`` gives the same for each output.
+
+    With the serving layouts — token-major ``(B, S, H, ...)`` tensors and
+    head-major pools ``(L?, P, Hkv, page_size, ...)`` split over the
+    KV-head axis, query heads in aligned blocks of the GQA group — a rank
+    holds whole heads, so nothing crosses ranks. Kernels inside ``fn``
+    see per-rank shapes, and a pool aliased to its output stays in place.
+    Without a mesh, or on a one-rank axis, ``fn`` is returned as it is.
+    """
+    if mesh is None or axis not in mesh.axis_names or mesh.shape[axis] <= 1:
+        return fn
+
+    def spec(h):
+        return P() if h is None else P(*(None,) * h, axis)
+
+    return jax.shard_map(fn, mesh=mesh, check_vma=False,
+                         in_specs=tuple(spec(h) for h in heads),
+                         out_specs=tuple(spec(h) for h in out_heads))

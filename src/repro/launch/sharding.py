@@ -200,10 +200,11 @@ def slot_cache_specs(cache_shapes: Any, mesh: Mesh,
     tiny (num_slots) and the decode step's per-slot scatter writes would
     gather the whole cache if sequence were split, so unlike
     :func:`cache_specs` the shard axis is the KV head: each rank owns all
-    pages of ``Hkv / tp`` heads (its head-slice of every physical page),
-    block tables and scalar slot metadata replicate, and the sharded
-    decode attention merges per-rank softmax partials
-    (kernels/tda/sharded.py). int8 KV scale leaves (``(L?, P, Hkv,
+    pages of ``Hkv / tp`` heads (its head-slice of every physical page)
+    and block tables and scalar slot metadata replicate. These are the
+    shards the TDA kernels run on, once per rank
+    (``kernels/tda/sharded.per_rank``); the dense path merges per-rank
+    softmax partials instead. int8 KV scale leaves (``(L?, P, Hkv,
     page_size)`` / ``(..., Hkv)``) mirror their codes; recurrent state
     leaves replicate (they are neither paged nor head-structured).
     """

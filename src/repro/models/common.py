@@ -62,8 +62,12 @@ class ModelConfig:
     n_kv_heads: Optional[int] = None  # None -> MHA
     d_head: Optional[int] = None  # None -> d_model // n_heads
     qkv_bias: bool = False
+    # Biases on the attention output and the feed-forward linears; with
+    # qkv_bias, on every linear of the block (starcoder2's ``use_bias``).
+    linear_bias: bool = False
     act: str = "swiglu"  # swiglu | gelu | geglu
     norm: str = "rmsnorm"  # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     rope: bool = True
     rope_theta: float = 1e6
     tie_embeddings: bool = False

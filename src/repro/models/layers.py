@@ -17,6 +17,7 @@ Design notes
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -36,6 +37,8 @@ from repro.kernels.tda.ops import (
     gather_paged_lanes,
     write_paged_kv,
 )
+from repro.kernels.tda.sharded import per_rank, sharded_decode_attention
+from repro.launch.mesh import tensor_parallel_size
 from repro.models.common import ModelConfig
 
 NEG_INF = -1e30
@@ -45,6 +48,23 @@ NEG_INF = -1e30
 # K/V into the page pool, and the attention call over it.
 KV_WRITE_SCOPE = "kv_write"
 ATTN_SCOPE = "attn_kernel"
+# On a tensor-parallel mesh only: the row-parallel projections (attention
+# output, feed-forward down), whose partial sums the partitioner joins by
+# an all-reduce across ranks.
+TP_REDUCE_SCOPE = "tp_reduce"
+
+
+def _row_parallel(mesh):
+    """The name scope of a row-parallel projection: ``tp_reduce`` on a
+    tensor-parallel mesh, none on one device."""
+    if tensor_parallel_size(mesh) > 1:
+        return jax.named_scope(TP_REDUCE_SCOPE)
+    return contextlib.nullcontext()
+
+
+def _pool_head_axis(layer_idx) -> int:
+    """The KV-head axis of a head-major page pool, ``(L?, P, Hkv, ...)``."""
+    return 1 if layer_idx is None else 2
 
 
 def constrain_batch(x: jnp.ndarray, mesh,
@@ -112,8 +132,15 @@ def rope_tables(positions: jnp.ndarray, dim: int, theta: float) -> Tuple[jnp.nda
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
-    """x: (B, S, H, D); cos/sin: (B, S, D//2) — rotate pairs (even, odd)."""
-    x1, x2 = x[..., 0::2], x[..., 1::2]
+    """x: (B, S, H, D); cos/sin: (B, S, D//2) — rotate pairs (even, odd).
+    The pairs are split by a reshape, not by strided indexing: that lowers
+    to a gather, which the partitioner meets by gathering a head-sharded
+    ``x`` whole on every rank. The barrier keeps XLA from folding the
+    reshape into the projection that made ``x``: in a one-token step it
+    would relayout the whole weight matrix on every call instead."""
+    x = jax.lax.optimization_barrier(x)
+    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
     c = cos[:, :, None, :]
     s = sin[:, :, None, :]
     out = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
@@ -337,8 +364,6 @@ def decode_attention(
     v_scale: Optional[jnp.ndarray] = None,
     impl: str = "dense",
     block_k: int = 128,
-    block_table: Optional[jnp.ndarray] = None,  # (B, n) paged lane pool
-    layer=None,  # paged: the layer of a layer-stacked pool
     mesh=None,  # tensor-parallel mesh: dispatch to the sharded merge path
 ) -> jnp.ndarray:
     """Single-token attention against a (possibly ring-buffered) KV cache.
@@ -356,36 +381,22 @@ def decode_attention(
     VMEM. ``impl="dense"`` is this jnp path — with scales it dequantizes
     the whole cache first, which the kernel exists to avoid.
 
-    ``block_table`` switches the tda path to the **paged lane pool**
-    layout: ``k_cache``/``v_cache`` are then head-major page pools
-    ``(P, Hkv, page_size, D)``, or with ``layer`` the layer-stacked
-    ``(L, P, Hkv, page_size, D)``, and ``block_table[b, i]`` names the
-    physical page holding logical kv block ``i`` of slot ``b`` (one page
-    = one kv block); the kernel reads it in place by scalar prefetch.
-    Bounds semantics are unchanged.
+    Paged lane pools are read by :func:`attention_block` itself (the
+    paged TDA kernel, or this dense path over gathered lanes).
 
     ``mesh`` with a >1 ``model`` axis dispatches to the tensor-parallel
     form (:mod:`repro.kernels.tda.sharded`): caches sharded on the KV-head
     axis, per-rank online-softmax partials merged by one cross-rank
-    rescale/psum. Paged pools are gathered to lane views first (the
-    gather is shard-local — layer, page and position axes are replicated).
+    rescale/psum.
     """
-    from repro.launch.mesh import tensor_parallel_size
     if tensor_parallel_size(mesh) > 1:
-        from repro.kernels.tda.sharded import sharded_decode_attention
-        if block_table is not None:
-            k_cache, v_cache, k_scale, v_scale = (
-                None if x is None
-                else gather_paged_lanes(x, block_table, layer)
-                for x in (k_cache, v_cache, k_scale, v_scale))
         return sharded_decode_attention(
             q, k_cache, v_cache, cache_index, mesh=mesh, window=window,
             k_scale=k_scale, v_scale=v_scale)
     if impl == "tda":
         return fused_decode_attention(
             q, k_cache, v_cache, cache_index, k_scale=k_scale,
-            v_scale=v_scale, window=window, block_k=block_k,
-            block_table=block_table, layer=layer)
+            v_scale=v_scale, window=window, block_k=block_k)
     if k_scale is not None:
         k_cache = k_cache.astype(jnp.float32) * k_scale[..., None]
         v_cache = v_cache.astype(jnp.float32) * v_scale[..., None]
@@ -427,7 +438,7 @@ def init_attention(key: jax.Array, cfg: ModelConfig, bank: Optional[DictionaryBa
         "wv": init_linear(ks[2], d, cfg.kv_heads * hd, fcfg, bank, f"{prefix}_v",
                           use_bias=cfg.qkv_bias, dtype=cfg.params_dtype),
         "wo": init_linear(ks[3], cfg.n_heads * hd, d, fcfg, bank, f"{prefix}_o",
-                          dtype=cfg.params_dtype),
+                          use_bias=cfg.linear_bias, dtype=cfg.params_dtype),
     }
 
 
@@ -474,7 +485,11 @@ def attention_block(
     q = lin("wq", x, f"{prefix}_q").reshape(B, S, cfg.n_heads, hd)
     k = lin("wk", x_kv, f"{prefix}_k").reshape(B, x_kv.shape[1], cfg.kv_heads, hd)
     v = lin("wv", x_kv, f"{prefix}_v").reshape(B, x_kv.shape[1], cfg.kv_heads, hd)
-    mdim = 2 if cfg.constrain_acts else None
+    # A paged serving step on a tensor-parallel mesh pins the head axis
+    # too: left to itself the partitioner gathers whole 512-column chunks
+    # of q, k and v on every rank before the per-rank kernels.
+    mdim = 2 if cfg.constrain_acts or (
+        pages is not None and tensor_parallel_size(mesh) > 1) else None
     q, k, v = (constrain_batch(t, mesh, model_dim=mdim) for t in (q, k, v))
 
     if cfg.rope and kv is None:
@@ -531,27 +546,39 @@ def attention_block(
         else:
             k_row, v_row = k, v
 
-        from repro.launch.mesh import tensor_parallel_size
-        impl = resolve_decode_attn(cfg.decode_attn)
-        use_kernel = impl == "tda" and tensor_parallel_size(mesh) <= 1
-        # The pool is read and written in place, addressed by (layer,
-        # page); the write is ordered after the attention by a data edge
-        # (its output), since a chunk may overwrite a ring position an
-        # earlier query still reads.
-        with jax.named_scope(ATTN_SCOPE):
-            o = fused_mixed_attention(
-                q, cache["k"], cache["v"], k_row, v_row, ci, nn,
-                block_table=bt, ring=ringw, window=window,
-                k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
-                layer=layer_idx, use_kernel=use_kernel)
-        with jax.named_scope(KV_WRITE_SCOPE):
-            new = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc} \
-                if quant else {"k": k, "v": v}
-            new_cache = write_paged_kv(
-                {name: cache[name] for name in new}, new, ci, nn,
-                block_table=bt, ring=ringw, layer=layer_idx, after=o,
-                use_kernel=use_kernel)
-        o = o.reshape(B, S, cfg.n_heads * hd)
+        use_kernel = resolve_decode_attn(cfg.decode_attn) == "tda"
+        new = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc} \
+            if quant else {"k": k, "v": v}
+
+        def attend_then_write(q, pools, k_row, v_row, new, ci, nn, bt,
+                              layer):
+            # The pool is read and written in place, addressed by (layer,
+            # page); the write is ordered after the attention by a data
+            # edge (its output), since a chunk may overwrite a ring
+            # position an earlier query still reads.
+            with jax.named_scope(ATTN_SCOPE):
+                o = fused_mixed_attention(
+                    q, pools["k"], pools["v"], k_row, v_row, ci, nn,
+                    block_table=bt, ring=ringw, window=window,
+                    k_scale=pools.get("k_scale"),
+                    v_scale=pools.get("v_scale"), layer=layer,
+                    use_kernel=use_kernel)
+            with jax.named_scope(KV_WRITE_SCOPE):
+                pools = write_paged_kv(
+                    pools, new, ci, nn, block_table=bt, ring=ringw,
+                    layer=layer, after=o, use_kernel=use_kernel)
+            # Heads flattened here, per rank: the output projection reads
+            # (B, S, heads x D) sharded on its last axis.
+            return o.reshape(o.shape[:2] + (-1,)), pools
+
+        hp = _pool_head_axis(layer_idx)
+        if use_kernel:
+            attend_then_write = per_rank(
+                attend_then_write, mesh, (2, hp, 2, 2, 2, None, None, None,
+                                          None), (2, hp))
+        o, new_cache = attend_then_write(
+            q, {name: cache[name] for name in new}, k_row, v_row, new, ci,
+            nn, bt, layer_idx)
     elif cache is not None and S == 1 and pages is not None:
         # ---- paged decode: lanes live in a page pool (serve/pages.py) ----
         # Logical lane coordinates are the contiguous layout's (canonical
@@ -561,7 +588,6 @@ def attention_block(
         # reads the written pool at (layer, page).
         ringw = pages["width"]  # logical lane width (static int)
         bt = pages["bt"]        # (B, n) int32; FREE sentinel == num_pages
-        from repro.launch.mesh import tensor_parallel_size
         impl = resolve_decode_attn(cfg.decode_attn)
         if quant:
             kq, ksc = kv_quantize(k)
@@ -569,15 +595,11 @@ def attention_block(
             new = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
         else:
             new = {"k": k, "v": v}
+        pools = {name: cache[name] for name in new}
+        ci = jnp.reshape(cache_index, (-1,))
         # Inactive slots write nothing: the lane stays untouched.
         nn = jnp.ones((B,), jnp.int32) if slot_mask is None \
             else jnp.reshape(slot_mask, (-1,)).astype(jnp.int32)
-        with jax.named_scope(KV_WRITE_SCOPE):
-            new_cache = write_paged_kv(
-                {name: cache[name] for name in new}, new,
-                jnp.reshape(cache_index, (-1,)), nn, block_table=bt,
-                ring=ringw, layer=layer_idx,
-                use_kernel=impl == "tda" and tensor_parallel_size(mesh) <= 1)
         if slot_mask is not None:
             cache_index = jnp.where(jnp.reshape(slot_mask, (-1,)),
                                     cache_index, -1)
@@ -585,24 +607,39 @@ def attention_block(
         # (canonical ring phase) — same bounds as the contiguous layout.
         hi = cache_index + 1 if window is None \
             else jnp.minimum(cache_index + 1, ringw)
-        with jax.named_scope(ATTN_SCOPE):
-            if impl == "tda":
+        if impl == "tda":
+            def write_then_attend(q, pools, new, ci, nn, hi, bt, layer):
+                with jax.named_scope(KV_WRITE_SCOPE):
+                    pools = write_paged_kv(pools, new, ci, nn,
+                                           block_table=bt, ring=ringw,
+                                           layer=layer)
                 # The kernel consumes the stacked page pools directly: the
                 # layer and block table ride scalar prefetch and one page
                 # is one kv block.
-                o = decode_attention(
-                    q, new_cache["k"], new_cache["v"], hi,
-                    k_scale=new_cache.get("k_scale"),
-                    v_scale=new_cache.get("v_scale"), impl="tda",
-                    block_k=cfg.decode_block_k, block_table=bt,
-                    layer=layer_idx, mesh=mesh)
-            else:
-                # Dense path: gather each slot's lane view out of the pool
-                # (same data volume as reading a dense lane), then attend.
-                def lanes(name):
-                    return gather_paged_lanes(new_cache[name], bt,
-                                              layer_idx)
+                with jax.named_scope(ATTN_SCOPE):
+                    o = fused_decode_attention(
+                        q, pools["k"], pools["v"], hi,
+                        k_scale=pools.get("k_scale"),
+                        v_scale=pools.get("v_scale"), block_table=bt,
+                        layer=layer)
+                return o.reshape(o.shape[:2] + (-1,)), pools
 
+            hp = _pool_head_axis(layer_idx)
+            o, new_cache = per_rank(
+                write_then_attend, mesh, (2, hp, 2, None, None, None, None,
+                                          None), (2, hp))(
+                q, pools, new, ci, nn, hi, bt, layer_idx)
+        else:
+            with jax.named_scope(KV_WRITE_SCOPE):
+                new_cache = write_paged_kv(pools, new, ci, nn,
+                                           block_table=bt, ring=ringw,
+                                           layer=layer_idx, use_kernel=False)
+            # Dense path: gather each slot's lane view out of the pool
+            # (same data volume as reading a dense lane), then attend.
+            def lanes(name):
+                return gather_paged_lanes(new_cache[name], bt, layer_idx)
+
+            with jax.named_scope(ATTN_SCOPE):
                 if quant:
                     kc = kv_dequantize(lanes("k"), lanes("k_scale"), dt)
                     vc = kv_dequantize(lanes("v"), lanes("v_scale"), dt)
@@ -744,8 +781,9 @@ def attention_block(
                 wedge=cfg.causal_wedge,
             ).reshape(B, S, cfg.n_heads * hd)
 
-    y = apply_linear(p["wo"], o, dicts, f"{prefix}_o", fcfg, sparse_train,
-                     compute_dtype=dt)
+    with _row_parallel(mesh):
+        y = apply_linear(p["wo"], o, dicts, f"{prefix}_o", fcfg,
+                         sparse_train, compute_dtype=dt)
     return y.astype(dt), new_cache
 
 
@@ -760,13 +798,14 @@ def init_ffn(key: jax.Array, cfg: ModelConfig, bank: Optional[DictionaryBank],
     f = d_ff or cfg.d_ff
     fcfg = cfg.factorization
     ks = jax.random.split(key, 3)
+    bias = cfg.linear_bias
     p = {"w_up": init_linear(ks[0], d, f, fcfg, bank, f"{prefix}_up",
-                             dtype=cfg.params_dtype),
+                             use_bias=bias, dtype=cfg.params_dtype),
          "w_down": init_linear(ks[1], f, d, fcfg, bank, f"{prefix}_down",
-                               dtype=cfg.params_dtype)}
+                               use_bias=bias, dtype=cfg.params_dtype)}
     if cfg.act in ("swiglu", "geglu"):
         p["w_gate"] = init_linear(ks[2], d, f, fcfg, bank, f"{prefix}_gate",
-                                  dtype=cfg.params_dtype)
+                                  use_bias=bias, dtype=cfg.params_dtype)
     return p
 
 
@@ -789,7 +828,8 @@ def ffn_block(p: Dict, x: jnp.ndarray, *, cfg: ModelConfig, dicts: Optional[Dict
         h = jax.nn.gelu(up)
     if cfg.constrain_acts:
         h = constrain_batch(h, mesh, model_dim=-1)
-    return lin("w_down", h, f"{prefix}_down")
+    with _row_parallel(mesh):
+        return lin("w_down", h, f"{prefix}_down")
 
 
 # --------------------------------------------------------------------------

@@ -125,16 +125,17 @@ class EncDecModel:
         enc_cfg = dataclasses.replace(cfg, causal=False)
         for i in range(cfg.n_encoder_layers):
             lp = p["encoder"][f"layer_{i:02d}"]
-            h = L.apply_norm(lp["norm1"], x)
+            h = L.apply_norm(lp["norm1"], x, cfg.norm_eps)
             a, _ = L.attention_block(lp["attn"], h, cfg=enc_cfg, dicts=dicts,
                                      positions=pos, seg_ids=seg,
                                      sparse_train=sparse_train,
                                      prefix="enc_attn")
             x = x + a
-            x = x + L.ffn_block(lp["ffn"], L.apply_norm(lp["norm2"], x),
+            x = x + L.ffn_block(lp["ffn"],
+                                L.apply_norm(lp["norm2"], x, cfg.norm_eps),
                                 cfg=cfg, dicts=dicts,
                                 sparse_train=sparse_train, prefix="enc_ffn")
-        return L.apply_norm(p["enc_norm"], x)
+        return L.apply_norm(p["enc_norm"], x, cfg.norm_eps)
 
     def decode(self, p: Dict, memory: jnp.ndarray, batch: Dict,
                sparse_train=False) -> jnp.ndarray:
@@ -148,23 +149,24 @@ class EncDecModel:
         seg_kv = batch.get("src_seg")
         for i in range(cfg.n_layers):
             lp = p["decoder"][f"layer_{i:02d}"]
-            h = L.apply_norm(lp["norm1"], x)
+            h = L.apply_norm(lp["norm1"], x, cfg.norm_eps)
             a, _ = L.attention_block(lp["attn"], h, cfg=cfg, dicts=dicts,
                                      positions=pos, seg_ids=None,
                                      sparse_train=sparse_train,
                                      prefix="dec_attn")
             x = x + a
-            h = L.apply_norm(lp["norm2"], x)
+            h = L.apply_norm(lp["norm2"], x, cfg.norm_eps)
             a, _ = L.attention_block(lp["xattn"], h, cfg=cfg, dicts=dicts,
                                      positions=pos, seg_ids=None,
                                      kv=memory, seg_kv=seg_kv,
                                      sparse_train=sparse_train,
                                      prefix="dec_xattn")
             x = x + a
-            x = x + L.ffn_block(lp["ffn"], L.apply_norm(lp["norm3"], x),
+            x = x + L.ffn_block(lp["ffn"],
+                                L.apply_norm(lp["norm3"], x, cfg.norm_eps),
                                 cfg=cfg, dicts=dicts,
                                 sparse_train=sparse_train, prefix="dec_ffn")
-        x = L.apply_norm(p["dec_norm"], x)
+        x = L.apply_norm(p["dec_norm"], x, cfg.norm_eps)
         return x.astype(jnp.float32) @ p["lm_head"]["w"].astype(jnp.float32)
 
     def loss(self, p: Dict, batch: Dict, sparse_train=False

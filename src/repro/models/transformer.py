@@ -166,7 +166,7 @@ class Model:
         new_cache = None
         if kind in ("attn", "local"):
             window = cfg.local_window if kind == "local" else cfg.sliding_window
-            h = L.apply_norm(lp["norm1"], x)
+            h = L.apply_norm(lp["norm1"], x, cfg.norm_eps)
             a_out, new_cache = L.attention_block(
                 lp["attn"], h, cfg=cfg, dicts=dicts, positions=positions,
                 seg_ids=seg_ids, window=window, cache=cache_l,
@@ -174,7 +174,7 @@ class Model:
                 layer_idx=layer_idx, pages=pages_l, prefix_kv=prefix_l,
                 n_new=n_new, sparse_train=sparse_train, mesh=mesh)
             x = x + a_out
-            h2 = L.apply_norm(lp["norm2"], x)
+            h2 = L.apply_norm(lp["norm2"], x, cfg.norm_eps)
             if cfg.moe is not None:
                 mo, aux = M.moe_ffn(lp["moe"], h2, cfg=cfg, dicts=dicts,
                                     mesh=mesh, sparse_train=sparse_train)
@@ -187,7 +187,7 @@ class Model:
                 x = x + L.ffn_block(lp["ffn"], h2, cfg=cfg, dicts=dicts,
                                     sparse_train=sparse_train, mesh=mesh)
         elif kind == "ssd":
-            h = L.apply_norm(lp["norm1"], x)
+            h = L.apply_norm(lp["norm1"], x, cfg.norm_eps)
             out, new_cache = S.ssd_block(
                 lp["ssd"], h, cfg=cfg, dicts=dicts, cache=cache_l,
                 cache_index=cache_index, layer_idx=layer_idx,
@@ -195,13 +195,13 @@ class Model:
                 sparse_train=sparse_train)
             x = x + out
         elif kind == "rglru":
-            h = L.apply_norm(lp["norm1"], x)
+            h = L.apply_norm(lp["norm1"], x, cfg.norm_eps)
             out, new_cache = R.rglru_block(lp["rglru"], h, cfg=cfg, dicts=dicts,
                                            cache=cache_l, seg_ids=seg_ids,
                                            slot_mask=slot_mask,
                                            sparse_train=sparse_train)
             x = x + out
-            h2 = L.apply_norm(lp["norm2"], x)
+            h2 = L.apply_norm(lp["norm2"], x, cfg.norm_eps)
             x = x + L.ffn_block(lp["ffn"], h2, cfg=cfg, dicts=dicts,
                                 sparse_train=sparse_train, mesh=mesh)
         return x, new_cache, aux
@@ -341,7 +341,7 @@ class Model:
             params, x, dicts=dicts, positions=positions, seg_ids=seg_ids,
             caches=caches, cache_index=cache_index, mesh=mesh,
             sparse_train=sparse_train, prefix=prefix_kv)
-        x = L.apply_norm(params["final_norm"], x)
+        x = L.apply_norm(params["final_norm"], x, cfg.norm_eps)
         return x, new_caches, aux
 
     def apply(self, params: Dict, batch: Dict, *, mesh=None,
@@ -491,7 +491,7 @@ class Model:
             caches=caches, cache_index=ci, mesh=mesh,
             sparse_train=False, unroll=cfg.unroll_decode,
             slot_mask=slot_mask, pages=pages)
-        x = L.apply_norm(params["final_norm"], x)
+        x = L.apply_norm(params["final_norm"], x, cfg.norm_eps)
         logits = L.lm_logits(params["lm_head"], params["embed"], x, cfg)
         return logits, new_caches
 
@@ -536,7 +536,7 @@ class Model:
             n_new=nn)
         last = jnp.clip(nn - 1, 0, S - 1)
         x = jnp.take_along_axis(x, last[:, None, None], axis=1)  # (B, 1, d)
-        x = L.apply_norm(params["final_norm"], x)
+        x = L.apply_norm(params["final_norm"], x, cfg.norm_eps)
         logits = L.lm_logits(params["lm_head"], params["embed"], x, cfg)
         return logits[:, 0], new_caches
 

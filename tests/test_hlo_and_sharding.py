@@ -153,3 +153,41 @@ def test_sharded_train_step_runs_and_matches_single_device():
         print(json.dumps({"loss": float(metrics["loss"]), "ref": ref_loss}))
     """)
     assert abs(r["loss"] - r["ref"]) / r["ref"] < 0.02
+
+
+def test_tp4_serving_specs_match_the_per_rank_call():
+    """starcoder2-15b's placement on the 1x4 serving mesh is what the
+    per-rank TDA region (``models/layers.py``, ``per_rank``) expects: the
+    stacked page pool is split on its KV-head axis (axis 2, one head a
+    rank), q/k/v and the feed-forward up projection are column-parallel
+    with their biases split alike, and the row-parallel output and down
+    projections are split on their input rows with their biases
+    replicated, so each is added once, after the all-reduce."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AbstractMesh
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch import sharding as shd
+    from repro.launch.serve import build_model
+    from repro.models.layers import _pool_head_axis
+
+    mesh = AbstractMesh((1, 4), ("data", "model"))
+    model = build_model("starcoder2-15b", "full")
+    specs = shd.param_specs(jax.eval_shape(model.init, jax.random.key(0)),
+                            mesh)
+    layer = specs["layers"]
+    for lin in ("wq", "wk", "wv"):
+        assert layer["attn"][lin]["w"] == P(None, "data", "model")
+        assert layer["attn"][lin]["b"] == P(None, "model")
+    assert layer["ffn"]["w_up"]["w"] == P(None, "data", "model")
+    assert layer["ffn"]["w_up"]["b"] == P(None, "model")
+    assert layer["attn"]["wo"]["w"] == P(None, "model", "data")
+    assert layer["ffn"]["w_down"]["w"] == P(None, "model", "data")
+    assert layer["attn"]["wo"]["b"] == P(None, None)
+    assert layer["ffn"]["w_down"]["b"] == P(None, None)
+    pool = jax.ShapeDtypeStruct((40, 289, 4, 128, 128), jnp.bfloat16)
+    got = shd.slot_cache_specs({"k": pool, "v": pool}, mesh, paged=True)
+    head = _pool_head_axis(layer_idx=0)
+    want = P(*[("model" if i == head else None) for i in range(5)])
+    assert got == {"k": want, "v": want}

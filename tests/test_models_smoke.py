@@ -154,3 +154,44 @@ def test_bf16_param_dtype_holds_for_weight_leaves(factorized):
                 in jax.tree_util.tree_flatten_with_path(shapes)[0]
                 if leaf.dtype != jnp.bfloat16 and leaf.ndim >= 2]
         assert not wide, (arch, wide)
+
+
+def _leaves(arch):
+    m = Model(get_config(arch, "full"))
+    flat = jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(m.init, jax.random.key(0)))[0]
+    return m.cfg, {jax.tree_util.keystr(p): x.shape for p, x in flat}
+
+
+def test_starcoder2_full_is_the_published_block():
+    """starcoder2-15b as published (``use_bias``: a bias on every attention
+    and feed-forward linear; LayerNorm with bias, eps 1e-5; tanh GELU, not
+    gated), and its smoke variant the same block."""
+    cfg, leaves = _leaves("starcoder2-15b")
+    assert (cfg.norm, cfg.norm_eps, cfg.act) == ("layernorm", 1e-5, "gelu")
+    assert get_config("starcoder2-15b", "smoke").norm_eps == 1e-5
+    for lin, width in (("attn']['wq", 48 * 128), ("attn']['wk", 4 * 128),
+                       ("attn']['wv", 4 * 128), ("attn']['wo", 6144),
+                       ("ffn']['w_up", 24576), ("ffn']['w_down", 6144)):
+        assert leaves[f"['layers']['{lin}']['b']"] == (40, width), lin
+    assert "['layers']['ffn']['w_gate']['w']" not in leaves
+    for norm in ("['layers']['norm1']", "['layers']['norm2']",
+                 "['final_norm']"):
+        assert f"{norm}['bias']" in leaves
+    assert sum(int(np.prod(s)) for s in leaves.values()) == 15_957_889_024
+
+
+def test_qwen1_5_4b_parameter_tree_is_unchanged():
+    """The published-bias fields default off: qwen1.5-4b keeps its leaves
+    (biases on q, k and v only), its size and its RMSNorm eps 1e-6."""
+    cfg, leaves = _leaves("qwen1.5-4b")
+    assert (cfg.linear_bias, cfg.norm_eps) == (False, 1e-6)
+    assert sorted(leaves) == sorted(
+        ["['embed']['tok']", "['final_norm']['scale']", "['lm_head']['w']",
+         "['layers']['norm1']['scale']", "['layers']['norm2']['scale']"]
+        + [f"['layers']['attn']['{n}']['{k}']" for n in ("wq", "wk", "wv")
+           for k in ("w", "b")]
+        + ["['layers']['attn']['wo']['w']"]
+        + [f"['layers']['ffn']['{n}']['w']"
+           for n in ("w_up", "w_gate", "w_down")])
+    assert sum(int(np.prod(s)) for s in leaves.values()) == 3_950_369_280

@@ -20,6 +20,8 @@ ring/windowed lanes, sampled decode (seeded sampling is placement- and
 mesh-invariant), and preemption + prefix-sharing/CoW invisibility under
 audit on a mesh.
 """
+import pathlib
+
 import pytest
 
 # Shared child preamble: model/engine builders + a runner that serves the
@@ -267,3 +269,118 @@ def test_params_and_pages_built_straight_into_shards(mesh_cpu):
     assert out["wq_shard_bytes"] == [out["wq_bytes"] // 4]
     assert out["kv_shard_bytes"] == [out["kv_bytes"] // 4]
     assert out["kv_devices"] == 4
+
+
+def test_tp4_tda_engine_matches_reference_logits(mesh_cpu):
+    """starcoder2 at a smoke size that splits as the published model does
+    over four ranks (4 KV heads, 12 query heads each; head size 8), with
+    the published block (biases on every linear, seeded nonzero by the
+    benchmark's own weights, LayerNorm eps 1e-5, window 32): the Engine
+    on a 1x4 mesh with ``decode_attn="tda"`` runs the paged kernels per
+    rank (interpret mode), and the logits of every step it serves —
+    prompt chunks through the mixed step, then decode through the page
+    pool — match the benchmark's plain reference (``reference.py``, a
+    full float32 forward with no cache) at the same positions, and match
+    the same engine with ``decode_attn="dense"`` and the one-device
+    engine. Prompts pass the window, so the lanes are rings. Float32
+    throughout: the only differences are the order of float32 sums
+    (kernel tiles, row-parallel all-reduce), a few 1e-6 on logits of
+    unit size; the limit is 5e-5."""
+    bench = pathlib.Path(__file__).resolve().parents[1] / "benchmarks/chip"
+    path = f"import sys\nsys.path.insert(0, {str(bench)!r})\n"
+    r = mesh_cpu(4, COMMON + path + """
+import reference
+import weights
+from repro.models import transformer as T
+
+records = []
+
+
+def tap(name):
+    orig = getattr(T.Model, name)
+
+    def step(self, params, batch, caches, cache_index, *a, **kw):
+        out = orig(self, params, batch, caches, cache_index, *a, **kw)
+        if name == "mixed_step":
+            logits, n_new = out[0], a[0]
+        else:
+            logits, n_new = out[0][:, 0], jnp.ones_like(cache_index)
+        jax.debug.callback(
+            lambda *v: records.append([np.asarray(x) for x in v]),
+            logits, cache_index, n_new, kw["slot_mask"])
+        return out
+
+    setattr(T.Model, name, step)
+
+
+tap("mixed_step")
+tap("decode_step")
+
+cfg = get_config("starcoder2-15b", "smoke", dtype="float32", n_heads=48,
+                 n_kv_heads=4, d_head=8)
+assert (cfg.linear_bias, cfg.norm_eps) == (True, 1e-5)
+m = Model(cfg)
+params = weights.make_weights(m, 2**33 + 7)
+lens = [37, 9, 44, 21, 30]
+rng = np.random.default_rng(3)
+prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32) for n in lens]
+
+
+def serve_logits(mesh, attn):
+    eng = Engine(m, params, mesh=mesh, max_len=16, max_new_tokens=6,
+                 num_slots=3, max_prompt_len=48, page_size=8,
+                 decode_attn=attn)
+    reqs = [Request(rid=i, prompt=p.copy(), max_new_tokens=6)
+            for i, p in enumerate(prompts)]
+    for q in reqs:
+        eng.submit(q)
+    rows = {}
+    while eng.has_work():
+        before = list(eng.slots.request)
+        n0 = len(records)
+        eng.step()
+        jax.effects_barrier()
+        after = list(eng.slots.request)
+        for logits, ci, nn, act in records[n0:]:
+            for b in np.flatnonzero(act & (nn > 0)):
+                req = after[b] if after[b] is not None else before[b]
+                rows[(req.rid, int(ci[b] + nn[b] - 1))] = logits[b]
+    eng.finish_run()
+    assert all(q.status == "ok" for q in reqs)
+    return rows, {q.rid: list(q.output) for q in reqs}, eng.decode_attn
+
+
+tp, out_tp, attn = serve_logits(make_local_mesh(1, 4), "tda")
+assert attn == "tda"
+dense, out_dense, _ = serve_logits(make_local_mesh(1, 4), "dense")
+one, out_one, _ = serve_logits(None, "tda")
+
+ref = reference.Reference(
+    {"hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+     "num_key_value_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
+     "rope_theta": cfg.rope_theta, "sliding_window": cfg.sliding_window,
+     "model_type": "starcoder2", "norm_epsilon": 1e-5,
+     "hidden_act": "gelu_pytorch_tanh"}, 64, 64)
+ref_gap = 0.0
+for rid, out in out_tp.items():
+    seq = np.concatenate([prompts[rid], np.asarray(out, np.int32)])
+    pos = np.array(sorted(p for r_, p in tp if r_ == rid), np.int32)
+    want = np.asarray(ref._logits(params, jnp.asarray(seq),
+                                  jnp.asarray(pos), False))
+    got = np.stack([tp[(rid, int(p))] for p in pos])
+    ref_gap = max(ref_gap, float(np.abs(got - want).max()))
+steps_seen = {p for _, p in tp}
+print(json.dumps({
+    "rows": len(tp), "max_pos": max(steps_seen),
+    "ref": ref_gap,
+    "dense": max(float(np.abs(tp[k] - dense[k]).max()) for k in tp),
+    "one": max(float(np.abs(tp[k] - one[k]).max()) for k in tp),
+    "same_keys": sorted(map(list, tp)) == sorted(map(list, dense))
+                 == sorted(map(list, one)),
+    "tokens_same": out_tp == out_dense == out_one}))
+""")
+    # 5 requests x 6 served tokens, and the prompt chunks that end short
+    # of a prompt's last token.
+    assert r["rows"] >= 30 and r["max_pos"] > 40, r
+    assert r["same_keys"] and r["tokens_same"], r
+    assert r["ref"] < 5e-5 and r["dense"] < 5e-5 and r["one"] < 5e-5, r
