@@ -145,7 +145,8 @@ def main():
           f"tests/test_faults.py pins this)")
 
     # ---- bursty mid-decode arrivals: chunked prefill interleaved with
-    # decode in ONE jitted mixed step vs the phase-serialized engine.
+    # decode (the chunk step's rows beside the decode step in each
+    # iteration) vs the phase-serialized engine.
     # Three waves of long prompts (4-8x the chunk width) land while
     # earlier admissions are still decoding; `run(arrivals=...)` replays
     # the identical schedule through both engines. TTFT is reported in

@@ -10,7 +10,8 @@ from __future__ import annotations
 import jax
 
 __all__ = ["make_mesh", "make_production_mesh", "make_local_mesh",
-           "devices_per_pod", "tensor_parallel_size"]
+           "data_parallel_axes", "data_parallel_size", "devices_per_pod",
+           "tensor_parallel_size"]
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
@@ -39,6 +40,23 @@ def tensor_parallel_size(mesh) -> int:
     if mesh is None or "model" not in mesh.axis_names:
         return 1
     return int(mesh.shape["model"])
+
+
+def data_parallel_axes(mesh) -> tuple:
+    """The mesh's batch axes (``pod``, ``data``) that it has; ``()`` for
+    ``mesh=None``."""
+    if mesh is None:
+        return ()
+    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+
+
+def data_parallel_size(mesh) -> int:
+    """Ranks over the batch axes: how many ways a batch dim splits; 1 for
+    ``mesh=None``."""
+    size = 1
+    for a in data_parallel_axes(mesh):
+        size *= int(mesh.shape[a])
+    return size
 
 
 def devices_per_pod(mesh: jax.sharding.Mesh) -> int:

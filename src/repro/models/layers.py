@@ -38,7 +38,8 @@ from repro.kernels.tda.ops import (
     write_paged_kv,
 )
 from repro.kernels.tda.sharded import per_rank, sharded_decode_attention
-from repro.launch.mesh import tensor_parallel_size
+from repro.launch.mesh import (data_parallel_axes, data_parallel_size,
+                               tensor_parallel_size)
 from repro.models.common import ModelConfig
 
 NEG_INF = -1e30
@@ -76,13 +77,8 @@ def constrain_batch(x: jnp.ndarray, mesh,
     constraint at the attention inputs keeps it. The model-dim pin makes
     GSPMD prefer gathering weights over all-reducing big activations
     (§Perf, starcoder2 prefill)."""
-    if mesh is None:
-        return x
-    dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    size = 1
-    for a in dp:
-        size *= mesh.shape[a]
-    if not dp or x.shape[0] % size != 0:
+    dp = data_parallel_axes(mesh)
+    if not dp or x.shape[0] % data_parallel_size(mesh) != 0:
         return x
     spec = [None] * x.ndim
     spec[0] = dp

@@ -69,22 +69,25 @@ tokens admitted) and ``pages_shared`` after :meth:`run`.
 
 **Mixed step** (default for paged attention-only stacks): instead of
 phase-serializing whole-prompt prefill sweeps against the decode loop, the
-engine streams each admitted prompt through per-step *chunks* of one
-fixed-shape jitted ``mixed_fn`` — up to ``prefill_budget`` fresh prompt
-tokens per step packed alongside every active decode slot, writing chunk
-K/V straight into the paged lanes (``Model.mixed_step``). A new request
-claims a free slot immediately (no prefill cache, no lane copy) and its
-time-to-first-token is bounded by ``ceil(prompt / prefill_budget)`` steps
-that keep decoding everyone else, instead of by whoever's full-prompt
-sweep is in front of it. Token-identical to the serialized engine by
-construction: chunk queries attend [resident lane ∥ causal in-row chunk]
-at absolute positions, the completion token is sampled from the same
-logits position with the same keys, and preemption/CoW/prefix sharing
-compose unchanged. ``mixed=False`` forces the serialized phases;
+engine streams each admitted prompt through per-iteration *chunks*: the
+jitted chunk step ``mixed_fn`` runs a row of ``S`` columns for each of the
+oldest mid-prefill slots (as many as stream this iteration, up to every
+slot or ``ceil(prefill_budget / S)``, at a few compiled row counts), their
+fresh prompt tokens, writing chunk K/V straight into the paged lanes
+(``Model.mixed_step``), while the decode step advances every decoding slot
+in the same iteration. A new request claims a free
+slot when a row takes its first chunk (no prefill cache, no lane copy),
+and its time-to-first-token is bounded by the chunks ahead of it, in
+iterations that keep decoding everyone else, instead of by whoever's
+full-prompt sweep is in front of it. Token-identical to the serialized
+engine by construction: chunk queries attend [resident lane ∥ causal
+in-row chunk] at absolute positions, the completion token is sampled from
+the same logits position with the same keys, and preemption/CoW/prefix
+sharing compose unchanged. ``mixed=False`` forces the serialized phases;
 ``mixed=True`` on an unsupported stack (recurrent layers, contiguous
 lanes, quantized KV) raises. :meth:`run` accepts ``arrivals`` — a list of
-``(tick, Request)`` submitted when the virtual clock reaches ``tick`` —
-so bursty mid-decode traffic is replayable, and ``decode_stats["ttft"]``
+``(tick, Request)`` submitted when the virtual clock reaches ``tick`` — so
+bursty mid-decode traffic is replayable, and ``decode_stats["ttft"]``
 records each finished request's first-token latency (wall seconds and
 clock ticks) for the ``ttft_p50``/``ttft_p99`` bench sidecars.
 
@@ -150,7 +153,7 @@ from repro.core.packing import chunk_prompt
 from repro.kernels.common import resolve_decode_attn
 from repro.kernels.tda.ref import block_stats
 from repro.launch import sharding as shd
-from repro.launch.mesh import tensor_parallel_size
+from repro.launch.mesh import data_parallel_size, tensor_parallel_size
 from repro.models.transformer import Model
 from repro.serve.config import RECURRENT_KINDS, EngineConfig
 from repro.serve.faults import FaultInjector, FaultPlan
@@ -212,7 +215,10 @@ class StepResult:
     ``"mixed"``; ``None`` when nothing was dispatched), per slot the lane
     length before the dispatch (``starts``) and the fresh tokens it
     processed (``n_new``: 1 for a decode row, the chunk for a chunk row, 0
-    for an inert or empty row), and the pool's pages in use (paged)."""
+    for an inert or empty row), the pool's pages in use (paged), and the
+    slots the chunk step ran, in row order (``chunk_slots``; ``None`` when
+    it did not run). A ``"mixed"`` iteration ran the chunk step, and the
+    decode step too when a slot was decoding."""
 
     emitted: List[Tuple[Request, int]] = dataclasses.field(
         default_factory=list)
@@ -222,6 +228,7 @@ class StepResult:
     starts: Optional[np.ndarray] = None
     n_new: Optional[np.ndarray] = None
     pages_in_use: Optional[int] = None
+    chunk_slots: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -253,6 +260,8 @@ class _RunState:
     pages_used_steps: int = 0
     mixed_steps: int = 0
     chunk_tokens: int = 0  # fresh prompt tokens streamed via mixed steps
+    mixed_columns: int = 0  # chunk-step columns paid: R x S a chunk step
+    chunk_waits: int = 0  # queued requests x iterations held for a row
     idle: int = 0  # consecutive iterations with nothing decoded/admitted
 
 
@@ -356,9 +365,9 @@ class Engine:
         self._fleet_restored_pages = 0
         if fleet is not None:
             self.attach_fleet(fleet)
-        # ---- mixed step (chunked prefill interleaved with decode): fold
-        # up to ``prefill_budget`` fresh prompt tokens per step into the
-        # same fixed-shape jitted call that advances every decode slot.
+        # ---- mixed step (chunked prefill interleaved with decode): each
+        # iteration streams up to ``prefill_budget`` fresh prompt tokens
+        # through the chunk step beside the decode step's slots.
         # Needs paged attention lanes (chunk K/V scatters straight through
         # the block tables — there is no prefill cache to lane-copy from)
         # and an attention-only stack (a recurrent layer has no
@@ -375,6 +384,23 @@ class Engine:
         # or a serialized prefill row would.
         self._chunk_width = max(1, min(max_len,
                                        cfg_e.prefill_budget or max_len))
+        # Rows of the chunk step: one per prompt streaming this iteration,
+        # at most R — every slot, or ceil(budget / S) under a
+        # ``prefill_budget`` — so a step pays S columns only for rows that
+        # carry a chunk. It is compiled at a few row counts (the powers of
+        # two below R, and R), a mesh's batch axes a multiple of their
+        # size (so the rows shard instead of running on every batch
+        # rank), and an iteration takes the largest count its prompts
+        # fill.
+        dp = data_parallel_size(mesh)
+        rows = num_slots if cfg_e.prefill_budget is None else min(
+            num_slots, -(-cfg_e.prefill_budget // self._chunk_width))
+        self._chunk_rows = -(-rows // dp) * dp
+        self._row_counts = tuple(sorted(
+            {min(self._chunk_rows, dp << i)
+             for i in range(self._chunk_rows.bit_length())}
+            | {self._chunk_rows}))
+        self._warm_rows: set = set()  # sampling modes warmed at every count
         # Static layer -> lane-width map for the paged decode step: one
         # width for uniform stacks, per-layer (None on recurrent layers)
         # otherwise. Derived from the slot table's per-leaf widths — the
@@ -463,7 +489,7 @@ class Engine:
         # (plus injected stall ticks); deadlines count against it.
         self._clock = 0
         # Modeled device time: every jitted forward dispatch advances this
-        # by its SEQUENCE width (decode steps by 1, a width-S mixed step by
+        # by its SEQUENCE width (decode steps by 1, a width-S chunk step by
         # S, a solo whole-prompt sweep by its full concatenated width).
         # Batch rows ride in parallel PE lanes and are free, matching the
         # paper's dynamic-batching utilization argument — and the same
@@ -510,96 +536,18 @@ class Engine:
                 mesh=mesh, prefix_kv={"k": pk, "v": pv, "len": plen})
             return logits, new_caches
 
-        def decode_fn(params, tokens, caches, lengths, active, seeds,
-                      temps, topks, tables, nan_mask, sampled):
-            pages = None
-            if self.paged:
-                def entry(w):
-                    return {"bt": tables[w][:num_slots], "width": w,
-                            "page_size": self.page_size}
-                if isinstance(self._page_struct, dict):
-                    pages = {name: (entry(w) if w is not None else None)
-                             for name, w in self._page_struct.items()}
-                else:
-                    pages = entry(self._page_struct)
-            logits, new_caches = dmodel.decode_step(
-                params, {"inputs": tokens}, caches, lengths,
-                slot_mask=active, pages=pages, mesh=mesh)
-            row = logits[:, 0, :]
-            # Fault injection lands *after* the model: caches never see
-            # the poison and other slots are untouched by construction.
-            row = jnp.where(nan_mask[:, None], jnp.nan, row)
-            if sampled:
-                # The drawn token's absolute position is lengths + 1: the
-                # same (request, position) key a preempted-then-resumed
-                # request re-derives at its prefill (serve/sampling.py).
-                # Per-slot temperature/top-k (resolved from each request's
-                # SamplingParams at admission) ride in-graph; greedy rows
-                # (temps == 0) take the batch sampler's argmax lane.
-                nxt = sample_tokens_batch(row, seeds, lengths + 1,
-                                          temps, topks)
-            else:
-                # ``sampled`` is a trace-time flag: an all-greedy batch
-                # compiles (and stays bit-identical to) the plain argmax
-                # graph — no sort/categorical ops to build or pay for.
-                nxt = jnp.argmax(row, axis=-1).astype(jnp.int32)
-            # In-graph finiteness guard: a slot whose logits went NaN/Inf
-            # (flaky kernel, injected fault) reports the -1 sentinel —
-            # vocab ids are >= 0 — through the run loop's existing single
-            # token fetch, so quarantine costs no extra device sync.
-            bad = ~jnp.all(jnp.isfinite(row), axis=-1)
-            nxt = jnp.where(bad, jnp.int32(-1), nxt)
-            return nxt, new_caches
-
-        def mixed_fn(params, tokens, caches, lengths, n_new, active, seeds,
-                     temps, topks, tables, nan_mask, sampled):
-            # One fixed-shape step over chunk rows AND decode rows:
-            # row b's columns [0, n_new[b]) are fresh tokens at absolute
-            # positions [lengths[b], lengths[b] + n_new[b]) — decode rows
-            # pass n_new == 1, budget-starved chunk rows 0 (inert).
-            def entry(w):
-                return {"bt": tables[w][:num_slots], "width": w,
-                        "page_size": self.page_size}
-            if isinstance(self._page_struct, dict):
-                pages = {name: (entry(w) if w is not None else None)
-                         for name, w in self._page_struct.items()}
-            else:
-                pages = entry(self._page_struct)
-            # The step's emitted token comes from chunk column n_new - 1
-            # (clamped; inert rows read column 0 and the host ignores it).
-            row, new_caches = dmodel.mixed_step(
-                params, {"inputs": tokens}, caches, lengths, n_new,
-                slot_mask=active, pages=pages, mesh=mesh)
-            row = jnp.where(nan_mask[:, None], jnp.nan, row)
-            if sampled:
-                # Absolute position of the sampled token: lengths + n_new
-                # tokens precede it — the same (request, position) key the
-                # serialized engine derives (prefill first token: L;
-                # decode: lengths + 1), so sampling is bit-identical.
-                nxt = sample_tokens_batch(row, seeds, lengths + n_new,
-                                          temps, topks)
-            else:
-                nxt = jnp.argmax(row, axis=-1).astype(jnp.int32)
-            bad = ~jnp.all(jnp.isfinite(row), axis=-1)
-            nxt = jnp.where(bad, jnp.int32(-1), nxt)
-            return nxt, new_caches
-
         # One compile per prefill shape — widths are max_len multiples and
         # packed row counts are padded to powers of two, so the set is small
-        # and bounded — and exactly one for decode: shapes never depend on
-        # which requests are in flight. Donating the cache lets accelerators
-        # update it in place (CPU doesn't implement donation; skip there).
-        donate = (2,) if jax.default_backend() != "cpu" else ()
+        # and bounded — and exactly one each for the decode and the chunk
+        # step: shapes never depend on which requests are in flight.
+        self._dmodel = dmodel
         self._prefill = jax.jit(prefill_fn)
         self._prefill_shared = jax.jit(prefill_shared_fn) \
             if self.prefix_share else None
-        # ``sampled`` is static: an all-greedy step compiles (and caches)
-        # exactly the argmax-only graph — at most two compiled variants.
-        self._decode = jax.jit(decode_fn, donate_argnums=donate,
-                               static_argnums=(10,))
-        self._mixed = jax.jit(mixed_fn, donate_argnums=donate,
-                              static_argnums=(11,)) \
-            if self.mixed else None
+        # (CPU doesn't implement donation; skip there.)
+        self._decode, self._mixed = self._step_programs(
+            mesh, donate=jax.default_backend() != "cpu")
+
         def sample1(row, seed, pos, temp, topk):
             return sample_tokens_batch(row[None], seed[None], pos[None],
                                        temp[None], topk[None])[0]
@@ -608,6 +556,97 @@ class Engine:
         # the very same batch sampler (as a 1-row batch) keeps them
         # bit-identical to decode, per-request parameters included.
         self._sample1 = jax.jit(sample1)
+
+    def _step_programs(self, mesh, donate: bool):
+        """The jitted decode step, ``(num_slots, 1)``, and chunk step,
+        ``(R, S)`` at each row count R it runs (``None`` without the mixed
+        step), serving on ``mesh``, with the page pool donated when
+        ``donate``. Their names (``decode_fn``, ``mixed_fn``) name the
+        programs in a profiler trace."""
+        dmodel, num_slots = self._dmodel, self.num_slots
+
+        def page_tables(tables, rows):
+            if not self.paged:
+                return None
+
+            def entry(w):
+                return {"bt": tables[w][rows], "width": w,
+                        "page_size": self.page_size}
+            if isinstance(self._page_struct, dict):
+                return {name: (entry(w) if w is not None else None)
+                        for name, w in self._page_struct.items()}
+            return entry(self._page_struct)
+
+        def next_tokens(row, nan_mask, seeds, pos, temps, topks, sampled):
+            # Fault injection lands *after* the model: caches never see
+            # the poison and other rows are untouched by construction.
+            row = jnp.where(nan_mask[:, None], jnp.nan, row)
+            if sampled:
+                # ``pos`` is the drawn token's absolute position: the same
+                # (request, position) key the serialized engine and a
+                # preempted-then-resumed request derive
+                # (serve/sampling.py). Per-row temperature/top-k ride
+                # in-graph; greedy rows (temps == 0) take the batch
+                # sampler's argmax lane.
+                nxt = sample_tokens_batch(row, seeds, pos, temps, topks)
+            else:
+                # ``sampled`` is a trace-time flag: an all-greedy batch
+                # compiles (and stays bit-identical to) the plain argmax
+                # graph — no sort/categorical ops to build or pay for.
+                nxt = jnp.argmax(row, axis=-1).astype(jnp.int32)
+            # In-graph finiteness guard: a row whose logits went NaN/Inf
+            # (flaky kernel, injected fault) reports the -1 sentinel —
+            # vocab ids are >= 0 — through the iteration's single token
+            # fetch, so quarantine costs no extra device sync.
+            bad = ~jnp.all(jnp.isfinite(row), axis=-1)
+            return jnp.where(bad, jnp.int32(-1), nxt)
+
+        def decode_fn(params, tokens, caches, lengths, active, seeds,
+                      temps, topks, tables, nan_mask, sampled):
+            logits, new_caches = dmodel.decode_step(
+                params, {"inputs": tokens}, caches, lengths,
+                slot_mask=active,
+                pages=page_tables(tables, slice(None, num_slots)),
+                mesh=mesh)
+            return next_tokens(logits[:, 0, :], nan_mask, seeds,
+                               lengths + 1, temps, topks,
+                               sampled), new_caches
+
+        def mixed_fn(params, tokens, caches, rows, lengths, n_new, active,
+                     seeds, temps, topks, tables, nan_mask, sampled):
+            # The chunk step: row r carries slot rows[r]'s fresh tokens in
+            # columns [0, n_new) at absolute positions [lengths, lengths +
+            # n_new). The per-slot inputs gather on the device; a padding
+            # row names the sentinel slot num_slots, which gathers as an
+            # inert row (n_new 0, inactive) over the FREE block-table row.
+            def take(a):
+                return jnp.take(a, rows, axis=0, mode="fill",
+                                fill_value=0)
+            lengths, n_new, active, seeds, temps, topks, nan_mask = map(
+                take, (lengths, n_new, active, seeds, temps, topks,
+                       nan_mask))
+            # The row's token comes from chunk column n_new - 1 (clamped;
+            # an inert row reads column 0 and the host ignores it).
+            row, new_caches = dmodel.mixed_step(
+                params, {"inputs": tokens}, caches, lengths, n_new,
+                slot_mask=active, pages=page_tables(tables, rows),
+                mesh=mesh)
+            # Absolute position of the sampled token: lengths + n_new
+            # tokens precede it — the serialized engine's prefill first
+            # token (L) and decode (lengths + 1) keys alike.
+            return next_tokens(row, nan_mask, seeds, lengths + n_new, temps,
+                               topks, sampled), new_caches
+
+        # Donating the cache lets accelerators update it in place.
+        # ``sampled`` is static: an all-greedy step compiles (and caches)
+        # exactly the argmax-only graph — at most two compiled variants of
+        # each program.
+        donated = (2,) if donate else ()
+        decode = jax.jit(decode_fn, donate_argnums=donated,
+                         static_argnums=(10,))
+        mixed = jax.jit(mixed_fn, donate_argnums=donated,
+                        static_argnums=(12,)) if self.mixed else None
+        return decode, mixed
 
     # ------------------------------------------------------------------
 
@@ -787,42 +826,77 @@ class Engine:
 
     def compiled_steps(self) -> Dict[str, str]:
         """Compiled HLO text of the jitted serving steps at this engine's
-        shapes: ``"decode"``, plus ``"mixed"`` when the mixed step is on —
-        greedy variants, the ones an all-greedy batch dispatches. For
-        auditing which kernels the hot path runs (a Pallas kernel shows as
-        a ``tpu_custom_call`` on a TPU)."""
-        n = self.num_slots
+        shapes: ``"decode"``, plus ``"mixed"`` (the chunk step at its
+        fewest rows) when the mixed step is on — greedy variants, the ones
+        an all-greedy batch dispatches. For auditing which kernels the hot
+        path runs (a Pallas kernel shows as a ``tpu_custom_call`` on a
+        TPU)."""
+        n, S, r = self.num_slots, self._chunk_width, self._row_counts[0]
+        x = self._slot_inputs()
         out = {"decode": self._decode.lower(*self._step_args(
-            np.zeros((n, 1), np.int32))).compile().as_text()}
+            x, np.zeros((n, 1), np.int32))).compile().as_text()}
         if self._mixed is not None:
             out["mixed"] = self._mixed.lower(*self._step_args(
-                np.zeros((n, self._chunk_width), np.int32),
-                np.zeros(n, np.int32))).compile().as_text()
+                x, np.zeros((r, S), np.int32), n_new=np.zeros(n, np.int32),
+                rows=np.zeros(r, np.int32))).compile().as_text()
         return out
 
-    def _step_args(self, toks, n_new=None, inj=None):
-        """Positional arguments of one ``_decode`` dispatch (or ``_mixed``,
-        given ``n_new``) at the engine's current slot state — the one
-        signature both the serving loop and :meth:`compiled_steps` use."""
+    def _slot_inputs(self, inj=None) -> dict:
+        """The per-slot device inputs that both step programs read — lane
+        lengths, sampling state, block tables, the NaN-injection mask and
+        whether any active slot samples — uploaded once an iteration."""
         sl = self.slots
-        nan_mask = self._no_nan
-        if inj is not None:
-            m = inj.nan_mask()
-            if m is not None:
-                nan_mask = jnp.asarray(m)
-        lengths = (jnp.asarray(sl.lengths),)
-        if n_new is not None:
-            lengths += (jnp.asarray(n_new),)
-        tables = sl.pool.device_tables() if self.paged else {}
-        sampled = bool(np.any(self._temps[sl.active] > 0))
-        return (self.params, jnp.asarray(toks), sl.caches, *lengths,
-                jnp.asarray(sl.active), jnp.asarray(self._seeds),
-                jnp.asarray(self._temps), jnp.asarray(self._topks),
-                tables, nan_mask, sampled)
+        mask = inj.nan_mask() if inj is not None else None
+        return {"lengths": jnp.asarray(sl.lengths),
+                "seeds": jnp.asarray(self._seeds),
+                "temps": jnp.asarray(self._temps),
+                "topks": jnp.asarray(self._topks),
+                "tables": sl.pool.device_tables() if self.paged else {},
+                "nan_mask": self._no_nan if mask is None
+                else jnp.asarray(mask),
+                "sampled": bool(np.any(self._temps[sl.active] > 0))}
+
+    def _step_args(self, x: dict, toks, active=None, n_new=None,
+                   rows=None) -> list:
+        """Positional arguments of one ``_decode`` dispatch over the slots
+        in ``active`` (all active slots by default), or of one ``_mixed``
+        dispatch of the chunk rows ``rows`` (slot indices; ``num_slots``
+        pads) given ``n_new`` per slot, from the iteration's
+        :meth:`_slot_inputs` ``x`` — the one signature the serving loop
+        and :meth:`compiled_steps` use."""
+        lead = (x["lengths"],)
+        if rows is not None:
+            lead = (jnp.asarray(rows), *lead, jnp.asarray(n_new))
+        active = self.slots.active if active is None else active
+        return [self.params, jnp.asarray(toks), self.slots.caches, *lead,
+                jnp.asarray(active), x["seeds"], x["temps"], x["topks"],
+                x["tables"], x["nan_mask"], x["sampled"]]
+
+    def _rows_filled(self, n: int) -> int:
+        """The largest row count the chunk step is compiled at that ``n``
+        prompts fill (``n`` itself below the smallest)."""
+        return max((r for r in self._row_counts if r <= n), default=n)
+
+    def _warm_row_counts(self, args: list, rows: int) -> None:
+        """At the chunk step's first dispatch in a sampling mode, run it
+        once on inert rows (the sentinel slot's) at each of its other row
+        counts, so that every shape an iteration may pick compiles then —
+        in a warm-up — and not while serving."""
+        if args[-1] in self._warm_rows:
+            return
+        self._warm_rows.add(args[-1])
+        for r in self._row_counts:
+            if r != rows:
+                a = list(args)
+                a[1] = jnp.zeros((r, self._chunk_width), jnp.int32)
+                a[2] = self.slots.caches
+                a[3] = jnp.full((r,), self.num_slots, jnp.int32)
+                _, self.slots.caches = self._mixed(*a)
 
     def step(self, submits: Sequence[Request] = ()) -> StepResult:
-        """ONE engine iteration — admit, one jitted mixed/decode dispatch,
-        retire — exactly the old ``run`` loop body, externally driveable.
+        """ONE engine iteration — admit, the jitted decode and/or chunk
+        step, retire — exactly the old ``run`` loop body, externally
+        driveable.
 
         ``submits`` are submitted after this step's clock tick, matching
         the arrival semantics of :meth:`run`. Returns the step's streamed
@@ -891,8 +965,25 @@ class Engine:
                         if not sl.active[s]:
                             pending[s] = None
                             pending_full[s] = None
+            n_rows = 0
+            if self.mixed:
+                # The chunk step's rows this iteration: the largest
+                # compiled count that the mid-prefill slots and the queued
+                # requests with a free slot fill.
+                staged = sum(p is not None for p in pending)
+                queued = min(sl.free_slots().size, self.scheduler.pending())
+                n_rows = self._rows_filled(staged + queued)
             if self.scheduler.pending():
                 free = sl.free_slots()
+                if self.mixed:
+                    # A request claims a slot when a chunk row takes its
+                    # first chunk this iteration: mid-prefill slots keep
+                    # their rows (oldest first) until their prompt is in,
+                    # and a request past the rows waits in the queue, not
+                    # in a slot that would run nothing.
+                    take = max(n_rows - staged, 0)
+                    st.chunk_waits += max(queued - take, 0)
+                    free = free[:take]
                 if free.size:
                     n_done = len(done)
                     self._admitted = []
@@ -928,173 +1019,109 @@ class Engine:
                 return self._step_result(res, n_done0)
             st.idle = 0
 
-            if self.mixed and any(pending[s] is not None
-                                  for s in active_ix):
-                # ---- mixed step: pack up to ``prefill_budget`` fresh
-                # prompt tokens (chunk rows, oldest admission first —
-                # matching serialized FIFO prefill order) alongside every
-                # decode slot in ONE jitted fixed-shape call. Pure-decode
-                # iterations below keep the (B, 1) decode step — no chunk
-                # columns to pay for when nobody is prefilling.
-                with TraceAnnotation(spans.PACK):
-                    S = self._chunk_width
-                    left = self.prefill_budget
-                    n_new = np.zeros(self.num_slots, np.int32)
-                    order = sorted(active_ix,
-                                   key=lambda s: self._admit_seq[s])
-                    for s in order:
-                        if pending[s] is None:
-                            n_new[s] = 1  # decode row
-                        else:
-                            c = min(len(pending[s]), S)
-                            if left is not None:
-                                c = min(c, left)
-                                left -= c
-                            n_new[s] = c
-                    # Chunk writes span [len, len + c): allocate + CoW each
-                    # span (oldest first; dry pool preempts the youngest,
-                    # like _ensure_pages — make_range_writable is
-                    # all-or-nothing so the retry after eviction is safe).
-                    for s in order:
-                        if (not sl.active[s] or pending[s] is None
-                                or n_new[s] <= 0):
-                            continue
-                        ok, rec, esc = self._grow_span(
-                            int(s), int(sl.lengths[s]) + int(n_new[s]),
-                            done)
-                        st.preemptions += rec + esc
-                        st.preempt_recovered += rec
-                        if not ok:
-                            # deferred (pool dry, this slot youngest):
-                            # ride this step as an inert row, chunk intact.
-                            n_new[s] = 0
-                    for s in range(self.num_slots):
-                        if not sl.active[s]:
-                            pending[s] = None
-                            pending_full[s] = None
-                    n_new = np.where(sl.active, n_new, 0).astype(np.int32)
-                    active_ix = np.flatnonzero(sl.active)
-                    if active_ix.size == 0:
-                        return self._step_result(res, n_done0)
-                    toks = np.zeros((self.num_slots, S), np.int32)
-                    for s in active_ix:
-                        if pending[s] is not None:
-                            c = int(n_new[s])
-                            toks[s, :c] = pending[s][:c]
-                        else:
-                            toks[s, 0] = cur[s]
-                    for ring in self._attn_rings:
-                        bs = block_stats(
-                            np.where(sl.active,
-                                     np.minimum(sl.lengths + n_new, ring),
-                                     0),
-                            ring, min(self._block_k, ring))
-                        st.blocks_visited += bs["visited"]
-                        st.blocks_dense += bs["dense"]
-                        st.kv_bytes += (bs["visited"]
-                                        * min(self._block_k, ring)
-                                        * self._ring_layers[ring]
-                                        * self._kv_token_bytes)
-                    self._record_dispatch(res, "mixed", n_new)
-                with TraceAnnotation(spans.UPLOAD):
-                    args = self._step_args(toks, n_new, inj)
-                with TraceAnnotation(spans.DISPATCH):
-                    nxt, sl.caches = self._mixed(*args)
-                with TraceAnnotation(spans.SYNC):
-                    nxt = np.asarray(nxt)  # the step's single host sync
-                with TraceAnnotation(spans.RETIRE):
-                    self._device_time += self._chunk_width
-                    st.steps += 1
-                    st.mixed_steps += 1
-                    st.active_slot_steps += active_ix.size
-                    if self.paged:
-                        st.pages_used_steps += res.pages_in_use
-                    for s in active_ix:
-                        tok = int(nxt[s])
-                        req = sl.request[s]
-                        if tok < 0:
-                            sl.release(int(s))
-                            pending[s] = None
-                            pending_full[s] = None
-                            self._finish(req, "failed",
-                                         "non-finite logits (NaN/Inf) in "
-                                         "the mixed step", done)
-                            continue
-                        if pending[s] is not None:
-                            c = int(n_new[s])
-                            if c <= 0:
-                                continue  # budget-starved: nothing this step
-                            sl.advance_n(int(s), c)
-                            st.chunk_tokens += c
-                            rest = pending[s][c:]
-                            if len(rest):
-                                # still mid-prefill: the sampled column is
-                                # a mid-prompt continuation, never an output
-                                pending[s] = rest
-                                continue
-                            # Prefill complete: ``tok`` IS the first token
-                            # — sampled from the same logits position (and,
-                            # in sampled mode, the same key) as the
-                            # serialized prefill's first token. Publish the
-                            # prompt's full pages now that they hold their
-                            # final bytes.
-                            if self.prefix_share:
-                                self._publish_prefix(int(s),
-                                                     pending_full[s])
-                            pending[s] = None
-                            pending_full[s] = None
-                            self._emit(req, tok)
-                            self._note_ttft(req)
-                            emitted[s] = len(req.output)
-                            cur[s] = tok
-                            if emitted[s] >= budget[s] or tok == self.eos_id:
-                                self._finish(req, "ok", None, done)
-                                sl.release(int(s))
-                            continue
-                        sl.advance(s)
-                        self._emit(req, tok)
-                        emitted[s] += 1
-                        cur[s] = tok
-                        st.decoded_tokens += 1
-                        if emitted[s] >= budget[s] or tok == self.eos_id:
-                            self._finish(req, "ok", None, done)
-                            sl.release(s)
-                return self._step_result(res, n_done0)
-
+            # ---- the iteration's dispatches: slots past their prompt
+            # decode one token each in the (num_slots, 1) decode step;
+            # with the mixed step, the ``n_rows`` oldest mid-prefill slots
+            # (the serialized engine's FIFO prefill order) stream up to S
+            # prompt tokens each, at most ``prefill_budget`` in all,
+            # through the (R, S) chunk step, R the smallest compiled row
+            # count that holds them. Both are dispatched, then read back
+            # in one sync.
             with TraceAnnotation(spans.PACK):
-                # Predicated-kernel work accounting: the TDA grid visits
-                # only the kv blocks covering each active lane's occupancy
-                # (+1 for the token being written, clamped to the lane's
-                # ring width); dense is the full slot-table sweep. One term
-                # per distinct attention-lane ring among the layers.
+                S = self._chunk_width
+                n_new = np.zeros(self.num_slots, np.int32)
+                chunk = sorted(
+                    (s for s in active_ix if pending[s] is not None),
+                    key=lambda s: self._admit_seq[s])[:n_rows]
+                left = self.prefill_budget
+                for s in chunk:
+                    c = min(len(pending[s]), S)
+                    if left is not None:
+                        c = min(c, left)
+                        left -= c
+                    n_new[s] = c
+                # Chunk writes span [len, len + c): allocate + CoW each
+                # span (oldest first; a dry pool preempts the youngest,
+                # like _ensure_pages — make_range_writable is
+                # all-or-nothing so the retry after eviction is safe).
+                for s in chunk:
+                    if not sl.active[s] or n_new[s] <= 0:
+                        continue
+                    ok, rec, esc = self._grow_span(
+                        int(s), int(sl.lengths[s]) + int(n_new[s]), done)
+                    st.preemptions += rec + esc
+                    st.preempt_recovered += rec
+                    if not ok:
+                        # deferred (pool dry, this slot youngest): its
+                        # chunk waits, intact, for the next iteration.
+                        n_new[s] = 0
+                for s in range(self.num_slots):
+                    if not sl.active[s]:
+                        pending[s] = None
+                        pending_full[s] = None
+                chunk = [s for s in chunk if sl.active[s] and n_new[s] > 0]
+                decode = sl.active & np.array([p is None for p in pending])
+                n_new = np.where(decode, 1, np.where(sl.active, n_new, 0)
+                                 ).astype(np.int32)
+                active_ix = np.flatnonzero(sl.active)
+                if not chunk and not decode.any():
+                    return self._step_result(res, n_done0)
                 for ring in self._attn_rings:
                     bs = block_stats(
-                        np.where(sl.active, np.minimum(sl.lengths + 1, ring),
-                                 0),
+                        np.where(sl.active,
+                                 np.minimum(sl.lengths + n_new, ring), 0),
                         ring, min(self._block_k, ring))
                     st.blocks_visited += bs["visited"]
                     st.blocks_dense += bs["dense"]
-                    # KV bytes this step: visited blocks x tokens/block,
-                    # once per attention layer sharing this ring shape.
                     st.kv_bytes += (bs["visited"] * min(self._block_k, ring)
                                     * self._ring_layers[ring]
                                     * self._kv_token_bytes)
-                self._record_dispatch(res, "decode",
-                                      sl.active.astype(np.int32))
+                self._record_dispatch(res, "mixed" if chunk else "decode",
+                                      n_new)
             with TraceAnnotation(spans.UPLOAD):
-                args = self._step_args(cur[:, None], inj=inj)
+                x = self._slot_inputs(inj)
+                dispatches = []
+                if decode.any():
+                    dispatches.append(("decode", self._decode, self._step_args(
+                        x, cur[:, None], active=decode)))
+                if chunk:
+                    res.chunk_slots = np.asarray(chunk, np.int32)
+                    R = next(r for r in self._row_counts if r >= len(chunk))
+                    rows = np.full(R, self.num_slots, np.int32)
+                    rows[:len(chunk)] = chunk
+                    toks = np.zeros((R, S), np.int32)
+                    for r, s in enumerate(chunk):
+                        toks[r, :n_new[s]] = pending[s][:n_new[s]]
+                    dispatches.append(("chunk", self._mixed, self._step_args(
+                        x, toks, active=sl.active, n_new=n_new, rows=rows)))
             with TraceAnnotation(spans.DISPATCH):
-                nxt, sl.caches = self._decode(*args)
+                if chunk:
+                    self._warm_row_counts(dispatches[-1][2], R)
+                outs = []
+                for _, program, args in dispatches:
+                    args[2] = sl.caches  # the previous dispatch's pool
+                    out, sl.caches = program(*args)
+                    outs.append(out)
             with TraceAnnotation(spans.SYNC):
-                nxt = np.asarray(nxt)  # the step's single host sync
+                outs = jax.device_get(outs)  # the iteration's single sync
+                nxt = np.zeros(self.num_slots, np.int32)
+                for (kind, _, _), out in zip(dispatches, outs):
+                    if kind == "decode":
+                        nxt[decode] = out[decode]
+                    else:
+                        nxt[chunk] = out[:len(chunk)]
             with TraceAnnotation(spans.RETIRE):
-                self._device_time += 1
+                self._device_time += int(decode.any()) + (S if chunk else 0)
                 st.steps += 1
+                if chunk:
+                    st.mixed_steps += 1
+                    st.mixed_columns += R * S
                 st.active_slot_steps += active_ix.size
                 if self.paged:
                     st.pages_used_steps += res.pages_in_use
-                for s in active_ix:
-                    sl.advance(s)
+                ran = decode.copy()
+                ran[chunk] = True
+                for s in np.flatnonzero(ran):
                     tok = int(nxt[s])
                     req = sl.request[s]
                     if tok < 0:
@@ -1103,18 +1130,45 @@ class Engine:
                         # rest (their tokens are bit-identical by
                         # construction: lanes are independent and the
                         # poison never reached the caches).
-                        sl.release(s)
+                        sl.release(int(s))
+                        pending[s] = None
+                        pending_full[s] = None
+                        step = "decode" if decode[s] else "chunk"
                         self._finish(req, "failed",
                                      "non-finite logits (NaN/Inf) in the "
-                                     "decode step", done)
+                                     f"{step} step", done)
                         continue
-                    self._emit(req, tok)
-                    emitted[s] += 1
+                    if pending[s] is not None:
+                        c = int(n_new[s])
+                        sl.advance_n(int(s), c)
+                        st.chunk_tokens += c
+                        rest = pending[s][c:]
+                        if len(rest):
+                            # still mid-prefill: the sampled column is a
+                            # mid-prompt continuation, never an output
+                            pending[s] = rest
+                            continue
+                        # Prefill complete: ``tok`` IS the first token —
+                        # sampled from the same logits position (and, in
+                        # sampled mode, the same key) as the serialized
+                        # prefill's first token. Publish the prompt's full
+                        # pages now that they hold their final bytes.
+                        if self.prefix_share:
+                            self._publish_prefix(int(s), pending_full[s])
+                        pending[s] = None
+                        pending_full[s] = None
+                        self._emit(req, tok)
+                        self._note_ttft(req)
+                        emitted[s] = len(req.output)
+                    else:
+                        sl.advance(s)
+                        self._emit(req, tok)
+                        emitted[s] += 1
+                        st.decoded_tokens += 1
                     cur[s] = tok
-                    st.decoded_tokens += 1
                     if emitted[s] >= budget[s] or tok == self.eos_id:
                         self._finish(req, "ok", None, done)
-                        sl.release(s)
+                        sl.release(int(s))
             return self._step_result(res, n_done0)
         finally:
             self._events = None
@@ -1224,6 +1278,12 @@ class Engine:
             "prefill_budget": self.prefill_budget,
             "mixed_steps": st.mixed_steps,
             "prefill_chunk_tokens": st.chunk_tokens,
+            # The chunk step's engagement: the columns it paid (R x S a
+            # chunk step; prefill_chunk_tokens over this is the paid
+            # columns' fill), and the queued requests, counted each
+            # iteration, that found a free slot but no row.
+            "mixed_columns": st.mixed_columns,
+            "chunk_waits": st.chunk_waits,
             "ttft": {
                 r.rid: {"wall_s": r.first_token_s - (
                             r.submitted_s if r.submitted_s is not None
@@ -1390,7 +1450,8 @@ class Engine:
     def _admit_mixed(self, free: np.ndarray, cur, emitted, budget,
                      pending, pending_full, done: List[Request]) -> int:
         """Chunk-granular admission for the mixed step: claim a free slot
-        per queued request (FIFO, page-budget head-blocking — but the
+        per queued request (the caller passes as many free slots as the
+        chunk step has free rows; FIFO, page-budget head-blocking — but the
         reservation covers only the FIRST chunk's span, so a long prompt
         never head-blocks the queue behind its whole page demand) and
         stage its prompt in ``pending`` for the chunk scheduler. No

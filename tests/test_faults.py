@@ -439,13 +439,15 @@ def test_audit_mode_is_transparent_on_a_healthy_run(fm):
 
 class _StubMesh:
     """Duck-typed mesh: the construction checks read ``mesh.devices.size``
-    (the compressed-MoE refusal keys on physical device count) and
-    ``mesh.axis_names`` (the tensor-parallel dispatch predicate). No
+    (the compressed-MoE refusal keys on physical device count),
+    ``mesh.axis_names`` (the tensor-parallel dispatch predicate) and the
+    batch axes' sizes in ``mesh.shape`` (the chunk step's rows). No
     ``model`` axis, so ``tensor_parallel_size`` stays 1 and the sharded
     placement path is off — real multi-device meshes are exercised in
     subprocesses via the ``mesh_cpu`` fixture."""
 
     axis_names = ("data",)
+    shape = {"data": 2}
 
     class devices:
         size = 2

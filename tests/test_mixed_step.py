@@ -9,7 +9,7 @@ import pytest
 from repro.configs import get_config
 from repro.core.errors import UnsupportedConfigError
 from repro.models.transformer import Model
-from repro.serve import Engine, Request
+from repro.serve import Engine, EngineConfig, Request
 from repro.serve.faults import FaultPlan
 
 
@@ -66,10 +66,17 @@ BUDGETS = [6, 5, 4, 6]
 TICKS = [1, 1, 3, 6]          # two up front, two arriving mid-decode
 
 
-@pytest.mark.parametrize("prefill_budget", [4, 16, None])
+# Chunk-step budgets of the token-identity cases: the default (None: a row
+# for every prompt streaming, up to every slot) and at most two max_len
+# rows a step.
+ROW_BUDGETS = [None, 2 * WORKLOAD_KW["max_len"]]
+
+
+@pytest.mark.parametrize("prefill_budget", [4, 16, None, 32])
 def test_mixed_matches_serialized_greedy(qwen, prefill_budget):
     """Same tokens at every chunk granularity — one tiny chunk per step,
-    one max_len row per step, and unbounded — with requests arriving
+    one max_len row per step, at most two rows per step, and a row for
+    every prompt streaming (the default) — with requests arriving
     mid-decode, against the phase-serialized engine on the identical
     arrival schedule."""
     cfg, m, params = qwen
@@ -94,7 +101,8 @@ def test_mixed_matches_serialized_greedy(qwen, prefill_budget):
     assert all(v["device_tokens"] >= 1 for v in st["ttft"].values())
 
 
-def test_mixed_matches_serialized_sampled(qwen):
+@pytest.mark.parametrize("prefill_budget", [5] + ROW_BUDGETS)
+def test_mixed_matches_serialized_sampled(qwen, prefill_budget):
     """Seeded sampling: chunk completion must draw the first token with
     the same (request, position) key the serialized prefill uses."""
     cfg, m, params = qwen
@@ -103,15 +111,17 @@ def test_mixed_matches_serialized_sampled(qwen):
     ref, _ = _run(m, params, prompts, BUDGETS, ticks=TICKS,
                   mixed=False, **kw)
     got, _ = _run(m, params, prompts, BUDGETS, ticks=TICKS,
-                  mixed=True, prefill_budget=5, **kw)
+                  mixed=True, prefill_budget=prefill_budget, **kw)
     assert got == ref
 
 
+@pytest.mark.parametrize("prefill_budget", [6] + ROW_BUDGETS)
 @pytest.mark.parametrize("sample_kw", [
     {},  # greedy
     dict(temperature=0.7, top_k=8, seed=5),  # sampled
 ])
-def test_mixed_matches_serialized_on_ring_lanes(star, sample_kw):
+def test_mixed_matches_serialized_on_ring_lanes(star, sample_kw,
+                                                prefill_budget):
     """Windowed (ring) lanes: chunks wrap the ring mid-prefill and decode
     pushes past the window — the dedup write and position-recovery masks
     must keep canonical ring phase identical to the serialized engine."""
@@ -122,12 +132,14 @@ def test_mixed_matches_serialized_on_ring_lanes(star, sample_kw):
               max_prompt_len=40, **sample_kw)
     ref, _ = _run(m, params, prompts, budgets, mixed=False, **kw)
     got, eng = _run(m, params, prompts, budgets, mixed=True,
-                    prefill_budget=6, **kw)
+                    prefill_budget=prefill_budget, **kw)
     assert got == ref
     assert eng.decode_stats["mixed_steps"] > 0
 
 
-def test_mixed_preemption_mid_prefill_matches_clean_run(qwen):
+@pytest.mark.parametrize("prefill_budget", [4] + ROW_BUDGETS)
+def test_mixed_preemption_mid_prefill_matches_clean_run(qwen,
+                                                        prefill_budget):
     """A forced preemption while a prompt is half-prefilled requeues it as
     a continuation; the resumed run must still emit exactly the clean
     serialized tokens (chunk state is discarded, pages are released, and
@@ -137,15 +149,17 @@ def test_mixed_preemption_mid_prefill_matches_clean_run(qwen):
     budgets = [5, 5]
     ref, _ = _run(m, params, prompts, budgets, mixed=False, **WORKLOAD_KW)
     # budget 4/step: the 25-token prompt is mid-prefill for ~6 iterations,
-    # so iterations 2-3 preempt it (youngest-first) while half-streamed.
+    # so iterations 2-3 preempt it (youngest-first) while half-streamed;
+    # with whole rows, they preempt whichever request is youngest then.
     got, eng = _run(m, params, prompts, budgets, mixed=True,
-                    prefill_budget=4,
+                    prefill_budget=prefill_budget,
                     faults=FaultPlan(preempt_at=(2, 3)), **WORKLOAD_KW)
     assert got == ref
     assert eng.decode_stats["preemptions"] >= 2
 
 
-def test_mixed_prefix_hit_and_cow_identity(qwen):
+@pytest.mark.parametrize("prefill_budget", ROW_BUDGETS)
+def test_mixed_prefix_hit_and_cow_identity(qwen, prefill_budget):
     """Chunked prefill over a mapped shared prefix: the suffix streams
     through chunk rows while the prefix pages stay shared (CoW on the
     tail), and tokens match both the sharing-off mixed engine and the
@@ -158,7 +172,7 @@ def test_mixed_prefix_hit_and_cow_identity(qwen):
     B = np.concatenate([pre, rng.integers(0, cfg.vocab_size, size=9)
                         ]).astype(np.int32)
     kw = dict(max_len=16, max_new_tokens=6, num_slots=2, max_prompt_len=40,
-              page_size=8)
+              page_size=8, prefill_budget=prefill_budget)
     eng = Engine(m, params, mixed=True, **kw)
     eng.submit(Request(rid=0, prompt=A, max_new_tokens=5))
     out = {r.rid: r.output for r in eng.run()}
@@ -174,6 +188,68 @@ def test_mixed_prefix_hit_and_cow_identity(qwen):
             ref.submit(Request(rid=rid, prompt=prompt, max_new_tokens=5))
             assert {r.rid: r.output for r in ref.run()}[rid] == out[rid], \
                 f"rid {rid} diverged vs {ref_kw}"
+
+
+@pytest.mark.parametrize("prefill_budget,rows", [(None, 7), (32, 2)])
+def test_chunk_step_takes_bursts_fifo(qwen, prefill_budget, rows):
+    """Two bursts (four prompts, then three arriving over two decoding
+    slots): the chunk step takes a row for every prompt streaming, up to
+    R (every slot at the default budget, two at two max_len rows), at a
+    count it is compiled at (the largest they fill: three prompts take
+    two rows and the third waits in the queue), oldest admission first;
+    it pays for no padded row; the decode step runs in every iteration
+    where a slot decodes and is skipped where none does; and the tokens
+    are the serialized engine's."""
+    cfg, m, params = qwen
+    S = WORKLOAD_KW["max_len"]
+    prompts = _prompts(cfg, [5, 7, 25, 12, 30, 9, 20], seed=12)
+    ticks = [1, 1, 1, 1, 4, 4, 4]
+    kw = {**WORKLOAD_KW, "num_slots": 7}
+    ref, _ = _run(m, params, prompts, [8] * 7, ticks=ticks, mixed=False,
+                  **kw)
+    eng = Engine(m, params, config=EngineConfig(
+        mixed=True, prefill_budget=prefill_budget, **kw))
+    assert eng._chunk_rows == rows
+    assert eng._row_counts == ((1, 2, 4, 7) if rows == 7 else (1, 2))
+    decodes = []
+    inner = eng._decode
+
+    def decode(*args):
+        decodes.append(len(results))
+        return inner(*args)
+
+    eng._decode = decode
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=8)
+            for i, p in enumerate(prompts)]
+    results, chunk_rids = [], []
+    while len(results) < ticks[-1] or eng.has_work():
+        due = [q for q, t in zip(reqs, ticks) if t == len(results) + 1]
+        res = eng.step(submits=due)
+        if res.chunk_slots is not None:
+            assert res.kind == "mixed"
+            assert res.chunk_slots.size in eng._row_counts
+            chunk_rids.append([eng.slots.request[s].rid
+                               for s in res.chunk_slots])
+        results.append(res)
+    eng.finish_run()
+    assert {q.rid: q.output for q in reqs} == ref
+    # The first burst runs in one step where R allows; FIFO: rows go in
+    # admission order, and requests reach the chunk step in arrival order.
+    assert len(chunk_rids[0]) == min(4, rows)
+    assert all(step == sorted(step) for step in chunk_rids)
+    flat = [rid for step in chunk_rids for rid in step]
+    assert list(dict.fromkeys(flat)) == list(range(7))
+    st = eng.decode_stats
+    assert st["mixed_columns"] == S * len(flat)
+    assert st["mixed_steps"] == len(chunk_rids)
+    assert st["prefill_chunk_tokens"] == sum(map(len, prompts))
+    assert st["chunk_waits"] > 0
+    # The decode step ran exactly where a slot decoded (n_new 1 outside
+    # the chunk rows: every chunk here is longer than one token).
+    decoding = [i for i, r in enumerate(results) if r.kind is not None
+                and (r.n_new == 1).any()]
+    assert decodes == decoding
+    assert results[0].kind == "mixed" and 0 not in decodes
 
 
 # ---------------------------------------------------------------------------

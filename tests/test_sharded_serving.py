@@ -306,7 +306,7 @@ def tap(name):
         else:
             logits, n_new = out[0][:, 0], jnp.ones_like(cache_index)
         jax.debug.callback(
-            lambda *v: records.append([np.asarray(x) for x in v]),
+            lambda *v: records.append((name, [np.asarray(x) for x in v])),
             logits, cache_index, n_new, kw["slot_mask"])
         return out
 
@@ -338,12 +338,16 @@ def serve_logits(mesh, attn):
     while eng.has_work():
         before = list(eng.slots.request)
         n0 = len(records)
-        eng.step()
+        res = eng.step()
         jax.effects_barrier()
         after = list(eng.slots.request)
-        for logits, ci, nn, act in records[n0:]:
+        for name, (logits, ci, nn, act) in records[n0:]:
+            # The chunk step's rows are the slots it ran, in row order.
+            slots = res.chunk_slots if name == "mixed_step" \
+                else np.arange(act.size)
             for b in np.flatnonzero(act & (nn > 0)):
-                req = after[b] if after[b] is not None else before[b]
+                s = slots[b]
+                req = after[s] if after[s] is not None else before[s]
                 rows[(req.rid, int(ci[b] + nn[b] - 1))] = logits[b]
     eng.finish_run()
     assert all(q.status == "ok" for q in reqs)

@@ -134,52 +134,108 @@ def test_compressed_linear_compiles(one_chip, m):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
-def _step_program(model, step, pool, sharding, mesh=None, donate=True):
-    """The compiled text of a paged decode step, or a mixed step at
-    S = 512, over ``pool`` (the stacked k and v pools' shape), with the
-    model's shapes as parameters: placed by ``sharding`` (one chip), or
-    on ``mesh`` as the engine places them (``launch/sharding``)."""
+def _placed(eng, args, place):
+    """A step's arguments (``Engine._step_args``) as shapes placed on
+    ``place``: a described chip's ``Sharding`` takes every input; a
+    ``Mesh`` takes the parameters (the model's shapes) and the page pool
+    sharded as a serving mesh places them (``launch/sharding``) and the
+    rest replicated. The static last argument stays."""
     from repro.launch import sharding as shd
-
-    shapes = jax.eval_shape(model.init, jax.random.key(0))
-    cache = {"k": jax.ShapeDtypeStruct(pool, BF16),
-             "v": jax.ShapeDtypeStruct(pool, BF16)}
-    if mesh is None:
-        place = jax.tree.map(lambda _: sharding, shapes)
-        cache_place = jax.tree.map(lambda _: sharding, cache)
-    else:
-        sharding = NamedSharding(mesh, PartitionSpec())
-        place = shd.named(shd.param_specs(shapes, mesh), mesh)
-        cache_place = shd.named(shd.slot_cache_specs(cache, mesh, paged=True),
-                                mesh)
 
     def put(tree, where):
         return jax.tree.map(
             lambda a, w: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=w),
             tree, where)
 
-    pages = {"width": PER_SLOT * PAGE, "page_size": PAGE}
+    params = jax.eval_shape(eng.model.init, jax.random.key(0))
+    caches = args[2]
+    if isinstance(place, Mesh):
+        rest = NamedSharding(place, PartitionSpec())
+        at_params = shd.named(shd.param_specs(params, place), place)
+        at_caches = shd.named(shd.slot_cache_specs(caches, place, paged=True),
+                              place)
+    else:
+        rest = place
+        at_params = jax.tree.map(lambda _: place, params)
+        at_caches = jax.tree.map(lambda _: place, caches)
 
-    def decode_fn(params, tokens, caches, lengths, n_new, active, bt):
-        logits, new = model.decode_step(
-            params, {"inputs": tokens}, caches, lengths, slot_mask=active,
-            pages=dict(pages, bt=bt), mesh=mesh)
-        return jnp.argmax(logits[:, 0, :], -1), new
+    def replicated(a):
+        return put(a, jax.tree.map(lambda _: rest, a))
 
-    def mixed_fn(params, tokens, caches, lengths, n_new, active, bt):
-        logits, new = model.mixed_step(
-            params, {"inputs": tokens}, caches, lengths, n_new,
-            slot_mask=active, pages=dict(pages, bt=bt), mesh=mesh)
-        return jnp.argmax(logits, -1), new
+    return [put(params, at_params), replicated(args[1]),
+            put(caches, at_caches), *map(replicated, args[3:-1]), args[-1]]
 
-    fn, width = (decode_fn, 1) if step == "decode" else (mixed_fn, 512)
-    shapes_in = [((B, width), I32), ((B,), I32), ((B,), I32),
-                 ((B,), jnp.bool_), ((B, PER_SLOT), I32)]
-    t, ln, nn, act, bt = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
-                          for s, dt in shapes_in]
-    return jax.jit(fn, donate_argnums=(2,) if donate else ()).lower(
-        put(shapes, place), t, put(cache, cache_place), ln, nn, act,
-        bt).compile().as_text()
+
+def _engine_programs(arch, place, rows=None, **engine):
+    """The engine's own decode step and chunk step at S = 512, pool
+    donated, compiled for ``place`` — one described chip, or the described
+    host's mesh — with ``arch`` at its published widths (two layers), the
+    TDA kernels compiled by Mosaic and the engine sized by ``engine``.
+    Returns the engine and the programs' text: ``decode`` and the chunk
+    step at each of ``rows`` (``{key: row count}``; by default ``mixed``
+    at one row and ``mixed_every_row`` at its most)."""
+    from repro.kernels import common
+    from repro.launch.serve import build_model
+    from repro.serve import Engine, EngineConfig
+
+    model = build_model(arch, "full", n_layers=2, decode_attn="tda")
+    eng = Engine(model, None, config=EngineConfig(
+        max_len=512, max_new_tokens=512, page_size=PAGE, decode_attn="tda",
+        **engine))
+    decode, mixed = eng._step_programs(
+        place if isinstance(place, Mesh) else None, donate=True)
+    n, S = eng.num_slots, eng._chunk_width
+    x = eng._slot_inputs()
+    steps = {"decode": (decode, eng._step_args(x, np.zeros((n, 1), I32)))}
+    rows = rows or {"mixed": 1, "mixed_every_row": eng._chunk_rows}
+    for key, r in rows.items():
+        steps[key] = (mixed, eng._step_args(
+            x, np.zeros((r, S), I32), n_new=np.zeros(n, I32),
+            rows=np.zeros(r, I32)))
+    # The program sees the CPU backend here; compile its kernels for the
+    # described chip, not the interpreter.
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(common, "pallas_interpret_default", lambda: False)
+        return eng, {k: program.lower(*_placed(eng, args, place)).compile()
+                     .as_text() for k, (program, args) in steps.items()}
+
+
+@pytest.fixture(scope="module")
+def qwen_steps(one_chip):
+    """qwen1.5-4b on one chip: 8 slots, a 16-page pool."""
+    return _engine_programs("qwen1.5-4b", one_chip, num_slots=B,
+                            max_prompt_len=512, page_cap=16)
+
+
+@pytest.fixture(scope="module")
+def starcoder2_steps(four_chips):
+    """starcoder2-15b on the 2x2 host: 16 slots of 4096-token lanes (its
+    window), a 512-page pool, 32 MB a rank."""
+    return _engine_programs("starcoder2-15b", four_chips, num_slots=16,
+                            max_prompt_len=3584)
+
+
+def _arrays_of(text, shape):
+    """Lines of ``text`` holding an array of ``shape``, in any layout."""
+    dims = sorted(shape)
+    return [line[:200] for line in text.splitlines()
+            if any(sorted(int(d) for d in found.split(",")) == dims
+                   for found in re.findall(r"\w+\[([\d,]+)\]", line))]
+
+
+@pytest.mark.parametrize("engine", ["qwen_steps", "starcoder2_steps"])
+def test_chunk_step_runs_only_its_rows(request, engine):
+    """The chunk step is compiled at (rows, S), one row up to every slot:
+    no operand of the decode step or of the one-row chunk step has the
+    shape (num_slots, S, d_model) that a step paying S columns for every
+    slot would carry."""
+    eng, programs = request.getfixturevalue(engine)
+    assert eng._chunk_rows == eng.num_slots
+    d = eng.model.cfg.d_model
+    assert _arrays_of(programs["mixed"], (1, 512, d))
+    assert _arrays_of(programs["mixed_every_row"], (eng.num_slots, 512, d))
+    for step in ("decode", "mixed"):
+        assert not _arrays_of(programs[step], (eng.num_slots, 512, d))
 
 
 def _pool_moves(text, *pool_shapes):
@@ -198,6 +254,25 @@ def _pool_moves(text, *pool_shapes):
     return moves
 
 
+def test_tp4_one_row_chunk_step_moves_a_small_pool(four_chips):
+    """What the 512-page pool of ``starcoder2_steps`` stays clear of: at
+    320 pages (two layers, 21 MB a rank) XLA moves a rank's whole stacked
+    pool into another memory space and back (``copy-start`` to and from
+    ``S(1)``) at every one-row chunk step, and not at two rows. The cell's
+    pool (40 layers, 288 pages, 377 MB a rank) is not moved at any row
+    count (PERF.md, the compiled cell programs)."""
+    P = 320
+    eng, programs = _engine_programs(
+        "starcoder2-15b", four_chips, rows={"mixed": 1, "mixed_two_rows": 2},
+        num_slots=16, max_prompt_len=3584, page_cap=P)
+    pool = (2, P, 1, PAGE, D)
+    assert eng.slots.caches["k"].shape == (2, P, 4, PAGE, D)
+    one_row = _pool_moves(programs["mixed"], pool)
+    assert one_row and all("copy-start(" in m and "S(1)}" in m
+                           for m in one_row), one_row
+    assert not _pool_moves(programs["mixed_two_rows"], pool)
+
+
 def _kernels(text):
     """The ``op_name`` of each Mosaic kernel in a compiled program."""
     return [re.search(r'op_name="([^"]*)"', line).group(1)
@@ -205,66 +280,62 @@ def _kernels(text):
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
-@pytest.mark.parametrize("step", ["decode", "mixed"])
-def test_paged_step_moves_no_pool_layer(one_chip, monkeypatch, step):
-    """The whole decode step, and the mixed step at S = 512, at qwen1.5-4b
-    widths (two layers, a 16-page pool): no XLA operation (copy, fusion,
-    slice, update, scatter) yields an array of a layer's pool or of the
-    stacked pool, in any layout — the pool is read and written in place
-    by kernels that address it by (layer, page) — and the write's Mosaic
-    kernel carries the ``kv_write`` name scope, so a trace charges it to
-    the KV write. No op relayouts the q or k weights for RoPE, and a
-    one-chip program has no ``tp_reduce`` scope."""
-    from repro.kernels import common
-    from repro.launch.serve import build_model
+@pytest.mark.parametrize("step", ["decode", "mixed", "mixed_every_row"])
+def test_paged_step_moves_no_pool_layer(qwen_steps, step):
+    """The engine's decode step, and its chunk step at S = 512 (one row,
+    and every slot's), at qwen1.5-4b widths (two layers, a 16-page
+    pool): no XLA operation (copy, fusion, slice, update, scatter) yields
+    an array of a layer's pool or of the stacked pool, in any layout —
+    the pool is read and written in place by kernels that address it by
+    (layer, page) — and the write's Mosaic kernel carries the
+    ``kv_write`` name scope, so a trace charges it to the KV write. No op
+    relayouts the q or k weights for RoPE, and a one-chip program has no
+    ``tp_reduce`` scope."""
     from repro.models.layers import KV_WRITE_SCOPE, TP_REDUCE_SCOPE
 
-    # The program sees the CPU backend here; compile its kernels for the
-    # described chip, not the interpreter.
-    monkeypatch.setattr(common, "pallas_interpret_default", lambda: False)
+    eng, programs = qwen_steps
+    text = programs[step]
     L, P, H = 2, 16, 20
-    model = build_model("qwen1.5-4b", "full", n_layers=L, decode_attn="tda")
-    text = _step_program(model, step, (L, P, H, PAGE, D), one_chip)
+    assert eng.slots.caches["k"].shape == (L, P, H, PAGE, D)
     moves = _pool_moves(text, (L, P, H, PAGE, D), (P, H, PAGE, D))
     assert not moves, moves
     # RoPE splits pairs on the activations: the q and k weights are not
     # relayouted into (heads, D / 2, 2, d_model) pairs on every call.
-    relayouts = _pool_moves(text, (H, D // 2, 2, model.cfg.d_model))
+    relayouts = _pool_moves(text, (H, D // 2, 2, eng.model.cfg.d_model))
     assert not relayouts, relayouts
     assert any(f"/{KV_WRITE_SCOPE}/" in name for name in _kernels(text))
     assert TP_REDUCE_SCOPE not in text and "shard_map" not in text
 
 
-@pytest.mark.parametrize("step", ["decode", "mixed"])
-def test_tp4_step_runs_the_kernels_per_rank(four_chips, monkeypatch, step):
+@pytest.mark.parametrize("step", ["decode", "mixed", "mixed_every_row"])
+def test_tp4_step_runs_the_kernels_per_rank(starcoder2_steps, step):
     """starcoder2-15b at its published widths (two of its 40 layers) on
     the described 2x2 host, sharded as the engine shards it (4-way over
     its 4 KV heads): the step's kernels run per rank inside the
     ``shard_map`` (decode: the page write and the paged decode kernel;
     mixed: the mixed kernel and the page write), no XLA operation yields
     a rank's pool or a layer of it, and the attention adds no collective.
-    The program holds five: in the layer loop the all-reduces of the two
+    The program holds six: in the layer loop the all-reduces of the two
     row-parallel projections (attention output, feed-forward down), each
     under ``tp_reduce``; outside it one all-reduce that assembles the
-    embedding rows from the vocabulary-sharded table and two all-gathers
-    of the vocabulary-sharded head's per-rank argmax."""
-    from repro.kernels import common
-    from repro.launch.serve import build_model
+    embedding rows from the vocabulary-sharded table, two that collect
+    the vocabulary-sharded head's per-rank argmax (value and index:
+    all-gathers, or all-reduces where a rank holds one row), and the
+    all-reduce of the in-graph finiteness guard over the sharded
+    logits."""
     from repro.models.layers import (ATTN_SCOPE, KV_WRITE_SCOPE,
                                      TP_REDUCE_SCOPE)
 
-    monkeypatch.setattr(common, "pallas_interpret_default", lambda: False)
-    L, P, HKV = 2, 320, 4  # 21 MB a rank's pool: too large to prefetch
-    model = build_model("starcoder2-15b", "full", n_layers=L,
-                        decode_attn="tda")
-    text = _step_program(model, step, (L, P, HKV, PAGE, D), None,
-                         mesh=four_chips)
+    eng, programs = starcoder2_steps
+    text = programs[step]
+    L, P, HKV = 2, 512, 4
+    assert eng.slots.caches["k"].shape == (L, P, HKV, PAGE, D)
     rank = HKV // 4
     moves = _pool_moves(text, (L, P, rank, PAGE, D), (P, rank, PAGE, D),
                         (L, P, PAGE, D), (P, PAGE, D))
     assert not moves, moves
-    attn = {"decode": "tda_paged_decode_attention",
-            "mixed": "tda_mixed_attention"}[step]
+    attn = "tda_paged_decode_attention" if step == "decode" \
+        else "tda_mixed_attention"
     kernels = _kernels(text)
     assert len(kernels) == 2, kernels
     for scope, kernel in ((KV_WRITE_SCOPE, "paged_kv_write"),
@@ -273,10 +344,17 @@ def test_tp4_step_runs_the_kernels_per_rank(four_chips, monkeypatch, step):
                    for n in kernels), kernels
     coll = re.compile(r"=\s*\S+\s(all-reduce|all-gather|reduce-scatter|"
                       r"collective-permute|all-to-all)(-start)?\(")
-    found = [(m.group(1), re.search(r'op_name="([^"]*)"', line).group(1))
+    found = [(m.group(1), (re.search(r'op_name="([^"]*)"', line)
+                           or [None, ""])[1])
              for line in text.splitlines() if (m := coll.search(line))]
     in_loop = [c for c in found if "/while/body/" in c[1]]
     assert [kind for kind, _ in in_loop] == ["all-reduce"] * 2, found
     assert all(f"/{TP_REDUCE_SCOPE}/" in name for _, name in in_loop), found
-    assert sorted(kind for kind, _ in found) == (
-        ["all-gather"] * 2 + ["all-reduce"] * 3), found
+    assert len(found) == 6, found
+    outside = [name for _, name in found if "/while/" not in name]
+    assert sum(name.endswith("(_take)/gather") for name in outside) == 1
+    assert sum(name.endswith("/reduce_and") for name in outside) == 1
+    argmax = [kind for kind, name in found
+              if "/while/" not in name and not name.endswith(
+                  ("(_take)/gather", "/reduce_and"))]
+    assert argmax in (["all-gather"] * 2, ["all-reduce"] * 2), found
