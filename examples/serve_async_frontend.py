@@ -63,7 +63,7 @@ async def serve_streaming(model, params, cfg):
             streamed[h.request.rid] = toks
 
         await asyncio.gather(*(consume(h) for h in handles))
-    return streamed, fe.results, fe.stats
+    return streamed
 
 
 async def serve_cancel(model, params, cfg):
@@ -89,8 +89,7 @@ def main():
     params = model.init(jax.random.key(0))
 
     # ---- 1+2: stream async, then replay the same trace synchronously ----
-    streamed, results, stats = asyncio.run(
-        serve_streaming(model, params, cfg))
+    streamed = asyncio.run(serve_streaming(model, params, cfg))
     eng_ref = Engine(model, params, config=ECFG)
     ref = eng_ref.run(arrivals=[(1 + 2 * i, r) for i, r in
                                 enumerate(make_requests(cfg))])
@@ -99,9 +98,7 @@ def main():
     print(f"streamed {len(streamed)} requests "
           f"(every other one sampled at T=0.8/top-k 5), e.g. rid 1 -> "
           f"{streamed[1]}")
-    print(f"token-identical to synchronous Engine.run on the same trace; "
-          f"itl p50/p99 = {stats['itl_p50']:.0f}/{stats['itl_p99']:.0f} "
-          f"device-tokens")
+    print("token-identical to synchronous Engine.run on the same trace")
 
     # ---- 3: cancellation frees pages mid-decode ----
     req, got, ratio = asyncio.run(serve_cancel(model, params, cfg))

@@ -35,8 +35,7 @@ Traffic accounting (per decode step, per layer, quantized cache)
                    (~bk=128, Hkv=8, D=128: 1 MiB/tile — fits comfortably)
 
 so HBM traffic drops by the blocks-visited ratio *and* the dequant
-round-trip; the occupancy ratio is reported by ``block_stats`` and tracked
-as ``BENCH_decode_attn.json`` across PRs.
+round-trip; the occupancy ratio is reported by ``block_stats``.
 
 Interpret mode runs the same kernel body on CPU (tests, CI); on TPU the
 backend-aware default (``kernels/common.py``) compiles it.

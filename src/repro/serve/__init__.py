@@ -61,8 +61,8 @@ Architecture:
   invariants each step with a structured ``AuditError``.
 
 See ``docs/serving.md`` for the slot-engine lifecycle, the page-table
-contract, the serving failure model, the async front-end / replica tier,
-and the benchmark sidecar contract.
+contract, the serving failure model, and the async front-end / replica
+tier.
 """
 from repro.core.errors import AuditError, UnsupportedConfigError  # noqa: F401
 from repro.serve.config import EngineConfig  # noqa: F401

@@ -38,7 +38,7 @@ __all__ = ["Dispatcher"]
 _SUM_KEYS = (
     "steps", "decoded_tokens", "kv_blocks_visited", "kv_blocks_dense",
     "preemptions", "preemptions_recovered", "pages_shared",
-    "audit_violations", "clock_ticks", "device_time",
+    "audit_violations", "clock_ticks",
     "fleet_restored_pages", "mixed_steps", "prefill_chunk_tokens",
     "completed_ok", "shed", "rejected", "timed_out", "failed", "cancelled",
 )
@@ -112,8 +112,7 @@ class Dispatcher:
         """One fleet step: route ``submits`` (in order) to least-loaded
         replicas, then step every replica that has work or new submits.
         Returns the merged :class:`StepResult` — emissions and finishes
-        concatenated in replica order, ``device_time`` summed (fleet
-        device-tokens spent this step)."""
+        concatenated in replica order."""
         self._iters += 1
         per: Dict[int, List[Request]] = {}
         for req in submits:
@@ -121,7 +120,6 @@ class Dispatcher:
             per.setdefault(id(eng), []).append(req)
         emitted: List[Tuple[Request, int]] = []
         finished: List[Request] = []
-        device_time = 0
         for eng in self.replicas:
             mine = per.get(id(eng), [])
             if not mine and not eng.has_work():
@@ -129,11 +127,9 @@ class Dispatcher:
             res = eng.step(submits=mine)
             emitted.extend(res.emitted)
             finished.extend(res.finished)
-            device_time += res.device_time
         # staged submits are now inside their engines' own load counts
         self._staged = [0] * len(self.replicas)
-        return StepResult(emitted=emitted, finished=finished,
-                          device_time=device_time)
+        return StepResult(emitted=emitted, finished=finished)
 
     def cancel(self, req: Request) -> bool:
         """Cancel on whichever replica the request was routed to."""
@@ -145,10 +141,9 @@ class Dispatcher:
     def finish_run(self) -> List[Request]:
         """Close every replica's session and merge: returns the combined
         done list (replica-major completion order) and builds the fleet's
-        ``decode_stats`` — summed counters, merged TTFT map, ITL
-        percentiles recomputed over the merged per-request emission
-        stamps, per-replica stats under ``"replicas"``, and the fleet
-        index's own hit/publish counters."""
+        ``decode_stats`` — summed counters, merged TTFT map, per-replica
+        stats under ``"replicas"``, and the fleet index's own hit/publish
+        counters."""
         done: List[Request] = []
         per_stats: List[dict] = []
         for eng in self.replicas:
@@ -166,12 +161,6 @@ class Dispatcher:
         stats["ttft"] = {}
         for ps in per_stats:
             stats["ttft"].update(ps.get("ttft", {}))
-        itl = [b - a
-               for r in done
-               for a, b in zip(getattr(r, "_token_dev", []),
-                               getattr(r, "_token_dev", [])[1:])]
-        stats["itl_p50"] = float(np.percentile(itl, 50)) if itl else 0.0
-        stats["itl_p99"] = float(np.percentile(itl, 99)) if itl else 0.0
         if self.fleet is not None:
             stats["fleet"] = {
                 "entries": len(self.fleet), "hits": self.fleet.hits,

@@ -89,7 +89,7 @@ lanes, quantized KV) raises. :meth:`run` accepts ``arrivals`` — a list of
 ``(tick, Request)`` submitted when the virtual clock reaches ``tick`` — so
 bursty mid-decode traffic is replayable, and ``decode_stats["ttft"]``
 records each finished request's first-token latency (wall seconds and
-clock ticks) for the ``ttft_p50``/``ttft_p99`` bench sidecars.
+clock ticks).
 
 **Failure hardening** (``docs/serving.md``, "Serving failure model"):
 every request the engine returns carries a terminal ``status`` (``ok |
@@ -132,7 +132,7 @@ fallback otherwise — plus the KV bytes of the blocks the predicated
 decode attention actually visits, per attention layer. Serving
 ``weight_format="compressed"`` params must drive ``bytes_per_token``
 strictly below the dense-factorized run of the same workload
-(``tools/check_bench.py``).
+(``tests/test_compressed_serving.py``).
 """
 from __future__ import annotations
 
@@ -207,9 +207,8 @@ class StepResult:
     """What one :meth:`Engine.step` iteration did, for external drivers
     (``serve/frontend.py``): the tokens streamed this step as
     ``(request, token)`` events in emission order (continuations resolved
-    to their origin request), the requests that reached a terminal status
-    this step, and the modeled device time after the step's dispatch —
-    the emission timestamp behind the inter-token-latency metrics.
+    to their origin request) and the requests that reached a terminal
+    status this step.
 
     The step's dispatch, when there was one: ``kind`` (``"decode"`` or
     ``"mixed"``; ``None`` when nothing was dispatched), per slot the lane
@@ -223,7 +222,6 @@ class StepResult:
     emitted: List[Tuple[Request, int]] = dataclasses.field(
         default_factory=list)
     finished: List[Request] = dataclasses.field(default_factory=list)
-    device_time: int = 0
     kind: Optional[str] = None
     starts: Optional[np.ndarray] = None
     n_new: Optional[np.ndarray] = None
@@ -488,16 +486,6 @@ class Engine:
         # Deterministic virtual clock: one tick per run-loop iteration
         # (plus injected stall ticks); deadlines count against it.
         self._clock = 0
-        # Modeled device time: every jitted forward dispatch advances this
-        # by its SEQUENCE width (decode steps by 1, a width-S chunk step by
-        # S, a solo whole-prompt sweep by its full concatenated width).
-        # Batch rows ride in parallel PE lanes and are free, matching the
-        # paper's dynamic-batching utilization argument — and the same
-        # modeled-cost convention as the bytes-per-token accounting. TTFT
-        # deltas against this counter are the deterministic, CI-gateable
-        # latency proxy at smoke scale, where wall time measures host FLOPs
-        # (row-linear) instead of dispatch latency.
-        self._device_time = 0
         # Per-engine terminal-status counters, reported (then reset) in
         # decode_stats["status_counts"]; requests finished outside a slot
         # (shed/rejected at submit) park in _terminal until the next run().
@@ -695,7 +683,6 @@ class Engine:
             req.status_reason = str(e)
             raise
         req._submit_clock = self._clock  # type: ignore[attr-defined]
-        req._submit_dev = self._device_time  # type: ignore[attr-defined]
 
     def run(self, arrivals: Optional[Sequence[Tuple[int, Request]]] = None
             ) -> List[Request]:
@@ -706,7 +693,7 @@ class Engine:
         ``arrivals``: optional ``(tick, Request)`` pairs submitted when the
         run-loop iteration count reaches ``tick`` — a deterministic,
         replayable way to drive bursty mid-decode traffic into either
-        engine mode (the TTFT benchmark's workload contract).
+        engine mode.
 
         This is now a thin loop over :meth:`step` — token-identical to the
         old monolithic loop by construction (same iteration body, same
@@ -772,24 +759,17 @@ class Engine:
 
     def _emit(self, req: Request, tok: int) -> None:
         """Append one output token (resolving continuations to their
-        origin), stamp its modeled-device-time emission point (the ITL
-        metric's clock), and surface it as a ``StepResult`` event for
-        streaming drivers."""
+        origin) and surface it as a ``StepResult`` event for streaming
+        drivers."""
         target = getattr(req, "_origin", req)
         target.output.append(int(tok))
-        devs = getattr(target, "_token_dev", None)
-        if devs is None:
-            devs = []
-            target._token_dev = devs  # type: ignore[attr-defined]
-        devs.append(self._device_time)
         if self._events is not None:
             self._events.append((target, int(tok)))
 
     def _step_result(self, res: StepResult, n_done0: int) -> StepResult:
         """Seal a step: the requests that reached a terminal status during
-        it (the session ``done`` tail) and the post-dispatch device time."""
+        it (the session ``done`` tail)."""
         res.finished = self._st.done[n_done0:]
-        res.device_time = self._device_time
         return res
 
     def cancel(self, req: Request) -> bool:
@@ -1111,7 +1091,6 @@ class Engine:
                     else:
                         nxt[chunk] = out[:len(chunk)]
             with TraceAnnotation(spans.RETIRE):
-                self._device_time += int(decode.any()) + (S if chunk else 0)
                 st.steps += 1
                 if chunk:
                     st.mixed_steps += 1
@@ -1192,14 +1171,6 @@ class Engine:
         sl = self.slots
         inj = self._inj
         done = st.done
-        # Inter-token latency in modeled device tokens: gaps between each
-        # request's consecutive emission stamps (see _emit). Deterministic
-        # like the TTFT device_tokens metric — the trace benchmark's gated
-        # itl_p50/itl_p99 source.
-        itl = [b - a
-               for r in done
-               for a, b in zip(getattr(r, "_token_dev", []),
-                               getattr(r, "_token_dev", [])[1:])]
         self.decode_stats = {
             "steps": st.steps,
             "decoded_tokens": st.decoded_tokens,
@@ -1225,9 +1196,9 @@ class Engine:
             "pages_shared": self._pages_shared,
             # Estimated HBM bytes moved per decoded token (weights streamed
             # once per step + KV blocks actually visited) — the serving
-            # analogue of the paper's EMA accounting. Gated by
-            # tools/check_bench.py: compressed serving must move strictly
-            # fewer bytes than dense at equal tokens.
+            # analogue of the paper's EMA accounting. Compressed serving
+            # moves strictly fewer bytes than dense at equal tokens
+            # (tests/test_compressed_serving.py).
             "weight_format": self.model.cfg.weight_format,
             "weight_bytes_per_step": self._weight_stream_bits / 8.0,
             "weight_bytes_per_token": (st.steps
@@ -1236,8 +1207,8 @@ class Engine:
             "kv_bytes_per_token": st.kv_bytes / max(st.decoded_tokens, 1),
             # Tensor-parallel decode: each rank streams only its
             # kv_heads / tp_ranks head-slice of every visited page, so
-            # per-rank KV traffic scales ~1/N with the mesh (gated by
-            # tools/check_bench.py via the decode/sharded row).
+            # per-rank KV traffic scales ~1/N with the mesh
+            # (tests/test_sharded_serving.py).
             "tp_ranks": self._tp,
             "kv_bytes_per_token_per_rank": (
                 st.kv_bytes / max(st.decoded_tokens, 1) / self._tp),
@@ -1260,20 +1231,11 @@ class Engine:
             "audit_violations": self._audit_violations,
             "faults_injected": dict(inj.counts) if inj is not None else {},
             "clock_ticks": self._clock,
-            "device_time": self._device_time,
             # Cross-replica prefix sharing: pages restored into the local
             # pool from the fleet index's host tier this session.
             "fleet_restored_pages": self._fleet_restored_pages,
-            "itl_p50": float(np.percentile(itl, 50)) if itl else 0.0,
-            "itl_p99": float(np.percentile(itl, 99)) if itl else 0.0,
             # Mixed-step accounting + per-request time-to-first-token:
-            # wall seconds since submit, deterministic clock ticks, and
-            # ``device_tokens`` — modeled device time (each jitted dispatch
-            # costs its sequence width; batch rows are free) between submit
-            # and the first token. ``device_tokens`` is the benchmark's
-            # gated ttft_p50/ttft_p99 sidecar source: deterministic and
-            # dispatch-shaped, where wall at smoke scale just measures host
-            # FLOPs and clock ticks hide whole-prompt admission sweeps.
+            # wall seconds since submit and deterministic clock ticks.
             "mixed": self.mixed,
             "prefill_budget": self.prefill_budget,
             "mixed_steps": st.mixed_steps,
@@ -1288,8 +1250,7 @@ class Engine:
                 r.rid: {"wall_s": r.first_token_s - (
                             r.submitted_s if r.submitted_s is not None
                             else r.first_token_s),
-                        "clock": int(r._ttft_clock),
-                        "device_tokens": int(getattr(r, "_ttft_dev", 0))}
+                        "clock": int(r._ttft_clock)}
                 for r in done if r.first_token_s is not None},
         }
         self._counts = {s: 0 for s in TERMINAL_STATUSES}
@@ -1434,9 +1395,6 @@ class Engine:
         target.first_token_s = time.perf_counter()
         target._ttft_clock = (  # type: ignore[attr-defined]
             self._clock - getattr(target, "_submit_clock", self._clock))
-        target._ttft_dev = (  # type: ignore[attr-defined]
-            self._device_time - getattr(target, "_submit_dev",
-                                        self._device_time))
 
     def _note_admit(self, req: Request) -> Request:
         """Count ``req`` as admitted by this step and stamp its origin's
@@ -1967,7 +1925,6 @@ class Engine:
                 logits, caches = self._prefill_shared(
                     self.params, batch, pk, pv, plen)
                 rows, width = batch["inputs"].shape
-                self._device_time += width
                 self.stats.append({
                     "rows": len(reqs), "n_requests": len(reqs),
                     "utilization": (sum(l for _, _, l, _ in slots_of)
@@ -2002,7 +1959,6 @@ class Engine:
         else:  # row-per-request (recurrent stacks), right-aligned
             batch, slots_of, rows = self._rows_batch(adm)
         logits, caches = self._prefill(self.params, batch)
-        self._device_time += int(batch["inputs"].shape[1])
         self.stats.append({"rows": rows, "n_requests": len(adm.requests),
                            "utilization": adm.utilization})
         return logits, caches, slots_of, [None] * len(adm.requests)

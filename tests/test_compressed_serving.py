@@ -114,8 +114,8 @@ def test_engine_compressed_token_equal_to_reforward(qwen_compressed):
 
 
 def test_engine_bytes_per_token_compressed_below_dense(qwen_compressed):
-    """The observability contract gated in tools/check_bench.py: identical
-    workload, equal decoded tokens, strictly fewer estimated bytes moved."""
+    """The observability contract: identical workload, equal decoded
+    tokens, strictly fewer estimated bytes moved."""
     m, params, mc, cparams, stats = qwen_compressed
     _, ds_dense = _run_engine(m, params, m.cfg,
                               stats["weight_stream_bits_dense"])

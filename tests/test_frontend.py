@@ -88,7 +88,6 @@ def test_manual_step_loop_matches_run(smoke_model, sampled):
             due.append(arr[ai][1])
             ai += 1
         res = eng.step(submits=due)
-        assert res.device_time >= 0
         for req, tok in res.emitted:
             emitted.setdefault(req.rid, []).append(tok)
     done = eng.finish_run()
@@ -158,8 +157,8 @@ def test_frontend_token_identical_to_run(smoke_model, sampled):
     assert _outputs(fe.results) == _outputs(ref)
     # the per-token stream IS the final output, token for token
     assert streamed == {r.rid: list(r.output) for r in fe.results}
-    # ITL stats flow from the per-token device stamps
-    assert fe.stats["itl_p99"] > 0
+    # every finished request's first-token latency is recorded
+    assert all(fe.stats["ttft"][r.rid]["wall_s"] >= 0 for r in fe.results)
 
 
 def test_frontend_result_resolves_terminal_status(smoke_model):
@@ -267,7 +266,9 @@ def test_dispatcher_replicas_token_identical(smoke_model):
     # prefill), merged across both replicas
     assert disp.decode_stats["decoded_tokens"] == sum(
         len(r.output) - 1 for r in ref)
-    assert disp.decode_stats["itl_p99"] > 0
+    # the merged TTFT map holds every finished request of both replicas
+    assert all(disp.decode_stats["ttft"][r.rid]["wall_s"] >= 0
+               for r in done)
 
 
 def test_dispatcher_routes_least_loaded_deterministically(smoke_model):

@@ -96,9 +96,6 @@ def test_mixed_matches_serialized_greedy(qwen, prefill_budget):
     # the same iteration gets its first token with no waiting step.
     assert all(v["clock"] >= 0 and v["wall_s"] >= 0.0
                for v in st["ttft"].values())
-    # Modeled device time: every first token costs at least one dispatch
-    # of width >= 1, so the device-token delta is strictly positive.
-    assert all(v["device_tokens"] >= 1 for v in st["ttft"].values())
 
 
 @pytest.mark.parametrize("prefill_budget", [5] + ROW_BUDGETS)

@@ -18,6 +18,8 @@
 * **Hit-aware admission**: a request that only fits the page budget
   because of its expected prefix hits is admitted (the reservation
   discounts shared pages).
+* **Sharing saves memory**: on a shared-system-prompt workload the pool
+  holds strictly fewer pages in use than the same run without sharing.
 * Recurrent/hybrid stacks degrade cleanly: sharing is gated off (state
   lanes are neither paged nor content-addressable).
 """
@@ -28,7 +30,7 @@ from _hypothesis_compat import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.models.transformer import Model
-from repro.serve import Engine, PagePool, Request
+from repro.serve import Engine, EngineConfig, PagePool, Request
 
 
 # ---------------------------------------------------------------------------
@@ -260,6 +262,37 @@ def test_exact_duplicate_prompt_cows_partial_tail():
     ref = Engine(m, params, paged=False, **kw)
     ref.submit(Request(rid=2, prompt=C, max_new_tokens=5))
     assert {r.rid: r.output for r in ref.run()}[2] == outs[2]
+
+
+def test_shared_system_prompt_lowers_kv_memory_ratio():
+    """Twelve requests behind one 48-token system prompt: with sharing on,
+    later admissions map the published prefix pages instead of writing
+    their own, so the mean fraction of the pool in use (kv_memory_ratio)
+    ends strictly below the same run with sharing off."""
+    cfg, m, params = _model("qwen2.5-32b")
+    rng = np.random.default_rng(4)
+    prefix = rng.integers(0, cfg.vocab_size, size=48)
+    tails = [int(rng.integers(4, 13)) for _ in range(12)]
+    budgets = [int(rng.integers(3, 9)) for _ in range(12)]
+
+    def run(share):
+        eng = Engine(m, params, config=EngineConfig(
+            max_len=32, max_new_tokens=16, num_slots=4, page_size=8,
+            max_prompt_len=64, prefix_share=share))
+        r = np.random.default_rng(5)
+        for rid, (n, b) in enumerate(zip(tails, budgets)):
+            eng.submit(Request(rid=rid, prompt=np.concatenate(
+                [prefix, r.integers(0, cfg.vocab_size, size=n)]
+            ).astype(np.int32), max_new_tokens=b))
+        assert all(q.status == "ok" for q in eng.run())
+        eng.slots.pool.check_invariants()
+        return eng.decode_stats
+
+    shared, cold = run(True), run(False)
+    assert shared["prefix_hit_ratio"] > 0 and shared["pages_shared"] > 0
+    assert cold["prefix_hit_ratio"] == 0.0
+    assert shared["decoded_tokens"] == cold["decoded_tokens"]
+    assert shared["kv_memory_ratio"] < cold["kv_memory_ratio"]
 
 
 def test_recurrent_and_hybrid_stacks_gate_sharing_off():

@@ -27,13 +27,13 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 DOC_FILES = [ROOT / "README.md", *sorted((ROOT / "docs").glob("*.md"))]
 
 # Load-bearing doc anchors: each (file, substring) must stay present so the
-# documented contracts (paged lane pool, sampling, sidecar gates) cannot be
+# documented contracts (paged lane pool, sampling, prefix cache) cannot be
 # silently dropped in a refactor. Extend when a new contract lands.
 REQUIRED_ANCHORS = [
     ("README.md", "python -m pytest -x -q"),
     ("README.md", "serve/pages.py"),          # paged lane-pool column/row
     ("README.md", "kv_memory_ratio"),
-    ("README.md", "prefix_hit_ratio"),        # prefix-sharing gate + row
+    ("README.md", "prefix_hit_ratio"),        # prefix-sharing metric + row
     ("README.md", "| Shared |"),              # config-coverage shared column
     ("serving.md", "src/repro/serve/pages.py"),
     ("serving.md", "block table"),
@@ -41,61 +41,44 @@ REQUIRED_ANCHORS = [
     ("serving.md", "kv_memory_ratio"),
     ("serving.md", "preempt"),
     ("serving.md", "src/repro/serve/sampling.py"),
-    ("serving.md", "speedup_vs_lockstep"),
-    # prefix cache contract: hash granularity, CoW, eviction, gates
+    # prefix cache contract: hash granularity, CoW, eviction
     ("serving.md", "chained"),
     ("serving.md", "copy-on-write"),
     ("serving.md", "prefix_hit_ratio"),
     ("serving.md", "pages_shared"),
     ("serving.md", "LRU"),
-    ("serving.md", "tools/check_bench.py"),
-    # compressed-weight serving contract: format switch, traffic metric,
-    # tracked bench row
+    # compressed-weight serving contract: format switch, traffic metric
     ("README.md", "bytes_per_token"),
-    ("README.md", "decode/compressed"),
     ("serving.md", "weight_format"),
     ("serving.md", "bytes_per_token"),
-    ("serving.md", "decode/compressed"),
     # serving failure model contract: terminal statuses, chaos harness,
-    # audit mode, the degraded gate, and the refused deployment
+    # audit mode, and the refused deployment
     ("serving.md", "Serving failure model"),
     ("serving.md", "FaultPlan"),
     ("serving.md", "timed_out"),
     ("serving.md", "REPRO_SERVE_AUDIT"),
     ("serving.md", "AuditError"),
-    ("serving.md", "decode/degraded"),
     ("serving.md", "UnsupportedConfigError"),
     # tensor-parallel sharded decode contract: the section, the merge
-    # kernel, the per-rank traffic metric, the tracked bench row, and the
-    # README coverage column
+    # kernel, the per-rank traffic metric, and the README coverage column
     ("serving.md", "Sharded decode"),
     ("serving.md", "kernels/tda/sharded.py"),
     ("serving.md", "tensor_parallel_size"),
     ("serving.md", "kv_bytes_per_token_per_rank"),
-    ("serving.md", "decode/sharded"),
-    ("README.md", "decode/sharded"),
     ("README.md", "| Mesh |"),
     # mixed-step contract: the interleaved-chunked-prefill section, the
-    # budget knob, the device-token TTFT metric, the tracked bench row,
-    # and the README map row
+    # budget knob, and the README map row
     ("serving.md", "Interleaved chunked prefill"),
     ("serving.md", "prefill_budget"),
-    ("serving.md", "device_tokens"),
-    ("serving.md", "decode/mixed"),
-    ("README.md", "decode/mixed"),
     ("README.md", "prefill_budget"),
     # async front-end & replica contract: the section, the new public
-    # API names, the trace sidecar keys, and the README map row
+    # API names, and the README map row
     ("serving.md", "Async front-end & replicas"),
     ("serving.md", "EngineConfig"),
     ("serving.md", "SamplingParams"),
     ("serving.md", "StreamHandle"),
     ("serving.md", "FleetPrefixIndex"),
     ("serving.md", "cancelled"),
-    ("serving.md", "decode/trace"),
-    ("serving.md", "goodput_slo"),
-    ("README.md", "decode/trace"),
-    ("README.md", "goodput_slo"),
     ("README.md", "SamplingParams"),
 ]
 
